@@ -1,0 +1,550 @@
+"""Chip smoke: the serving path, end to end, on the accelerator.
+
+    python chip_smoke.py              one chip: phases 1-3
+    python chip_smoke.py --chips 4    four chips: the tp=4 engine and
+                                      the single-device engine it is
+                                      compared with, nothing else
+    python chip_smoke.py --rehearse   sandbox dry run (below)
+
+The quickest proof that the system still starts on the chip. ONE
+process: the HTTP server runs in a thread of the process that owns the
+chip, the client in the same process; nothing is spawned.
+
+Phases — each fails the run on its own, nothing is caught and carried
+on from:
+
+1. device    — JAX's default backend is a TPU, or stop. Versions,
+               device kind/count, compile-cache directory, peak FLOPs
+               for this kind (must be known), native-library status.
+2. default   — ``examples/model-serving`` ``build_app()`` with
+               ``MODEL_PRESET=llama3_1b`` (Llama-3.2-1B widths, all 16
+               layers, bf16, seeded random weights), ``engine.warmup``,
+               the app's own HTTP server on a free port, requests over
+               a socket: /chat twice (same greedy ids), /v1/completions,
+               one streamed; health UP with a tpu device; app_engine_*
+               gauges; zero recompiles after warm-up; the prefill
+               program holds the Pallas kernel.
+3. paged     — the same weights, ``kv_layout="paged"`` with
+               ``paged_attention="auto"``, bf16 pool then int8 pool: a
+               prompt long enough for chunked prefill, plain decode, a
+               speculative run. The reference is the same engine built
+               with ``paged_attention="xla"`` on the same chip. Judged
+               on logits of the engines' own step functions (chunk,
+               chunk with history, decode, tree verify); greedy ids
+               reported beside them. Engines are built and dropped one
+               at a time, so HBM holds one pool.
+4. --chips 4 — the 1B shape under ``create_mesh({"tp": 4})`` through
+               the engine vs the single-device engine on the same
+               prompts, same judgement; every leaf ``llama_param_specs``
+               shards and the KV cache must sit on four devices, and
+               ``bytes_in_use`` must be of one order on all four.
+
+Judging rule (phases 3 and 4): ``max |logits - reference| <=
+LOGIT_ATOL``. Seeded random weights give near-flat logits, so where the
+two argmax ids differ and the reference's top-2 margin is under
+``2 * LOGIT_ATOL`` the difference is reported, not failed; any other
+id difference fails.
+
+The last line of stdout is one JSON object. On success, and only
+then: ``{"ok": true, "device": {"platform": "tpu", "kind": ...,
+"count": N}}``. A failed phase ends in ``{"ok": false, ...}`` and a
+non-zero exit. With no accelerator the script exits 2 and prints no
+result at all. Times printed on earlier lines are set-up facts
+(compile, warm-up), not results.
+
+``--rehearse`` is the sandbox dry run (on-chip-measurement guide §2):
+tiny shapes, the kernels under the Pallas interpreter, JAX pinned to
+the CPU (virtual devices for ``--chips 4``). It finds wrong paths and
+arguments before chip time is spent. Its output names ``platform:
+cpu`` and its last line has no "ok" key — it cannot be read as the
+success line.
+"""
+
+from __future__ import annotations
+
+import argparse
+import gc
+import importlib.util
+import json
+import os
+import sys
+import time
+
+REPO = os.path.dirname(os.path.abspath(__file__))
+
+#: bf16 carries 8 bits of mantissa (eps = 2**-8). Kernel and reference
+#: see the same bf16 weights and KV but round differently inside
+#: attention (f32 vs bf16 probabilities) and sum in a different order
+#: (tp=4: four partial sums), once or twice per layer over 16 layers —
+#: a random walk of ~sqrt(32) roundings on activations of order 1,
+#: read out by a head whose logits have a standard deviation of ~0.9.
+#: 32 eps = 0.125 bounds that with room; a wrong mask, a wrong page or
+#: a dropped scale moves logits by their own order, 1 or more.
+LOGIT_ATOL = 32 * 2.0 ** -8
+
+PROMPT = "The quick brown fox jumps over the lazy dog."
+
+
+def say(phase: str, **facts) -> None:
+    print(json.dumps({"phase": phase, **facts}), flush=True)
+
+
+def check(ok: bool, what: str) -> None:
+    if not ok:
+        raise AssertionError(what)
+
+
+# ----------------------------------------------------------------- device
+
+def phase_device(args) -> dict:
+    import importlib.metadata as md
+
+    import jax
+    import jaxlib
+
+    dev = jax.devices()[0]
+    device = {"platform": dev.platform, "kind": dev.device_kind,
+              "count": len(jax.devices())}
+    want = args.platform
+    if dev.platform != want or jax.default_backend() != want:
+        print(f"chip_smoke: JAX's default backend is "
+              f"{jax.default_backend()!r} ({device}), need {want!r}",
+              file=sys.stderr)
+        sys.exit(2)
+    if device["count"] < args.chips:
+        print(f"chip_smoke: --chips {args.chips} needs {args.chips} "
+              f"devices, JAX sees {device['count']}", file=sys.stderr)
+        sys.exit(2)
+
+    from gofr_tpu import native
+    from gofr_tpu.config.env import enable_compile_cache
+    from gofr_tpu.serving.observability import device_peak_flops
+
+    args.cache_dir = cache_dir = enable_compile_cache()
+    peak = device_peak_flops()
+    try:
+        libtpu = md.version("libtpu")
+    except md.PackageNotFoundError:
+        libtpu = None
+    say("device", **device, jax=jax.__version__, jaxlib=jaxlib.__version__,
+        libtpu=libtpu, compile_cache_dir=cache_dir,
+        compile_cache_entries=len(os.listdir(cache_dir)),
+        peak_bf16_flops=peak,
+        native={name: "compiled" if native.available(name) else "python"
+                for name in ("bpe", "batchq")})
+    check(cache_dir == jax.config.jax_compilation_cache_dir
+          and os.path.isdir(cache_dir),
+          f"compile cache directory {cache_dir!r} is not the one in use")
+    if not args.rehearse:
+        check(peak is not None,
+              f"no peak FLOPs known for device kind {dev.device_kind!r} "
+              f"(serving/observability.TPU_PEAK_FLOPS)")
+    return device
+
+
+# ----------------------------------------------------- phase 2: default
+
+def load_example():
+    path = os.path.join(REPO, "examples", "model-serving", "main.py")
+    spec = importlib.util.spec_from_file_location("example_serving", path)
+    module = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(module)
+    return module
+
+
+def has_kernel(fn, *args) -> bool:
+    """Whether the program ``fn`` lowers to holds a Pallas TPU kernel."""
+    import jax
+    return "tpu_custom_call" in jax.jit(fn).lower(*args).as_text()
+
+
+def phase_default(args):
+    import jax.numpy as jnp
+
+    from gofr_tpu.config import DictConfig
+    from gofr_tpu.serving.tokenizer import ByteTokenizer
+    from tests.apputil import AppRunner
+
+    preset = "tiny" if args.rehearse else "llama3_1b"
+    t0 = time.perf_counter()
+    app = load_example().build_app(DictConfig({
+        "HTTP_PORT": "0", "METRICS_PORT": "0", "APP_NAME": "chip-smoke",
+        "GOFR_TELEMETRY": "false", "MODEL_PRESET": preset}))
+    engine = app.container.get_model("llama")
+    built_s = time.perf_counter() - t0
+    n_prompt = len(ByteTokenizer().encode(PROMPT))
+    entries = len(os.listdir(args.cache_dir))
+    t0 = time.perf_counter()
+    engine.warmup(prompt_lens=(n_prompt,))
+    say("default.setup", preset=preset, build_s=round(built_s, 1),
+        warmup_s=round(time.perf_counter() - t0, 1),
+        cache_entries_before_warmup=entries,
+        note="set-up facts: cold when the cache was empty, warm after")
+
+    bucket = engine._bucket_for(n_prompt)
+    kernel = has_kernel(engine._prefill_fn, engine.params,
+                        jnp.zeros((1, bucket), jnp.int32),
+                        jnp.ones((1,), jnp.int32))
+    say("default.prefill_program", bucket=bucket, pallas_kernel=kernel)
+    if not args.rehearse:
+        check(kernel, "the prefill program holds no Pallas kernel: "
+              "attention(implementation='auto') took the XLA path")
+
+    vocab = engine.params["embed"].shape[0]
+    body = {"prompt": PROMPT, "max_tokens": 16, "temperature": 0.0}
+    with AppRunner(app=app) as runner:
+        def post(path, payload):
+            status, _, data = runner.request("POST", path, payload,
+                                             timeout=300)
+            check(status in (200, 201), f"POST {path} -> {status}: "
+                                        f"{data[:300]!r}")
+            return data
+
+        first = json.loads(post("/chat", body))["data"]
+        again = json.loads(post("/chat", body))["data"]
+        ids = first["tokens"]
+        check(len(ids) == 16 and all(0 <= t < vocab for t in ids),
+              f"/chat tokens malformed: {ids}")
+        check(again["tokens"] == ids,
+              f"same greedy prompt, different ids: {ids} / "
+              f"{again['tokens']}")
+        completion = json.loads(post("/v1/completions", {
+            **body, "model": preset}))
+        check(completion["usage"]["completion_tokens"] == 16
+              and completion["choices"][0]["finish_reason"] == "length",
+              f"/v1/completions malformed: {completion}")
+        events = [json.loads(line[6:]) for line in
+                  post("/chat", {**body, "stream": True}).decode()
+                  .splitlines()
+                  if line.startswith("data: ") and line != "data: [DONE]"]
+        streamed = [e["token"] for e in events]
+        check(streamed == ids,
+              f"streamed ids differ from the buffered ones: {streamed}")
+
+        status, health = runner.get_json("/.well-known/health")
+        tpu = health["data"]["checks"]["tpu"]
+        platforms = sorted({d["platform"]
+                            for d in tpu["details"]["devices"]})
+        check(status == 200 and tpu["status"] == "UP"
+              and platforms == [args.platform],
+              f"health does not show an UP {args.platform} device: {tpu}")
+        status, _, metrics = runner.request(
+            "GET", "/metrics", port=runner.metrics_port)
+        gauges = sorted({line.split("{")[0].split(" ")[0]
+                         for line in metrics.decode().splitlines()
+                         if line.startswith("app_engine_")})
+        check(status == 200 and "app_engine_tokens_per_second" in gauges,
+              f"app_engine_* gauges missing from /metrics: {gauges}")
+        recompiles = engine.stats["recompiles"]
+    say("default.serve", greedy_ids=ids, streamed=len(streamed),
+        health=tpu["status"], health_platforms=platforms,
+        engine_gauges=len(gauges), recompiles_after_warmup=recompiles)
+    check(recompiles == 0, f"{recompiles} recompiles after warm-up")
+    return engine.params
+
+
+# ------------------------------------------------------- phase 3: paged
+
+def judge(name: str, got, ref) -> dict:
+    """The judging rule of the module docstring on one logits pair
+    [..., V]; returns what to report, raises on a failure."""
+    import numpy as np
+    got = np.asarray(got, np.float32).reshape(-1, got.shape[-1])
+    ref = np.asarray(ref, np.float32).reshape(-1, ref.shape[-1])
+    check(np.isfinite(got).all() and np.isfinite(ref).all(),
+          f"{name}: non-finite logits")
+    err = float(np.abs(got - ref).max())
+    check(err <= LOGIT_ATOL, f"{name}: max |logit diff| {err:.4f} "
+                             f"> {LOGIT_ATOL:.4f}")
+    ids, ref_ids = got.argmax(-1), ref.argmax(-1)
+    top2 = np.sort(ref, axis=-1)[:, -2:]
+    margin = top2[:, 1] - top2[:, 0]
+    differ = np.flatnonzero(ids != ref_ids)
+    bad = [int(i) for i in differ if margin[i] >= 2 * LOGIT_ATOL]
+    check(not bad, f"{name}: greedy id differs from the reference at "
+                   f"rows {bad} where its top-2 margin is "
+                   f"{[float(margin[i]) for i in bad]}")
+    return {"max_logit_diff": round(err, 5), "ids": ids.tolist(),
+            "ids_differ_under_margin": [int(i) for i in differ]}
+
+
+def step_logits(eng, vocab: int, expect_kernel: bool | None) -> dict:
+    """Drive the engine's OWN paged step functions (what its jitted
+    programs wrap) over a zero pool of the engine's own shape with a
+    fixed token script: a 64-row chunk, a chunk against that history,
+    a decode step, a two-branch tree verify. Every engine gets the same
+    inputs, so the logits compare across implementations."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from gofr_tpu.serving.spec import build_draft_tree
+
+    rng = np.random.default_rng(21)
+    b, mp = 2, eng._pages_per_slot
+    tables = jnp.asarray(np.arange(b * mp, dtype=np.int32).reshape(b, mp))
+    kp = jax.tree.map(jnp.zeros_like, eng.k_cache)
+    vp = jax.tree.map(jnp.zeros_like, eng.v_cache)
+
+    def toks(*shape):
+        return jnp.asarray(rng.integers(0, vocab, shape), jnp.int32)
+
+    tree = build_draft_tree(0, [[1, 2, 3], [1, 4], [5, 6]])
+    n = tree.n_nodes
+    script = [  # name, step function, tokens, arguments after the
+        #         pools, valid rows of the logits (None = all)
+        ("chunk", eng._paged_chunk_fn, toks(b, 64),
+         (tables, jnp.asarray([0, 0]), jnp.asarray([64, 40])), None),
+        ("chunk_history", eng._paged_chunk_fn, toks(b, 64),
+         (tables, jnp.asarray([64, 40]), jnp.asarray([64, 64])), None),
+        ("decode", eng._paged_decode_fn, toks(b),
+         (tables, jnp.asarray([128, 104])), None),
+        ("tree_verify", eng._paged_verify_fn, toks(b, n),
+         (tables, jnp.asarray([129, 105]), jnp.asarray([n, n - 2]),
+          jnp.asarray([tree.depths] * b, jnp.int32),
+          jnp.asarray([tree.masks] * b, jnp.int32)), [n, n - 2]),
+    ]
+    out = {}
+    for name, fn, tokens, rest, rows in script:
+        if expect_kernel is not None:
+            kernel = has_kernel(fn, eng.params, tokens, kp, vp, *rest)
+            check(kernel == expect_kernel,
+                  f"{name}: program holds a Pallas kernel = {kernel}, "
+                  f"expected {expect_kernel}")
+        logits, kp, vp = jax.jit(fn, donate_argnums=(2, 3))(
+            eng.params, tokens, kp, vp, *rest)
+        out[name] = np.asarray(logits, np.float32) if rows is None else \
+            np.concatenate([np.asarray(logits[i, :r], np.float32)
+                            for i, r in enumerate(rows)])
+    return out
+
+
+def serve_paged(eng, vocab: int) -> dict:
+    """The engine-level run: a 150-token prompt (three chunks of the
+    64-wide bucket), plain decode, then the same prompt speculatively.
+    Seeded random weights at a 128k vocabulary never repeat an n-gram,
+    so prompt-lookup would draft nothing: the speculative run drafts
+    from an oracle instead — the plain run's own continuation beside a
+    wrong branch — which makes the verify pass, acceptance and KV
+    compaction run on the chip and pins what they must return."""
+    from gofr_tpu.serving.engine import SamplingParams
+    from gofr_tpu.serving.spec import build_draft_tree
+
+    prompt = [(7 * i + 3) % 251 for i in range(150)]
+    greedy = SamplingParams(temperature=0.0, max_new_tokens=12)
+    eng.start()
+    try:
+        plain = eng.submit_sync(prompt, greedy)
+        check(plain.error is None, f"plain run failed: {plain.error}")
+        want = list(plain.generated)
+        check(len(want) == 12 and all(0 <= t < vocab for t in want),
+              f"malformed ids {want}")
+        chunks = eng.stats["prefill_calls"]
+
+        def oracle(req):
+            # the engine's own budget rule: the bonus token always
+            # lands, so at most remaining - 1 drafts can be kept
+            done = len(req.generated)
+            depth = min(eng.config.spec_draft, len(want) - done - 1)
+            if depth <= 0 or req.prompt_tokens != prompt:
+                return []
+            right = want[done:done + depth]
+            wrong = [(t + 1) % vocab for t in right]
+            return build_draft_tree(req.generated[-1], [right, wrong])
+
+        eng._draft_proposals = oracle
+        spec = eng.submit_sync(prompt, greedy)
+        check(spec.error is None, f"speculative run failed: {spec.error}")
+        stats = dict(eng.stats)
+    finally:
+        eng.stop()
+    check(chunks >= 3, f"the 150-token prompt took {chunks} prefill "
+                       f"dispatches, not a chunk walk")
+    check(stats["spec_passes"] > 0, "no speculative verify pass ran")
+    check(stats["recompiles"] == 0,
+          f"{stats['recompiles']} recompiles after warm-up")
+    return {"greedy_ids": want, "speculative_ids": list(spec.generated),
+            "prefill_dispatches": chunks, "prefix_hits": stats["prefix_hits"],
+            "spec_passes": stats["spec_passes"],
+            "spec_accepted": stats["spec_accepted"]}
+
+
+def phase_paged(args, params) -> None:
+    from gofr_tpu.models.llama import LlamaConfig
+    from gofr_tpu.serving.engine import EngineConfig
+    from gofr_tpu.serving.glue import llama_engine
+
+    c = LlamaConfig.tiny() if args.rehearse else LlamaConfig.llama3_1b()
+    # small max_seq and few warm-up buckets (widths untouched) keep a
+    # cold run within a few minutes of compiling
+    for kv_dtype in ("bf16", "int8"):
+        results = {}
+        for impl in ("xla", "interpret" if args.rehearse else "auto"):
+            t0 = time.perf_counter()
+            eng = llama_engine(params, c, EngineConfig(
+                max_batch=4, max_seq=512, prefill_buckets=(64,),
+                prefill_batch=2, decode_steps_per_pass=4, seed=0,
+                kv_layout="paged", page_size=64, kv_dtype=kv_dtype,
+                paged_attention=impl, speculative=True, spec_draft=3,
+                spec_branches=2, spec_adaptive=False))
+            resolved = eng.paged_attention_impl
+            check(resolved == {"auto": "kernel"}.get(impl, impl),
+                  f"paged_attention={impl!r} resolved to {resolved!r}")
+            eng.warmup(prompt_lens=(64,), chunked=True)
+            warm_s = time.perf_counter() - t0
+            kernel = {"kernel": True, "xla": False}.get(resolved)
+            results[resolved] = (step_logits(eng, c.vocab_size, kernel),
+                                 serve_paged(eng, c.vocab_size))
+            say("paged.engine", kv_dtype=kv_dtype, resolved=resolved,
+                programs_hold_kernel=kernel, setup_s=round(warm_s, 1),
+                **results[resolved][1])
+            del eng          # one pool in HBM at a time
+            gc.collect()
+        (ref_logits, ref_run), (got_logits, got_run) = results.values()
+        verdict = {name: judge(f"paged/{kv_dtype}/{name}",
+                               got_logits[name], ref_logits[name])
+                   for name in ref_logits}
+        agree = sum(1 for a, b in zip(got_run["greedy_ids"],
+                                      ref_run["greedy_ids"]) if a == b)
+        say("paged.verdict", kv_dtype=kv_dtype, logit_atol=LOGIT_ATOL,
+            engine_ids_agree=f"{agree}/12 with the xla engine",
+            speculative_matches_plain=(got_run["speculative_ids"]
+                                       == got_run["greedy_ids"]),
+            **verdict)
+
+
+# ------------------------------------------------------ phase 4: tp = 4
+
+def phase_sharded(args) -> None:
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from gofr_tpu.models.llama import LlamaConfig, llama_init
+    from gofr_tpu.parallel import create_mesh, llama_param_specs
+    from gofr_tpu.parallel.sharding import _match_specs
+    from gofr_tpu.serving.engine import EngineConfig, SamplingParams
+    from gofr_tpu.serving.glue import llama_engine
+
+    # (the rehearsal's tiny shape needs four kv heads to split)
+    c = LlamaConfig.tiny().scaled(dim=128, n_heads=8, n_kv_heads=4) \
+        if args.rehearse else LlamaConfig.llama3_1b()
+    devices = jax.devices()[:4]
+    # weights are made on the host so that nothing but the sharded
+    # engine's share lands on any chip before the memory check
+    with jax.default_device(jax.devices("cpu")[0]):
+        params = llama_init(jax.random.key(0), c)
+    cfg = EngineConfig(max_batch=4, max_seq=512, prefill_buckets=(64,),
+                       prefill_batch=2, seed=0)
+    prompts = [[(5 * i + j) % 251 for i in range(40 + 7 * j)]
+               for j in range(3)]
+    tokens = jnp.asarray(np.random.default_rng(4).integers(
+        0, c.vocab_size, (2, 64)), jnp.int32)
+    kv_len = jnp.asarray([64, 33], jnp.int32)
+
+    def run(mesh):
+        eng = llama_engine(params if mesh is not None
+                           else jax.device_put(params, devices[0]),
+                           c, cfg, mesh=mesh)
+        eng.warmup(prompt_lens=(64,))
+        logits, _ = jax.jit(eng._prefill_fn)(eng.params, tokens, kv_len)
+        evidence = None
+        if mesh is not None:
+            specs = _match_specs(eng.params, llama_param_specs(mesh))
+            leaves = jax.tree.leaves_with_path(eng.params)
+            flat = dict(jax.tree.leaves_with_path(
+                specs, is_leaf=lambda s: not isinstance(s, dict)))
+            sharded = [jax.tree_util.keystr(path) for path, leaf in leaves
+                       if any(ax is not None for ax in flat[path])]
+            for path, leaf in leaves:
+                if jax.tree_util.keystr(path) not in sharded:
+                    continue
+                shards = {s.data.shape for s in leaf.addressable_shards}
+                check(len(leaf.sharding.device_set) == 4
+                      and leaf.size == 4 * int(np.prod(shards.pop())),
+                      f"{jax.tree_util.keystr(path)} is not split over "
+                      f"four devices: {leaf.sharding}")
+            kv = eng.k_cache
+            check(len(kv.sharding.device_set) == 4
+                  and {s.data.shape[3] for s in kv.addressable_shards}
+                  == {c.n_kv_heads // 4},
+                  f"KV cache is not split over four devices: "
+                  f"{kv.sharding}")
+            in_use = [d.memory_stats()["bytes_in_use"] for d in devices] \
+                if not args.rehearse else None
+            evidence = {"sharded_leaves": sharded,
+                        "kv_shard_shape": list(
+                            kv.addressable_shards[0].data.shape),
+                        "bytes_in_use": in_use}
+            if in_use is not None:
+                check(max(in_use) <= 3 * min(in_use),
+                      f"device memory is lopsided: {in_use}")
+        eng.start()
+        try:
+            reqs = [eng.submit_sync(p, SamplingParams(
+                temperature=0.0, max_new_tokens=8)) for p in prompts]
+        finally:
+            eng.stop()
+        check(all(r.error is None for r in reqs),
+              [r.error for r in reqs])
+        return np.asarray(logits, np.float32), \
+            [list(r.generated) for r in reqs], evidence
+
+    got, got_ids, evidence = run(create_mesh({"tp": 4}, devices))
+    say("sharded.tp4", attention="xla (glue: kernels are single-device)",
+        greedy_ids=got_ids, **evidence)
+    ref, ref_ids, _ = run(None)
+    say("sharded.single", greedy_ids=ref_ids)
+    say("sharded.verdict", logit_atol=LOGIT_ATOL,
+        engine_ids_agree=sum(a == b for a, b in zip(got_ids, ref_ids)),
+        **judge("tp4/prefill", got, ref))
+
+
+# ------------------------------------------------------------------- main
+
+def main() -> int:
+    parser = argparse.ArgumentParser(description=__doc__.split("\n")[0])
+    parser.add_argument("--chips", type=int, choices=(1, 4), default=1)
+    parser.add_argument("--rehearse", action="store_true",
+                        help="sandbox dry run: CPU, tiny shapes, "
+                             "interpret kernels; never the success line")
+    args = parser.parse_args()
+    if args.rehearse:
+        os.environ["JAX_PLATFORMS"] = "cpu"
+        os.environ["XLA_FLAGS"] = (
+            os.environ.get("XLA_FLAGS", "")
+            + " --xla_force_host_platform_device_count=4").strip()
+    args.platform = "cpu" if args.rehearse else "tpu"
+    sys.path.insert(0, REPO)
+    import gofr_tpu  # noqa: F401 — the script alone, without the
+    #                  program, stops here and prints no result
+
+    phase, device = "device", None
+    try:
+        device = phase_device(args)   # exits 2 with no accelerator
+        if args.chips == 4:
+            phase = "sharded"
+            phase_sharded(args)
+        else:
+            phase = "default"
+            params = phase_default(args)
+            gc.collect()     # the default engine's cache leaves HBM
+            phase = "paged"
+            phase_paged(args, params)
+    except Exception as exc:
+        import traceback
+        traceback.print_exc()
+        print(json.dumps({"ok": False, "phase": phase,
+                          "error": f"{type(exc).__name__}: {exc}"[:2000],
+                          "device": device}), flush=True)
+        return 1
+    say("compile_cache", entries=len(os.listdir(args.cache_dir)))
+    if args.rehearse:
+        print(json.dumps({"rehearsal": "passed", "device": device}))
+    else:
+        print(json.dumps({"ok": True, "device": device}))
+    return 0
+
+
+if __name__ == "__main__":
+    sys.exit(main())

@@ -114,88 +114,57 @@ class EnvConfig:
 
 # --------------------------------------------------- XLA compile cache
 #
-# The ONE shared config path for the persistent XLA compilation cache.
-# Everything that compiles serving graphs — the engine, bench children,
-# every scripts/tpu_jobs/*.py entry point — resolves the directory
-# here, so warmup compiles amortize across processes instead of being
-# re-paid per child (round 5 burned its ~35-minute TPU window ~10:1 on
-# recompiles because nothing in the tree set jax_compilation_cache_dir).
+# ONE rule for where the persistent XLA compilation cache lives, so
+# warmup compiles amortize across processes and a driver can place the
+# cache: ``JAX_COMPILATION_CACHE_DIR`` if the environment sets it — JAX
+# reads that itself and this code sets no directory — else the fixed
+# ``<checkout>/.jax_cache`` next to the package. The path is part of
+# the cache's key, so a directory that moves (a temp name, a
+# pid, a home that differs between machines) never hits.
 
-#: env / config key; value "off"/"none"/"0"/"false" disables, empty or
-#: unset falls back to the default directory below
-COMPILE_CACHE_ENV = "GOFR_COMPILE_CACHE_DIR"
-
-_OFF_VALUES = ("off", "none", "0", "false", "disabled")
-
-
-def default_compile_cache_dir() -> str:
-    """``$XDG_CACHE_HOME/gofr_tpu/xla_cache`` (``~/.cache`` fallback)
-    — stable across processes and repo checkouts, so bench children,
-    TPU jobs and restarted servers all hit the same cache."""
-    root = os.environ.get("XDG_CACHE_HOME") or os.path.join(
-        os.path.expanduser("~"), ".cache")
-    return os.path.join(root, "gofr_tpu", "xla_cache")
+#: the fixed default, derived from the package's location; gitignored
+DEFAULT_COMPILE_CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__)))), ".jax_cache")
 
 
-def resolve_compile_cache_dir(config: "Config | None" = None) -> str | None:
-    """Resolve the cache directory from the shared config key
-    (``Config`` layer if given, else the OS environment), falling back
-    to :func:`default_compile_cache_dir`. ``None`` = disabled."""
-    value = config.get(COMPILE_CACHE_ENV) if config is not None else None
-    if value is None:
-        value = os.environ.get(COMPILE_CACHE_ENV)
-    if value is None or value == "":
-        return default_compile_cache_dir()
-    if value.strip().lower() in _OFF_VALUES:
-        return None
-    return value
-
-
-#: directory this process last enabled — guards the reset below
+#: directory this process enabled — makes the call idempotent
 _enabled_dir: str | None = None
 
 
-def enable_compile_cache(dir_or_auto: str | None = "auto") -> str | None:
-    """Point JAX's persistent compilation cache at the shared
-    directory. "auto" resolves via :func:`resolve_compile_cache_dir`;
-    an explicit path is used as-is; ``None``/"off" disables (no-op).
-    Thresholds are lowered so every executable caches — the serving
-    graphs are many small jits (per-bucket prefills, decode windows)
-    whose compile time is individually under JAX's 1 s default floor
-    but collectively the whole warmup wall. Idempotent; returns the
-    directory actually enabled (or None). Best-effort: an unwritable
-    directory or an old JAX just leaves the cache off."""
+def enable_compile_cache() -> str:
+    """Turn JAX's persistent compilation cache on under the rule above
+    and return the directory in use. Thresholds are lowered so every
+    executable caches — the serving graphs are many small jits
+    (per-bucket prefills, decode windows) whose compile time is
+    individually under JAX's 1 s default floor but collectively the
+    whole warmup wall. Idempotent. A default directory that cannot be
+    created raises: a cache that is silently off re-pays every compile
+    in every process with nothing to say why."""
     global _enabled_dir
-    if dir_or_auto is None:
-        return None
-    if dir_or_auto == "auto":
-        path = resolve_compile_cache_dir()
-    elif str(dir_or_auto).strip().lower() in _OFF_VALUES:
-        path = None
+    if _enabled_dir is not None:
+        return _enabled_dir
+    import jax
+    if os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+        path = jax.config.jax_compilation_cache_dir
     else:
-        path = str(dir_or_auto)
-    if path is None:
-        return None
-    if path == _enabled_dir:
-        return path
-    try:
-        os.makedirs(path, exist_ok=True)
-    except OSError:
-        return None
-    try:
-        import jax
+        path = DEFAULT_COMPILE_CACHE_DIR
+        try:
+            os.makedirs(path, exist_ok=True)
+        except OSError as exc:
+            raise OSError(
+                f"cannot create the XLA compile cache directory {path}: "
+                f"{exc}. Set JAX_COMPILATION_CACHE_DIR to a writable "
+                f"directory.") from exc
         jax.config.update("jax_compilation_cache_dir", path)
-        jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
-        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
-        # JAX binds the persistent cache ONCE, at the first compile: a
-        # process that compiled anything before this call (model init,
-        # another engine) silently keeps the cache OFF unless the
-        # handle is reset to re-read the directory
-        from jax.experimental.compilation_cache import (
-            compilation_cache as _cc)
-        _cc.reset_cache()
-    except Exception:  # pragma: no cover — older jax without the knobs
-        return None
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    # JAX binds the persistent cache ONCE, at the first compile: a
+    # process that compiled anything before this call (model init,
+    # another engine) keeps the cache OFF unless the handle is reset
+    # to re-read the options
+    from jax.experimental.compilation_cache import compilation_cache
+    compilation_cache.reset_cache()
     _enabled_dir = path
     return path
 

@@ -5,8 +5,9 @@ SURVEY §7 stage 4: the device registry lives in the container
 metrics surfaces every other datasource uses (health aggregation
 container/health.go:8-98; the reference has no device analog).
 
-Design points for a tunneled/remote device backend:
-- enumeration runs in a worker thread with a deadline — a dead tunnel
+Design points:
+- enumeration runs in a worker thread with a deadline — a device
+  runtime that hangs (a wedged driver, a chip another process holds)
   makes health report DOWN instead of hanging the health endpoint;
 - results are cached with a TTL so /health and the metrics poller
   don't hammer the backend;

@@ -1,4 +1,4 @@
-"""HTTP error taxonomy with status codes and log levels.
+"""HTTP error classes with status codes and log levels.
 
 Mirrors the reference's error set (pkg/gofr/http/errors.go): each error
 knows its HTTP status code and the level it should be logged at
@@ -144,7 +144,7 @@ def status_and_level_for(err: BaseException) -> tuple[int, Level]:
     status = getattr(err, "status_code", 500)
     if not isinstance(status, int) or not (100 <= status <= 599):
         status = 500
-    # client errors default to INFO (matching the taxonomy above);
+    # client errors default to INFO (matching the classes above);
     # server errors to ERROR
     level = getattr(err, "log_level", INFO if status < 500 else ERROR)
     return status, level

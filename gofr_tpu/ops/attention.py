@@ -19,11 +19,11 @@ import jax.numpy as jnp
 NEG_INF = -1e30
 
 
-def _is_tpu() -> bool:
-    try:
-        return jax.default_backend() == "tpu"
-    except Exception:
-        return False
+def is_tpu() -> bool:
+    """What every ``implementation='auto'`` in ops/ asks. A backend
+    that fails to initialise raises here — it must never read as "not a
+    TPU" and quietly select the XLA path."""
+    return jax.default_backend() == "tpu"
 
 
 def _repeat_kv(x: jnp.ndarray, group: int) -> jnp.ndarray:
@@ -165,7 +165,7 @@ def attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
     elif implementation == "interpret":
         use_pallas, interpret = True, True
     elif implementation == "auto":
-        use_pallas = _is_tpu() and causal and isinstance(q_offset, int) \
+        use_pallas = is_tpu() and causal and isinstance(q_offset, int) \
             and q_offset == 0 and q.shape[1] > 1
     if use_pallas:
         from .flash_attention import flash_attention

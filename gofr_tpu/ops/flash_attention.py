@@ -22,11 +22,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# jax renamed TPUCompilerParams -> CompilerParams; support both so the
-# kernels compile on the installed toolchain either side of the rename
-_CompilerParams = getattr(pltpu, "CompilerParams", None) \
-    or pltpu.TPUCompilerParams
-
 NEG_INF = -1e30
 
 
@@ -127,7 +122,7 @@ def flash_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
         out_shape=jax.ShapeDtypeStruct((b, hq, sq_p, d), q.dtype),
         # (batch, head, q-block) cells carry no cross-iteration state —
         # the online-softmax accumulator lives within one cell's k loop
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel")),
         interpret=interpret,
     )(kv_lengths, qt, kt, vt)

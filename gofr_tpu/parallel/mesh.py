@@ -30,19 +30,6 @@ def mesh_axes(mesh: Mesh) -> dict[str, int]:
     return dict(zip(mesh.axis_names, mesh.devices.shape))
 
 
-def shard_map_compat(f, *, mesh: Mesh, in_specs, out_specs):
-    """``shard_map`` with replication checking off, across the jax
-    rename: new jax exposes ``jax.shard_map(..., check_vma=False)``,
-    older toolchains ``jax.experimental.shard_map.shard_map(...,
-    check_rep=False)``."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=False)
-    from jax.experimental.shard_map import shard_map as _shard_map
-    return _shard_map(f, mesh=mesh, in_specs=in_specs,
-                      out_specs=out_specs, check_rep=False)
-
-
 def create_mesh(axes: dict[str, int], devices=None) -> Mesh:
     """Build a Mesh from {axis: size}; one size may be -1 (inferred)."""
     devices = list(devices if devices is not None else jax.devices())

@@ -120,11 +120,10 @@ def make_pipeline_train_step(config: LlamaConfig, mesh: Mesh, *,
         param_specs["lm_head"] = P()
     batch_spec = P(None, "dp", None)  # [M, mb over dp, S]
 
-    from .mesh import shard_map_compat
-    sharded_loss = shard_map_compat(
+    sharded_loss = jax.shard_map(
         pipe_loss, mesh=mesh,
         in_specs=(param_specs, batch_spec, batch_spec, batch_spec),
-        out_specs=P())
+        out_specs=P(), check_vma=False)
 
     def train_step(state: TrainState, tokens, targets, mask):
         loss, grads = jax.value_and_grad(sharded_loss)(

@@ -51,11 +51,7 @@ def ring_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
                    scale: float | None = None) -> jnp.ndarray:
     """Causal attention inside shard_map: q/k/v [B, S_local, H, D] are
     this device's sequence chunk; returns the local output chunk."""
-    # axis_size is the newer spelling; psum(1, axis) constant-folds to
-    # the same static int on toolchains that predate it
-    ring = (int(jax.lax.axis_size(axis_name))
-            if hasattr(jax.lax, "axis_size")
-            else int(jax.lax.psum(1, axis_name)))
+    ring = int(jax.lax.axis_size(axis_name))
     rank = jax.lax.axis_index(axis_name)
     b, s_local, h, d = q.shape
     scale = scale if scale is not None else d ** -0.5
@@ -99,10 +95,10 @@ def make_ring_attention(mesh: Mesh, axis_name: str = "sp"):
     """
     spec = P(None, axis_name, None, None)
 
-    from .mesh import shard_map_compat
-    fn = shard_map_compat(
+    fn = jax.shard_map(
         functools.partial(ring_attention, axis_name=axis_name),
-        mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec)
+        mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec,
+        check_vma=False)
 
     def apply(q, k, v):
         sharding = NamedSharding(mesh, spec)

@@ -10,9 +10,9 @@ scale-out behind its service client (reference
 pkg/gofr/service/new.go:68); on TPU the "replicas" are mesh shards in
 a single SPMD program, coordinated by the runtime rather than HTTP.
 
-``EngineConfig.kv_dtype="int8"`` needs NO glue here: ``make_cache``
-always allocates the model-dtype pool and the engine re-lays it as
-the quantized ``{"q", "s"}`` pytree at allocation time
+``EngineConfig.kv_dtype="int8"`` and the pool's lane packing need NO
+glue here: ``make_cache`` describes the model-dtype K/V rows and the
+engine builds the pool in its final representation
 (``engine._alloc_pool``). The paged model fns below take whole pools
 and route writes through ``ops.paged_kv.pool_write``, which is
 pytree-aware — so native decode, chunked prefill, prefix-cache
@@ -69,8 +69,15 @@ def llama_engine(params: Any, model_config: LlamaConfig,
     constrain_kv = None
     if mesh is not None:
         import jax
-        import jax.numpy as jnp
         from ..parallel.sharding import llama_param_specs, shard_params
+        if implementation == "auto":
+            # the Pallas kernels are single-device: GSPMD refuses them
+            # ("Mosaic kernels cannot be automatically partitioned.
+            # Please wrap the call in a shard_map" — the v5e compiler,
+            # described 2x2 mesh). Until they are shard_mapped over the
+            # head axis (ROADMAP A5) a sharded engine is built on XLA
+            # attention, chosen here by name, not found out in warmup.
+            implementation = "xla"
         params = shard_params(params, mesh, llama_param_specs(mesh))
         kv_sharding = _kv_sharding(mesh)
 
@@ -118,27 +125,12 @@ def llama_engine(params: Any, model_config: LlamaConfig,
             kc, vc = constrain_kv(kc), constrain_kv(vc)
         return logits, kc, vc
 
-    def make_cache(batch, max_seq, head_major=False):
-        if head_major:
-            # paged pool [L, Hkv, Np, pg, hd] (ops/paged_kv.py),
-            # allocated directly — no transient double buffer
-            import jax.numpy as jnp
-            shape = (c.n_layers, c.n_kv_heads, batch, max_seq,
-                     c.head_dim)
-            kc = jnp.zeros(shape, c.dtype)
-            vc = jnp.zeros(shape, c.dtype)
-        else:
-            kc, vc = make_empty_cache(c, batch, max_seq=max_seq)
+    def make_cache(batch, max_seq):
+        kc, vc = make_empty_cache(c, batch, max_seq=max_seq)
         if mesh is not None:
             import jax
-            from jax.sharding import NamedSharding, PartitionSpec as P
-            if head_major:
-                tp = "tp" if "tp" in mesh.axis_names else None
-                sharding = NamedSharding(mesh, P(None, tp))
-            else:
-                sharding = _kv_sharding(mesh)
-            kc = jax.device_put(kc, sharding)
-            vc = jax.device_put(vc, sharding)
+            kc = jax.device_put(kc, _kv_sharding(mesh))
+            vc = jax.device_put(vc, _kv_sharding(mesh))
         return kc, vc
 
     paged_decode_fn = None
@@ -207,10 +199,8 @@ def moe_engine(params: Any, model_config, engine_config: EngineConfig | None = N
         return moe_decode_step(params, tokens, k_cache, v_cache,
                                lengths, c, attn_window=attn_window)
 
-    def make_cache(batch, max_seq, head_major=False):
-        shape = ((c.n_layers, c.n_kv_heads, batch, max_seq, c.head_dim)
-                 if head_major else
-                 (c.n_layers, batch, max_seq, c.n_kv_heads, c.head_dim))
+    def make_cache(batch, max_seq):
+        shape = (c.n_layers, batch, max_seq, c.n_kv_heads, c.head_dim)
         return jnp.zeros(shape, c.dtype), jnp.zeros(shape, c.dtype)
 
     return Engine(params, engine_config, prefill_fn=prefill_fn,

@@ -203,7 +203,7 @@ class GoodputMeter:
     pass's classification, so the identity is structural, not
     statistical — tests pin it across every pass kind).
 
-    Causes (the taxonomy ``/debug/efficiency`` and
+    Causes (the set ``/debug/efficiency`` and
     ``app_engine_waste_seconds{cause}`` expose):
 
     - ``padding`` — inactive/pad rows in a dispatched fixed-shape
@@ -219,8 +219,8 @@ class GoodputMeter:
     - ``bubble`` — wall-clock gaps between a collect completing with
       NOTHING left in flight and the next dispatch, while work was
       waiting (queued, requeued or active). Host scheduling overhead
-      the device spends idle — the dispatch-bound regime BENCH_r05
-      measured, now a named number.
+      the device spends idle — the dispatch-bound regime the round-5
+      chip run showed, now a named number.
     - ``integrity_probe`` — device time spent serving golden canary
       probes (serving/integrity.py): correct-by-design synthetic
       traffic, re-priced out of ``useful`` at the probe's retire
@@ -1167,25 +1167,18 @@ class StallWatchdog:
 
 # ------------------------------------------------------------------- MFU
 #
-# Peak dense bf16 FLOPs per chip by device kind (same table the bench
-# uses). Unknown kinds (CPU, future TPUs) -> None and the MFU gauge
-# simply stays 0 — never a guess.
+# Peak dense bf16 FLOPs per chip, keyed by the EXACT ``device_kind``
+# JAX reports (v5e is "TPU v5 lite"; Google Cloud TPU documentation,
+# per-generation system architecture pages). A kind that is not in the
+# table (CPU, a TPU generation nobody measured on) -> None and the MFU
+# gauge simply stays 0 — never a prefix match onto a neighbour's peak.
 TPU_PEAK_FLOPS = {"TPU v5 lite": 197e12, "TPU v5p": 459e12,
-                  "TPU v5": 459e12, "TPU v4": 275e12,
-                  "TPU v6 lite": 918e12}
+                  "TPU v4": 275e12, "TPU v6 lite": 918e12}
 
 
 def device_peak_flops() -> float | None:
-    try:
-        import jax
-        kind = jax.devices()[0].device_kind
-    except Exception:
-        return None
-    for name, peak in sorted(TPU_PEAK_FLOPS.items(),
-                             key=lambda kv: -len(kv[0])):
-        if kind.startswith(name):
-            return peak
-    return None
+    import jax
+    return TPU_PEAK_FLOPS.get(jax.devices()[0].device_kind)
 
 
 def jit_cost_flops(jitted: Any, *args: Any) -> float | None:

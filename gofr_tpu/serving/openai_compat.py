@@ -180,7 +180,7 @@ class OpenAIRoutes:
         prompt_tokens = self.tokenizer.encode(prompt)
         req = self.engine.submit(prompt_tokens, params, tenant=tenant)
         if req.error:
-            # typed scheduler rejects map to OpenAI's taxonomy: rate
+            # typed scheduler rejects map to OpenAI's error types: rate
             # limits are 429 rate_limit_error with Retry-After, the
             # rest stay 503 server_error
             rej = getattr(req, "reject", None)

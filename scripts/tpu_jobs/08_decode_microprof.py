@@ -15,17 +15,19 @@ import time
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__)))))
 
+# GOFR_JOB_SMOKE=1 is the CPU dry run of the same script (tiny shapes,
+# interpret kernels); without it the job needs a TPU and fails on any
+# other backend — a number from the dry run is never a device number
+SMOKE = os.environ.get("GOFR_JOB_SMOKE") == "1"
+if SMOKE:
+    os.environ["JAX_PLATFORMS"] = "cpu"
+
 import jax
 import jax.numpy as jnp
 
-SMOKE = os.environ.get("GOFR_JOB_SMOKE") == "1"
-if SMOKE:
-    jax.config.update("jax_platforms", "cpu")
-if not SMOKE:
-    assert jax.default_backend() != "cpu", "TPU job ran on CPU"
+assert jax.default_backend() == ("cpu" if SMOKE else "tpu"), \
+    f"job ran on {jax.default_backend()!r}"
 
-# shared persistent XLA compile cache: this job's warmup compiles
-# amortize across every child in the round (config/env.py)
 from gofr_tpu.config.env import enable_compile_cache
 enable_compile_cache()
 

@@ -20,14 +20,18 @@ import time
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__)))))
 
+# GOFR_JOB_SMOKE=1 is the CPU dry run of the same script (tiny shapes,
+# interpret kernels); without it the job needs a TPU and fails on any
+# other backend — a number from the dry run is never a device number
+SMOKE = os.environ.get("GOFR_JOB_SMOKE") == "1"
+if SMOKE:
+    os.environ["JAX_PLATFORMS"] = "cpu"
+
 import jax
 import jax.numpy as jnp
 
-SMOKE = os.environ.get("GOFR_JOB_SMOKE") == "1"
-if SMOKE:
-    jax.config.update("jax_platforms", "cpu")
-if not SMOKE:
-    assert jax.default_backend() != "cpu", "TPU job ran on CPU"
+assert jax.default_backend() == ("cpu" if SMOKE else "tpu"), \
+    f"job ran on {jax.default_backend()!r}"
 
 from gofr_tpu.config.env import enable_compile_cache
 enable_compile_cache()
@@ -37,7 +41,7 @@ from gofr_tpu.models.llama import (LlamaConfig, llama_init,
                                    llama_prefill_chunk_paged)
 from gofr_tpu.ops.paged_attention import (paged_chunk_attention_pallas,
                                           paged_chunk_attention_xla)
-from gofr_tpu.ops.paged_kv import gather_view, scatter_decode
+from gofr_tpu.ops.paged_kv import gather_view, pack_pool, scatter_decode
 
 out = {"job": "prefill_microprof", "backend": jax.default_backend(),
        "device": jax.devices()[0].device_kind}
@@ -76,7 +80,8 @@ def timed(fn, *args, reps=REPS):
 mp = MAX_SEQ // PAGE
 n_pages = B * mp
 hd = c.head_dim
-kp = jnp.zeros((c.n_layers, c.n_kv_heads, n_pages, PAGE, hd), c.dtype)
+kp = pack_pool(jnp.zeros((c.n_layers, c.n_kv_heads, n_pages, PAGE, hd),
+                         c.dtype))
 vp = jnp.zeros_like(kp)
 tables = jnp.arange(B * mp, dtype=jnp.int32).reshape(B, mp)
 tokens = jnp.ones((B, CHUNK), jnp.int32)
@@ -84,7 +89,7 @@ chunk_lens = jnp.full((B,), CHUNK, jnp.int32)
 
 # ---- 1) bare chunk-attention kernel vs the XLA gather reference at
 # several history depths (one layer's pool)
-kp1 = jnp.zeros((c.n_kv_heads, n_pages, PAGE, hd), c.dtype)
+kp1 = pack_pool(jnp.zeros((c.n_kv_heads, n_pages, PAGE, hd), c.dtype))
 vp1 = jnp.zeros_like(kp1)
 q = jnp.ones((B, CHUNK, c.n_heads, hd), c.dtype)
 for hist in (0, MAX_SEQ // 4, MAX_SEQ - CHUNK):
@@ -108,8 +113,8 @@ def native_step(params, tokens, kp, vp, tables, offsets, chunk_lens):
 
 
 def view_step(params, tokens, kp, vp, tables, offsets, chunk_lens):
-    k_view = gather_view(kp, tables)
-    v_view = gather_view(vp, tables)
+    k_view = gather_view(kp, tables, head_dim=hd)
+    v_view = gather_view(vp, tables, head_dim=hd)
     logits, k_view, v_view = llama_prefill_chunk(
         params, tokens, k_view, v_view, offsets, chunk_lens, c,
         implementation="xla")
@@ -138,7 +143,8 @@ def timed_donated(fn, kp, vp, reps=REPS):
 t_native = timed_donated(native_step, kp, vp)
 out["native_chunk_step_ms"] = round(t_native * 1e3, 2)
 out["native_chunk_tok_per_s"] = round(B * CHUNK / t_native, 1)
-kp = jnp.zeros((c.n_layers, c.n_kv_heads, n_pages, PAGE, hd), c.dtype)
+kp = pack_pool(jnp.zeros((c.n_layers, c.n_kv_heads, n_pages, PAGE, hd),
+                         c.dtype))
 vp = jnp.zeros_like(kp)
 t_view = timed_donated(view_step, kp, vp)
 out["view_chunk_step_ms"] = round(t_view * 1e3, 2)

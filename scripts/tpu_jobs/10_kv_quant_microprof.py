@@ -20,14 +20,18 @@ import time
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__)))))
 
+# GOFR_JOB_SMOKE=1 is the CPU dry run of the same script (tiny shapes,
+# interpret kernels); without it the job needs a TPU and fails on any
+# other backend — a number from the dry run is never a device number
+SMOKE = os.environ.get("GOFR_JOB_SMOKE") == "1"
+if SMOKE:
+    os.environ["JAX_PLATFORMS"] = "cpu"
+
 import jax
 import jax.numpy as jnp
 
-SMOKE = os.environ.get("GOFR_JOB_SMOKE") == "1"
-if SMOKE:
-    jax.config.update("jax_platforms", "cpu")
-if not SMOKE:
-    assert jax.default_backend() != "cpu", "TPU job ran on CPU"
+assert jax.default_backend() == ("cpu" if SMOKE else "tpu"), \
+    f"job ran on {jax.default_backend()!r}"
 
 from gofr_tpu.config.env import enable_compile_cache
 enable_compile_cache()
@@ -35,7 +39,7 @@ enable_compile_cache()
 from gofr_tpu.models.llama import LlamaConfig
 from gofr_tpu.ops.paged_attention import (paged_chunk_attention_pallas,
                                           paged_decode_attention_pallas)
-from gofr_tpu.ops.paged_kv import quantize_pool
+from gofr_tpu.ops.paged_kv import pack_pool, quantize_pool
 
 out = {"job": "kv_quant_microprof", "backend": jax.default_backend(),
        "device": jax.devices()[0].device_kind}
@@ -74,9 +78,12 @@ mp = MAX_SEQ // PAGE
 n_pages = B * mp
 key = jax.random.key(0)
 kk, kv, kq = jax.random.split(key, 3)
-kp = jax.random.normal(kk, (c.n_kv_heads, n_pages, PAGE, hd), jnp.bfloat16)
-vp = jax.random.normal(kv, (c.n_kv_heads, n_pages, PAGE, hd), jnp.bfloat16)
-kp8, vp8 = quantize_pool(kp), quantize_pool(vp)
+# the engine's layout: kv heads packed into 128-lane rows (paged_kv.py)
+kp = pack_pool(jax.random.normal(kk, (c.n_kv_heads, n_pages, PAGE, hd),
+                                 jnp.bfloat16))
+vp = pack_pool(jax.random.normal(kv, (c.n_kv_heads, n_pages, PAGE, hd),
+                                 jnp.bfloat16))
+kp8, vp8 = quantize_pool(kp, head_dim=hd), quantize_pool(vp, head_dim=hd)
 tables = jnp.arange(B * mp, dtype=jnp.int32).reshape(B, mp)
 
 # per-row KV bytes each kernel DMAs (K + V): the roofline the measured

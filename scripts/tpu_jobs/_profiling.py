@@ -4,7 +4,7 @@
 ``jax.profiler.start_trace/stop_trace``, landing an xprof trace under
 ``GOFR_JOB_PROFILE_DIR`` (default ``/tmp/gofr_tpu_profiles``) — the
 same capture the serving app exposes at ``POST /debug/profile/start``
-(gofr_tpu/serving/observability.py), so the next TPU window gets
+(gofr_tpu/serving/observability.py), so a chip run gets
 profiler traces for free alongside the jobs' JSON lines.
 
 Usage in a job (after the sys.path/jax setup)::

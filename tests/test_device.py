@@ -1,5 +1,5 @@
 """TPU device registry: enumeration, caching, health, metrics, and the
-dead-tunnel timeout path."""
+hung-probe timeout path."""
 
 import time
 
@@ -66,7 +66,7 @@ def test_stale_cache_degrades_instead_of_down():
     assert devices  # real probe worked
 
     def boom():
-        raise ConnectionError("tunnel gone")
+        raise ConnectionError("device runtime gone")
     reg._probe = boom
     still = reg.devices()
     assert still == devices  # stale cache served

@@ -109,14 +109,15 @@ def test_zero_length_slot_returns_zeros_not_nan():
 
 # --------------------------------------------- Mosaic sublane alignment
 #
-# BENCH_r05's first real-TPU compile died in Mosaic: "Slice shape
+# Round 5's first real-TPU compile died in Mosaic: "Slice shape
 # along dimension 2 must be aligned to tiling (8), but is 1" — a grid
 # cell's q/out block carried fewer than 8 rows along the sublane dim
 # (small GQA group x short q block). The wrappers now pad those blocks
 # to the 8-row tile; these tests pin (a) the alignment arithmetic for
 # every group/block_q the serving shapes can produce and (b) interpret
 # -mode parity on the exact shapes that used to emit misaligned slices,
-# so the regression is caught on CPU, not in the next TPU window.
+# so the regression is caught on CPU (tests/test_tpu_compile.py
+# compiles the real shapes for a described chip besides).
 
 def test_sublane_padding_always_tile_aligned():
     from gofr_tpu.ops.paged_attention import SUBLANE, _pad_group
@@ -190,12 +191,14 @@ def test_chunk_parity_with_sub_tile_rows():
                                    rtol=2e-5, atol=2e-5)
 
 
-def test_page_misalignment_raises_actionable_error():
-    """A page size that cannot DMA into sublane-tiled VMEM must fail
-    with a message naming the fix, not a Mosaic internal error (only
-    on the compiled path — interpret mode has no tiling)."""
-    case = _random_paged_case(jax.random.key(9), page=4, max_pages=12,
-                              lengths=(5, 9, 3))
+def test_untakeable_shapes_raise_actionable_errors():
+    """A pool the compiled kernel cannot take must fail with a message
+    naming the constraint, not a Mosaic internal error (only on the
+    compiled path — interpret mode has no tiling): a page size that
+    cannot DMA into sublane-tiled VMEM, and a row that does not fill
+    the 128 lanes (head_dim 16 with two kv heads packs to 32)."""
+    case = _random_paged_case(jax.random.key(9), hd=128, page=4,
+                              max_pages=12, lengths=(5, 9, 3))
     q, k_pool, v_pool, tables, lengths, *_ = case
     with pytest.raises(ValueError, match="multiple of 8"):
         paged_decode_attention_pallas(q, k_pool, v_pool, tables,
@@ -203,6 +206,118 @@ def test_page_misalignment_raises_actionable_error():
     # interpret mode still accepts it (CPU tests use small pages)
     paged_decode_attention_pallas(q, k_pool, v_pool, tables, lengths,
                                   interpret=True)
+    case = _random_paged_case(jax.random.key(9))
+    q, k_pool, v_pool, tables, lengths, *_ = case
+    with pytest.raises(ValueError, match="128 lanes"):
+        paged_decode_attention_pallas(q, pack_pool(k_pool),
+                                      pack_pool(v_pool), tables,
+                                      lengths, interpret=False)
+
+
+def test_engine_construction_rejects_untakeable_kernel_shape():
+    """paged_attention='kernel' with a pool the compiled kernel cannot
+    take is a ValueError at ENGINE CONSTRUCTION naming the constraint —
+    never a Mosaic trace out of warmup, never a quiet switch to
+    xla/view. The tiny config's rows pack to 32 lanes."""
+    from gofr_tpu.serving.engine import EngineConfig
+    from gofr_tpu.serving.glue import demo_llama_engine
+    with pytest.raises(ValueError, match="128 lanes"):
+        demo_llama_engine(EngineConfig(
+            max_batch=2, max_seq=64, kv_layout="paged", page_size=16,
+            paged_attention="kernel"))
+    # the same shape is fine for the paths that were asked for by name
+    eng = demo_llama_engine(EngineConfig(
+        max_batch=2, max_seq=64, kv_layout="paged", page_size=16,
+        paged_attention="interpret"))
+    assert eng.paged_attention_impl == "interpret"
+
+
+# ------------------------------------------------- lane-packed pools
+#
+# head_dim < 128 packs 128 // head_dim kv heads into one pool row
+# (ops/paged_kv.py) and the kernel attends a head group per grid cell.
+# The cases above build UNPACKED pools by hand (pack == 1); these run
+# the same three paths on the packed re-lay of the same values — the
+# kernel on the packed pool must reproduce the XLA reference on the
+# unpacked one, for plain and quantized pools alike.
+
+from gofr_tpu.ops.paged_attention import (  # noqa: E402
+    paged_chunk_attention_pallas, paged_chunk_attention_xla,
+    paged_tree_attention_pallas, paged_tree_attention_xla)
+from gofr_tpu.ops.paged_kv import pack_pool, quantize_pool  # noqa: E402
+
+
+def _packed_case(hd, hkv, group, page, sq=5, seed=70):
+    b, max_pages, n_pages = 3, 6, 16
+    ks = jax.random.split(jax.random.key(seed), 3)
+    q = jax.random.normal(ks[0], (b, sq, hkv * group, hd), jnp.float32)
+    k_pool = jax.random.normal(ks[1], (hkv, n_pages, page, hd),
+                               jnp.float32)
+    v_pool = jax.random.normal(ks[2], (hkv, n_pages, page, hd),
+                               jnp.float32)
+    # mid-page history, a history spanning several chunks, and a
+    # zero-length slot; the 2nd slot's chunk is shorter than Sq
+    history = jnp.asarray([3, 2 * page + 1, 0], jnp.int32)
+    chunk_lens = jnp.asarray([sq, max(1, sq - 2), 0], jnp.int32)
+    rng = np.random.default_rng(seed)
+    tables = np.full((b, max_pages), n_pages, np.int32)
+    for i in range(b):
+        need = -(-int(history[i] + chunk_lens[i]) // page)
+        if need:
+            tables[i, :need] = rng.choice(n_pages, size=need,
+                                          replace=False)
+    return q, k_pool, v_pool, jnp.asarray(tables), history, chunk_lens
+
+
+@pytest.mark.parametrize("quantized", [False, True])
+@pytest.mark.parametrize("hd,hkv,group,page", [
+    (64, 4, 2, 64),     # the serving shape: two heads to a row
+    (32, 4, 1, 16),     # four to a row, four pages to a chunk
+    (16, 2, 4, 8),      # head count caps the pack: 32-lane rows
+    (64, 3, 2, 8),      # odd head count: no packing at all
+])
+def test_packed_pool_kernel_matches_unpacked_xla(hd, hkv, group, page,
+                                                 quantized):
+    from gofr_tpu.ops.paged_kv import head_pack
+    q, k_pool, v_pool, tables, history, chunk_lens = _packed_case(
+        hd, hkv, group, page)
+    kp, vp = pack_pool(k_pool), pack_pool(v_pool)
+    assert kp.shape[-1] == hd * head_pack(hkv, hd)
+    if quantized:
+        kp, vp = (quantize_pool(kp, head_dim=hd),
+                  quantize_pool(vp, head_dim=hd))
+        k_pool, v_pool = quantize_pool(k_pool), quantize_pool(v_pool)
+
+    def close(got, want, n_valid):
+        assert not np.isnan(np.asarray(got)).any()
+        for i, n in enumerate(n_valid):  # rows past chunk_len: padding
+            np.testing.assert_allclose(np.asarray(got)[i, :n],
+                                       np.asarray(want)[i, :n],
+                                       rtol=2e-5, atol=2e-5)
+        np.testing.assert_allclose(np.asarray(got)[2], 0.0, atol=1e-6)
+
+    n_valid = [int(n) for n in chunk_lens]
+    close(paged_chunk_attention_pallas(q, kp, vp, tables, history,
+                                       chunk_lens, interpret=True),
+          paged_chunk_attention_xla(q, k_pool, v_pool, tables, history,
+                                    chunk_lens), n_valid)
+    # the packed XLA reference is the on-chip yardstick: same answer
+    close(paged_chunk_attention_xla(q, kp, vp, tables, history,
+                                    chunk_lens),
+          paged_chunk_attention_xla(q, k_pool, v_pool, tables, history,
+                                    chunk_lens), n_valid)
+    lengths = history + chunk_lens
+    close(paged_decode_attention_pallas(q[:, 0], kp, vp, tables, lengths,
+                                        interpret=True)[:, None],
+          paged_decode_attention_xla(q[:, 0], k_pool, v_pool, tables,
+                                     lengths)[:, None], [1, 1, 0])
+    # a two-branch tree over the 5 nodes: 0 -> (1 -> 3, 2 -> 4)
+    masks = jnp.asarray([[0b00001, 0b00011, 0b00101, 0b01011, 0b10101]]
+                        * 3, jnp.int32)
+    close(paged_tree_attention_pallas(q, kp, vp, tables, history,
+                                      chunk_lens, masks, interpret=True),
+          paged_tree_attention_xla(q, k_pool, v_pool, tables, history,
+                                   chunk_lens, masks), n_valid)
 
 
 # ---------------------------------------------------- quantized pools
@@ -215,11 +330,6 @@ def test_page_misalignment_raises_actionable_error():
 # interpret-parity idiom) — while int8-vs-f32 is bounded by the
 # quantization error itself (per element <= amax/254; observed worst
 # case ~0.018 on N(0,1) pools, asserted at 0.05 = ~3x margin).
-
-from gofr_tpu.ops.paged_attention import (paged_chunk_attention_pallas,
-                                          paged_chunk_attention_xla)
-from gofr_tpu.ops.paged_kv import quantize_pool
-
 
 def _quant_decode_case(seed, *, page, hq, hkv, lengths=(5, 17, 0)):
     """Mid-page histories + a zero-length tail slot, quantized pools
@@ -317,20 +427,6 @@ def test_int8_chunk_within_quant_bound_of_f32():
         n = int(chunk_lens[i])
         np.testing.assert_allclose(np.asarray(got)[i, :n],
                                    np.asarray(want)[i, :n], atol=0.05)
-
-
-def test_int8_page_alignment_requires_32_rows():
-    """int8 VMEM tiles are (32, 128): the compiled path must reject
-    pages under 32 rows with the actionable error (a 16-row page is
-    legal for f32's 8-row tiles), while interpret mode — no tiling —
-    still accepts it so CPU tests can use small pages."""
-    q, k_pool, v_pool, kq, vq, tables, lens = _quant_decode_case(
-        59, page=16, hq=4, hkv=4)
-    with pytest.raises(ValueError, match="multiple of 32"):
-        paged_decode_attention_pallas(q, kq, vq, tables, lens,
-                                      interpret=False)
-    paged_decode_attention_pallas(q, kq, vq, tables, lens,
-                                  interpret=True)
 
 
 # ------------------------------------------------- engine-level parity

@@ -176,16 +176,18 @@ def test_quantized_decode_append_preserves_earlier_rows():
                              jnp.float32) * 5.0
     pool = scatter_prefill(pool, tables, slab[:, :, :3])  # rows 0..2
     before_q = np.asarray(pool["q"][:, :, 3, :3]).copy()
-    before_s = np.asarray(pool["s"][:, :, 3, :3]).copy()
+    # scales are lane-major: page 3's row r sits at lane r of its row
+    before_s = np.asarray(pool["s"][:, :, 3, 0, :3]).copy()
     # append logical row 3 (offset 3 of page 3) with a much larger amax
     view = jnp.zeros((L, 1, 12, H, D), jnp.float32)
     view = view.at[:, 0, 3].set(100.0)
     pool = scatter_decode(pool, tables, view, jnp.asarray([3]), 1)
     np.testing.assert_array_equal(np.asarray(pool["q"][:, :, 3, :3]),
                                   before_q)
-    np.testing.assert_array_equal(np.asarray(pool["s"][:, :, 3, :3]),
+    np.testing.assert_array_equal(np.asarray(pool["s"][:, :, 3, 0, :3]),
                                   before_s)
-    got = dequantize_rows(pool["q"][:, :, 3, 3], pool["s"][:, :, 3, 3])
+    got = dequantize_rows(pool["q"][:, :, 3, 3],
+                          pool["s"][:, :, 3, 0, 3:4])
     np.testing.assert_allclose(np.asarray(got), 100.0, rtol=1e-2)
 
 
@@ -220,11 +222,19 @@ def test_quantized_scatter_drops_like_plain():
 
 
 def test_quantized_row_bytes_accounting():
-    """int8 rows cost hd + 4 bytes per (layer, head) vs 4*hd for the
-    f32 source pool — the engine's byte-budget sizing leans on this."""
+    """Rows are billed AS ALLOCATED — the engine's byte-budget sizing
+    leans on this. A page's scales are one 128-lane f32 row per head
+    group, so at the serving shape (head_dim 64 packed two to a row,
+    page 64) an int8 row costs hd + 4 bytes per head against 2*hd for
+    bf16 — exactly the f32-per-row ideal; a 4-row toy page spreads the
+    same 512-byte scale row over 4 rows."""
     plain, quant = _pool(), _qpool()
     assert pool_row_bytes(plain) == L * H * D * 4
-    assert pool_row_bytes(quant) == L * H * (D + 4)
+    assert pool_row_bytes(quant) == L * H * (D + 128 * 4 // PG)
+    serving = jnp.zeros((16, 4, 2, 64, 128), jnp.bfloat16)  # 8 heads, hd 64
+    assert pool_row_bytes(serving) == 16 * 8 * 64 * 2
+    assert pool_row_bytes(quantize_pool(serving, head_dim=64)) \
+        == 16 * 8 * (64 + 4)
 
 
 # ---------------------------------------------------------------- engine
@@ -339,6 +349,22 @@ def test_recovered_pool_keeps_head_major_layout():
     assert all(len(r.generated) == 6 for r in reqs)
 
 
+@pytest.mark.parametrize("kv_dtype", ["bf16", "int8"])
+def test_single_device_pool_is_not_pinned(kv_dtype):
+    """The pool is built directly in its final representation, but it
+    must not be COMMITTED to the default device: a committed pool
+    commits every array the jitted steps hand back (lengths, tokens),
+    whose signature then differs from warm-up's — each program would
+    compile a second time at its first real dispatch, behind the
+    shape-keyed recompile sentinel's back (scripts/cost_smoke.py saw
+    it as a 10x first-pass cost). Only a mesh places the pool."""
+    eng = demo_llama_engine(EngineConfig(
+        max_batch=2, max_seq=64, kv_layout="paged", page_size=16,
+        kv_dtype=kv_dtype))
+    leaves = jax.tree_util.tree_leaves((eng.k_cache, eng.v_cache))
+    assert leaves and not any(leaf.committed for leaf in leaves)
+
+
 def test_kv_dtype_validation():
     """Engine construction (where every config knob is validated)
     rejects unknown kv_dtypes and int8/byte-budgets on the slot
@@ -403,9 +429,10 @@ def test_int8_engine_greedy_close_to_bf16():
 
 def test_int8_pool_doubles_pages_at_same_byte_budget():
     """Capacity is the point: at one fixed kv_pool_bytes budget the
-    int8 pool must hold >= 1.8x the pages of the bf16 pool. Uses
-    head_dim=64 (ratio 2*hd/(hd+4) = 1.88); the tiny config's hd=16
-    would overstate the win (its f32 pools give 3.2x)."""
+    int8 pool must hold >= 1.8x the pages of the bf16 pool. Uses the
+    serving shape — head_dim=64, page 64 (ratio 2*hd/(hd+4) = 1.88):
+    a page's scales fill one 128-lane row per packed head pair there;
+    shorter pages pad that row and give back part of the win."""
     import jax as _jax
 
     from gofr_tpu.models.llama import LlamaConfig, llama_init
@@ -420,7 +447,7 @@ def test_int8_pool_doubles_pages_at_same_byte_budget():
 
     def pages(dt):
         eng = llama_engine(params, c, EngineConfig(
-            max_batch=2, max_seq=256, kv_layout="paged", page_size=32,
+            max_batch=2, max_seq=256, kv_layout="paged", page_size=64,
             kv_dtype=dt, kv_pool_bytes=budget), implementation="xla")
         return eng._n_pages, eng._kv_bytes_total
 
@@ -443,7 +470,7 @@ def test_recovered_pool_stays_quantized():
     assert eng._kv_lost()                      # pytree-aware probe
     eng._recover_lost_cache(RuntimeError("induced"))
     assert eng.k_cache["q"].shape == shape_before
-    assert eng.k_cache["s"].shape == shape_before[:-1] + (1,)
+    assert eng.k_cache["s"].shape == shape_before[:3] + (1, 128)
     eng.start()
     reqs = [eng.submit([3, 1, 4], SamplingParams(
         temperature=0.0, max_new_tokens=6)) for _ in range(2)]

@@ -222,11 +222,12 @@ def test_quantized_bytes_dtype_detection():
 def test_quantized_bytes_covers_kv_pool_tree():
     """The engine's kv_bytes accounting is quantized_bytes over the
     (k_cache, v_cache) pytree — the paged pool's {"q", "s"} split must
-    sum codes + per-row scales, and the bf16 pool its plain array."""
+    sum codes + scale rows (one 128-lane f32 row per page and head
+    group, ops/paged_kv.py), and the bf16 pool its plain array."""
     from gofr_tpu.ops.paged_kv import quantize_pool
     l, h, np_, pg, d = 2, 2, 4, 8, 16
     plain = jnp.zeros((l, h, np_, pg, d), jnp.bfloat16)
     assert quantized_bytes((plain, plain)) == 2 * l * h * np_ * pg * d * 2
     qp = quantize_pool(plain)
-    want = l * h * np_ * pg * (d + 4)          # int8 codes + f32 scale
+    want = l * h * np_ * (pg * d + 128 * 4)    # int8 codes + scale row
     assert quantized_bytes((qp, qp)) == 2 * want
