@@ -1,0 +1,12 @@
+"""Harness checks. Run them with ``python3 -m pytest benchmarks/tests``;
+tier-1's ``pytest tests/`` does not collect this directory."""
+
+import os
+import sys
+
+HERE = os.path.dirname(os.path.abspath(__file__))
+BENCH = os.path.dirname(HERE)
+REPO = os.path.dirname(BENCH)
+for path in (BENCH, REPO):
+    if path not in sys.path:
+        sys.path.insert(0, path)
