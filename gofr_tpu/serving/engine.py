@@ -41,6 +41,7 @@ the measured bottleneck of the overhead-bound round-5 chip decode).
 from __future__ import annotations
 
 import asyncio
+import itertools
 import threading
 import time
 from dataclasses import dataclass, field
@@ -100,6 +101,10 @@ class GenRequest:
                                    # submitter's trace is sampled — the
                                    # engine.* spans assemble at retire
     admitted_at: float | None = None  # first slot assignment (queue end)
+    rid: int = -1                  # engine-local request id, set in
+                                   # submit(): the request's spans and
+                                   # flight-log entry carry it, and a
+                                   # pass record names the rids it served
     events: list = field(default_factory=list)  # (name, t0, t1, attrs)
     _obs_done: bool = False        # finalize-once guard (retire + fail)
     tenant: str | None = None      # bounded tenant label from the auth
@@ -346,15 +351,18 @@ class EngineConfig:
     #: adaptive-pipelining threshold (``pipeline_depth=None`` only):
     #: minimum actively-decoding slots before a pass is left in flight.
     pipeline_min_slots: int = 8
-    #: flight recorder ring size: per-pass records (kind, occupancy,
-    #: queue depth, tokens, dispatch/collect spans, h2d count,
-    #: preemptions) kept in a fixed ring, served at ``/debug/engine``,
-    #: summarized by ``health_check()`` and dumped on a loop crash.
-    #: Recording is append-only host work — zero device perturbation.
-    #: 0 disables.
-    flight_recorder_size: int = 256
+    #: flight recorder ring size: per-pass records (kind, enqueue and
+    #: result times, the rows' request ids and context lengths,
+    #: occupancy, queue depth, tokens, h2d count, preemptions) kept in
+    #: a fixed ring beside the ring of the loop's phase spans, served
+    #: at ``/debug/engine``, summarized by ``health_check()`` and
+    #: dumped on a loop crash. Sized for a minute at several times the
+    #: pass rate the chip shows today. Recording is append-only host
+    #: work — zero device perturbation. 0 disables passes, requests
+    #: and spans alike.
+    flight_recorder_size: int = 4096
     #: retired-request event logs kept alongside the pass ring
-    flight_recorder_requests: int = 32
+    flight_recorder_requests: int = 512
     #: workload capture: arm the WorkloadRecorder at construction so
     #: every retired request lands in the capture ring (arrival time,
     #: prompt ids, gen params, seed, tenant, outcome) — the replayable
@@ -510,6 +518,7 @@ class Engine:
                                     WatermarkTracker, WorkloadRecorder)
         self.recorder = FlightRecorder(config.flight_recorder_size,
                                        config.flight_recorder_requests)
+        self._rids = itertools.count(1)  # GenRequest.rid (next() is atomic)
         #: device-time waste attribution (useful vs padding/
         #: preempt_recompute/spec_rejected/bubble, conserved against
         #: busy time); fed at collect/retire on the engine thread
@@ -916,7 +925,9 @@ class Engine:
             "scheduler": lambda: self.waiting.state(),
             "goodput": self.goodput.state,
             "watermarks": self.watermarks.state,
-            "recorder": self.recorder.snapshot,
+            # the newest passes and spans (what the whole ring was
+            # before it grew to a minute's worth): a bundle stays small
+            "recorder": lambda: self.recorder.snapshot(256),
             "config": self.config_digest,
             # every bundle ships the per-signature cost table + the
             # autoprofiler state ("which kernel class got slower, and
@@ -1055,8 +1066,9 @@ class Engine:
         self.total_generated = 0
         #: per-phase wall time (device call + sync) for perf accounting;
         #: the bench surfaces these as the per-phase breakdown.
-        #: dispatch_s/collect_s are the HOST-side spans of the decode
-        #: hot loop (arg prep + async dispatch / post-sync emission);
+        #: dispatch_s/collect_s are the summed durations of the decode
+        #: passes' ``engine.decode_dispatch`` and ``engine.emit`` spans
+        #: (sweep + arg prep + async dispatch / post-sync emission);
         #: h2d_transfers counts scheduler-state uploads performed by
         #: decode dispatches — steady-state passes must add zero.
         self.stats = {"prefill_calls": 0, "prefill_s": 0.0,
@@ -1655,7 +1667,7 @@ class Engine:
         prompt_tokens = self._clamp_prompt(list(prompt_tokens),
                                            params.max_new_tokens)
         req = GenRequest(prompt_tokens=prompt_tokens, params=params,
-                         tenant=tenant, lane=lane)
+                         tenant=tenant, lane=lane, rid=next(self._rids))
         if self.tracer is not None:
             parent = self.tracer.current_span()
             if parent is not None:
@@ -2026,62 +2038,62 @@ class Engine:
                         ready = [r for r in ready if owns_slot(r)]
                         if not ready:
                             continue
-                        # pad to the full group: only (1, P) variants
-                        # ever compile per width
-                        G = 1 if len(ready) == 1 else P
-                        tokens = np.zeros((G, width), np.int32)
-                        offs = np.zeros(G, np.int32)
-                        lens = np.zeros(G, np.int32)
-                        temps = np.zeros(G, np.float32)
-                        top_ps = np.ones(G, np.float32)
-                        top_ks = np.zeros(G, np.int32)
-                        if paged:  # dummy rows all-OOB: writes drop
-                            slots_arg = np.full(
-                                (G, self._pages_per_slot), self._n_pages,
-                                np.int32)
-                        else:
-                            slots_arg = np.full(G, cfg.max_batch, np.int32)
-                        for row, r in enumerate(ready):
-                            chunk = r.prompt_tokens[
-                                r.prefill_offset:r.prefill_offset + width]
-                            tokens[row, :len(chunk)] = chunk
-                            offs[row] = r.prefill_offset
-                            lens[row] = len(chunk)
-                            temps[row] = r.params.temperature
-                            top_ps[row] = r.params.top_p
-                            top_ks[row] = r.params.top_k
-                            slots_arg[row] = self._tables[r.slot] \
-                                if paged else r.slot
-                        self._rng_step += 1
-                        dispatched = ready
-                        cw = self._chunk_window(int((offs + lens).max()),
-                                                width)
-                        call = (self._get_chunk_prefill(cw) if cw
-                                else fn)
-                        self._note_dispatch_shape("chunk", width, G, cw)
-                        c0 = time.perf_counter()
-                        self.goodput.note_dispatch(c0)
-                        w0 = time.time()  # gofrlint: allow(hot-path-purity) -- span timestamps use wall clock; once per chunk dispatch (the walk is synchronous by design)
-                        toks, self.k_cache, self.v_cache = call(
-                            self.params, jnp.asarray(tokens),
-                            self.k_cache, self.v_cache,
-                            jnp.asarray(slots_arg), jnp.asarray(offs),
-                            jnp.asarray(lens), np.int32(self._rng_step),
-                            jnp.asarray(temps), jnp.asarray(top_ps),
-                            jnp.asarray(top_ks),
-                            self._prefill_base_key)
-                        self.stats["prefill_calls"] += 1
-                        if self._native_chunk:
-                            self._note_view_avoided(G)
-                        c_dur = time.perf_counter() - c0
+                        pass_id = self.recorder.new_pass()
+                        with self.recorder.span("engine.chunk_walk",
+                                                pass_id) as sp:
+                            # pad to the full group: only (1, P)
+                            # variants ever compile per width
+                            G = 1 if len(ready) == 1 else P
+                            tokens = np.zeros((G, width), np.int32)
+                            offs = np.zeros(G, np.int32)
+                            lens = np.zeros(G, np.int32)
+                            temps = np.zeros(G, np.float32)
+                            top_ps = np.ones(G, np.float32)
+                            top_ks = np.zeros(G, np.int32)
+                            if paged:  # dummy rows all-OOB: writes drop
+                                slots_arg = np.full(
+                                    (G, self._pages_per_slot),
+                                    self._n_pages, np.int32)
+                            else:
+                                slots_arg = np.full(G, cfg.max_batch,
+                                                    np.int32)
+                            for row, r in enumerate(ready):
+                                chunk = r.prompt_tokens[
+                                    r.prefill_offset:
+                                    r.prefill_offset + width]
+                                tokens[row, :len(chunk)] = chunk
+                                offs[row] = r.prefill_offset
+                                lens[row] = len(chunk)
+                                temps[row] = r.params.temperature
+                                top_ps[row] = r.params.top_p
+                                top_ks[row] = r.params.top_k
+                                slots_arg[row] = self._tables[r.slot] \
+                                    if paged else r.slot
+                            self._rng_step += 1
+                            dispatched = ready
+                            cw = self._chunk_window(
+                                int((offs + lens).max()), width)
+                            call = (self._get_chunk_prefill(cw) if cw
+                                    else fn)
+                            self._note_dispatch_shape("chunk", width, G, cw)
+                            c0 = time.perf_counter()
+                            self.goodput.note_dispatch(c0)
+                            w0 = time.time()  # gofrlint: allow(hot-path-purity) -- span timestamps use wall clock; once per chunk dispatch (the walk is synchronous by design)
+                            toks, self.k_cache, self.v_cache = call(
+                                self.params, jnp.asarray(tokens),
+                                self.k_cache, self.v_cache,
+                                jnp.asarray(slots_arg), jnp.asarray(offs),
+                                jnp.asarray(lens), np.int32(self._rng_step),
+                                jnp.asarray(temps), jnp.asarray(top_ps),
+                                jnp.asarray(top_ks),
+                                self._prefill_base_key)
+                            self.stats["prefill_calls"] += 1
+                            if self._native_chunk:
+                                self._note_view_avoided(G)
+                        # the call is asynchronous: this times the
+                        # enqueue, not the chunk on the device
+                        c_dur = sp.t1 - c0
                         chunk_sig = self._sig_str("chunk", width, G, cw)
-                        if self.recorder.enabled:
-                            self.recorder.record_pass(
-                                "prefill_chunk", rows=len(ready),
-                                width=width, sig=chunk_sig,
-                                dur=round(c_dur, 6),
-                                view_avoided=self._native_chunk,
-                                queue_depth=self.waiting.qsize())
                         # goodput: a walker with a first token already
                         # emitted is re-prefilling KV it computed once
                         # (preemption recompute); pad rows are padding
@@ -2102,18 +2114,38 @@ class Engine:
                             r.device_s += c_dur / len(ready)
                             if r.first_token_at is not None or r.recovered:
                                 r.waste_recompute_s += c_dur / len(ready)
+                            # the enqueue of an asynchronous call, named
+                            # for what it is; the chunk's device time is
+                            # in the profiler's trace, not on this clock
                             self._req_event(
-                                r, "prefill", w0, w1,
+                                r, "prefill_dispatch", w0, w1,
                                 {"bucket": width,
                                  "offset": int(r.prefill_offset),
+                                 "pass_id": pass_id,
                                  "view_avoided": self._native_chunk})
                         toks_np = None
+                        t1 = None  # nobody waited: the walk goes on
                         for row, r in enumerate(ready):
                             r.prefill_offset += int(lens[row])
                             if r.prefill_offset >= len(r.prompt_tokens):
                                 if toks_np is None:
-                                    toks_np = np.asarray(toks)  # gofrlint: allow(hot-path-purity) -- this sync IS the walk's collect: finished walkers' first tokens cross to host here
+                                    with self.recorder.span(
+                                            "engine.chunk_wait",
+                                            pass_id) as wait:
+                                        toks_np = np.asarray(toks)  # gofrlint: allow(hot-path-purity) -- this sync IS the walk's collect: finished walkers' first tokens cross to host here
+                                    t1 = wait.t1
                                 self._finish_walk(r, int(toks_np[row]))
+                        if self.recorder.enabled:
+                            n = len(ready)
+                            self.recorder.record_pass(
+                                "prefill_chunk", pass_id, t0=c0, t1=t1,
+                                rids=[r.rid for r in ready],
+                                offsets=offs[:n].tolist(),
+                                lens=lens[:n].tolist(),
+                                width=width, window=cw, sig=chunk_sig,
+                                dur=round(c_dur, 6),
+                                view_avoided=self._native_chunk,
+                                queue_depth=self.waiting.qsize())
                         dispatched = []
         except Exception as exc:
             # fail the rows of the crashing dispatch; walkers that
@@ -2798,6 +2830,23 @@ class Engine:
                                  detail=detail)
         self._fail(req, req.reject.message)
 
+    def _admit_live(self, batch: list[GenRequest]) -> None:
+        """Drop what was cancelled while it waited, admit the rest."""
+        live = []
+        for r in batch:
+            if r.cancelled:  # dropped before prefill
+                if (r.pending_prefill and r.slot >= 0
+                        and self.active[r.slot] is r):
+                    # mid chunk-walk: free the slot too
+                    self._retire(r.slot)
+                elif r.finished_at is None:
+                    r.finished_at = time.time()
+                    r._emit(None)
+            else:
+                live.append(r)
+        if live:
+            self._admit_batch(live)
+
     def _admit_batch(self, reqs: list[GenRequest]) -> None:
         """Admit a burst: group by prompt bucket, prefill each group in
         chunks of ``prefill_batch`` with one device call per chunk.
@@ -2911,7 +2960,17 @@ class Engine:
         P = next(g for g in self._group_sizes() if g >= len(placed))
         self._rng_step += 1
         self._note_dispatch_shape("prefill", bucket, P)
-        start = time.perf_counter()
+        pass_id = self.recorder.new_pass()
+        with self.recorder.span("engine.prefill_dispatch", pass_id) as sp:
+            self._enqueue_prefill(bucket, P, placed, pass_id, sp.t0)
+
+    @hot_path
+    def _enqueue_prefill(self, bucket: int, P: int,
+                         placed: list[GenRequest], pass_id: int,
+                         start: float) -> None:
+        """Build the rows of one bucket prefill and enqueue it."""
+        cfg = self.config
+        paged = cfg.kv_layout == "paged"
         self.goodput.note_dispatch(start)
         try:
             tokens = np.zeros((P, bucket), np.int32)
@@ -2968,6 +3027,7 @@ class Engine:
             "t0": start,
             "wall0": time.time(),  # span timestamps use wall clock  # gofrlint: allow(hot-path-purity) -- span timestamps use wall clock; once per prefill dispatch, never per decode pass
             "bucket": bucket,
+            "pass_id": pass_id,
         })
 
     @hot_path
@@ -2976,13 +3036,22 @@ class Engine:
         slots for decode. Requests whose slot changed hands or that
         were re-dispatched since (epoch mismatch) are discarded — their
         current life owns its own prefill."""
-        if self._pending_prefills:
-            # collected slots flip pending -> decoding with new lengths
-            self._sched_dirty = True
+        if not self._pending_prefills:
+            self._note_device_idle()
+            return
+        with self.recorder.span("engine.prefill_collect"):
+            self._collect_pending_prefills()
+
+    @hot_path
+    def _collect_pending_prefills(self) -> None:
+        # collected slots flip pending -> decoding with new lengths
+        self._sched_dirty = True
         while self._pending_prefills:
             rec = self._pending_prefills.popleft()
             try:
-                toks_np = np.asarray(rec["toks"])  # gofrlint: allow(hot-path-purity) -- this sync IS the prefill collect: first tokens cross to host here by design
+                with self.recorder.span("engine.prefill_wait",
+                                        rec["pass_id"]) as wait:
+                    toks_np = np.asarray(rec["toks"])  # gofrlint: allow(hot-path-purity) -- this sync IS the prefill collect: first tokens cross to host here by design
             except Exception as exc:
                 for req, slot, epoch in zip(rec["placed"], rec["slots"],
                                             rec["epochs"]):
@@ -3001,7 +3070,7 @@ class Engine:
                 continue
             self._note_prefill_span(rec["t0"])
             now = time.time()  # gofrlint: allow(hot-path-purity) -- wall-clock span assembly at the prefill collect boundary, once per batch
-            pass_dur = time.perf_counter() - rec["t0"]
+            pass_dur = wait.t1 - rec["t0"]
             pass_share = pass_dur / max(1, len(rec["placed"]))
             # the dispatch's (bucket, group) signature: group size is
             # the padded batch axis the graph compiled for
@@ -3009,8 +3078,11 @@ class Engine:
                                         int(toks_np.shape[0]))
             if self.recorder.enabled:
                 self.recorder.record_pass(
-                    "prefill", rows=len(rec["placed"]),
-                    bucket=rec.get("bucket"), sig=prefill_sig,
+                    "prefill", rec["pass_id"], t0=rec["t0"], t1=wait.t1,
+                    rids=[r.rid for r in rec["placed"]],
+                    lens=[len(r.prompt_tokens) for r in rec["placed"]],
+                    bucket=rec.get("bucket"),
+                    group=int(toks_np.shape[0]), sig=prefill_sig,
                     dur=round(pass_dur, 6),
                     occupancy=sum(r is not None for r in self.active),
                     queue_depth=self.waiting.qsize())
@@ -3137,8 +3209,9 @@ class Engine:
         self._dev_last_reqs[slot] = None  # device-token lineage ends here
         self._sched_dirty = True
         req.finished_at = time.time()
-        self._finalize_obs(req)  # before the terminal None: a drained
-        #                          stream implies spans are exported
+        with self.recorder.span("engine.finalize"):
+            self._finalize_obs(req)  # before the terminal None: a drained
+            #                          stream implies spans are exported
         req._emit(None)
         self.active[slot] = None
         self.lengths[slot] = 0
@@ -3267,6 +3340,21 @@ class Engine:
 
     @hot_path
     def _decode_dispatch(self) -> None:
+        # the id is taken before anything is known to decode, so the
+        # span and the profiler's annotation carry it; a dispatch that
+        # finds no active row leaves an id with spans and no record
+        pass_id = self.recorder.new_pass()
+        with self.recorder.span("engine.decode_dispatch", pass_id) as sp:
+            rec = self._enqueue_decode(pass_id)
+        if rec is not None:
+            rec["disp"] = sp.t1 - sp.t0
+            self.stats["dispatch_s"] += rec["disp"]
+
+    @hot_path
+    def _enqueue_decode(self, pass_id: int) -> dict | None:
+        """Sweep, make room, sync the scheduler arrays if an event
+        dirtied them, enqueue one decode pass; the pending record, or
+        None when no row decodes."""
         cfg = self.config
         T = self._tokens_per_pass
         paged = cfg.kv_layout == "paged"
@@ -3291,12 +3379,11 @@ class Engine:
                 if not self._ensure_headroom(i, rows):
                     self._preempt(i)  # pool can't hold even this one now
 
-        host0 = time.perf_counter()
         if self._sched_dirty:
             self._sync_decode_state()
         active_mask = self._active_np
         if not active_mask.any():
-            return
+            return None
         st = self._dev_sched
 
         # steps whose cache write would land past max_seq-1 are dropped
@@ -3342,18 +3429,23 @@ class Engine:
             # device output next pass: their use_prev flips — one more
             # sync, then steady state
             self._sched_dirty = True
-        disp = time.perf_counter() - host0
-        self._pending.append({
+        rows = np.flatnonzero(active_mask)
+        rec = {
             "toks": step_tokens,
             "reqs": list(self.active),
             "mask": active_mask,
             "valid": valid,
             "t0": start,
-            "disp": disp,
+            "pass_id": pass_id,
+            # what the pass works on, for its record: request id and
+            # context length after the pass (lengths advanced above)
+            "rids": [self.active[i].rid for i in rows],
+            "ctx": self.lengths[rows].tolist(),
             "win": win,
             "h2d": self.stats["h2d_transfers"] - h2d0,
-        })
-        self.stats["dispatch_s"] += disp
+        }
+        self._pending.append(rec)
+        return rec
 
     @hot_path
     def _decode_collect(self) -> None:
@@ -3368,80 +3460,89 @@ class Engine:
             # typed-retryable branch, never the bit-identical replay
             self.faults.trip("nan_logits")
         rec = self._pending.popleft()
-        step_np = np.asarray(rec["toks"])  # [T, B] — blocks on device  # gofrlint: allow(hot-path-purity) -- this sync IS the decode collect: the token download is the pass's one sanctioned device read
+        pass_id = rec["pass_id"]
+        with self.recorder.span("engine.decode_wait", pass_id) as wait:
+            step_np = np.asarray(rec["toks"])  # [T, B] — blocks on device  # gofrlint: allow(hot-path-purity) -- this sync IS the decode collect: the token download is the pass's one sanctioned device read
         # decode_s = wall time with a decode pass in flight (dispatch →
         # sync complete), accumulated as a UNION of spans — consecutive
         # passes overlap (N+1 dispatches before N collects), and host/
         # prefill work overlapping a pass still counts as decode here,
         # so the bench's residual host_s is true dead time
-        end = time.perf_counter()
-        busy = end - max(rec["t0"], self._decode_busy_until)
-        self._decode_busy_until = end
-        self.stats["decode_passes"] += 1
-        self.stats["decode_s"] += busy
-        occupancy = int(rec["mask"].sum())
-        if self.metrics is not None:
-            self.metrics.record_histogram("app_tpu_execute_seconds", busy)  # gofrlint: allow(hot-path-purity) -- per-pass observation at the collect sync point, host floats already paid for
-            self.metrics.record_histogram("app_engine_batch_occupancy",  # gofrlint: allow(hot-path-purity) -- per-pass observation at the collect sync point, host floats already paid for
-                                          float(occupancy))
-        self._step_count += 1
-        # KV watermark BEFORE retires zero the finishing slots: the
-        # dispatch already advanced lengths, so this is the pass peak
-        self._update_kv_watermarks()
-        emitted = 0
-        credited = 0  # rows whose request actually kept this pass
-        share = busy / occupancy if occupancy else 0.0
-        for i, req in enumerate(rec["reqs"]):
-            if req is None or not rec["mask"][i]:
-                continue
-            if self.active[i] is not req or req.finished_at is not None:
-                continue  # retired/preempted since dispatch: discard
-            # device-time attribution: this pass's busy span split
-            # evenly across its occupied rows — the per-tenant
-            # device_seconds the usage ledger accounts at retire
-            req.device_s += share
-            credited += 1
-            done = False
-            for k in range(int(rec["valid"][i])):
-                token = int(step_np[k, i])
-                if self.faults is not NO_FAULTS and \
-                        self.faults.trip("logit_corrupt", req.tenant):
-                    token = self._corrupt_token(token)
-                req.generated.append(token)
-                req._emit(token)
-                self.total_generated += 1
-                emitted += 1
-                if self._finished(req, token):
-                    done = True
-                    break
-            if done or rec["valid"][i] < self._tokens_per_pass:
-                self._retire(i)
-        collect = time.perf_counter() - end
+        end = wait.t1
+        with self.recorder.span("engine.emit", pass_id) as emit:
+            busy = end - max(rec["t0"], self._decode_busy_until)
+            self._decode_busy_until = end
+            self.stats["decode_passes"] += 1
+            self.stats["decode_s"] += busy
+            occupancy = int(rec["mask"].sum())
+            if self.metrics is not None:
+                self.metrics.record_histogram("app_tpu_execute_seconds", busy)  # gofrlint: allow(hot-path-purity) -- per-pass observation at the collect sync point, host floats already paid for
+                self.metrics.record_histogram("app_engine_batch_occupancy",  # gofrlint: allow(hot-path-purity) -- per-pass observation at the collect sync point, host floats already paid for
+                                              float(occupancy))
+            self._step_count += 1
+            # KV watermark BEFORE retires zero the finishing slots: the
+            # dispatch already advanced lengths, so this is the pass peak
+            self._update_kv_watermarks()
+            emitted = 0
+            credited = 0  # rows whose request actually kept this pass
+            share = busy / occupancy if occupancy else 0.0
+            for i, req in enumerate(rec["reqs"]):
+                if req is None or not rec["mask"][i]:
+                    continue
+                if self.active[i] is not req or req.finished_at is not None:
+                    continue  # retired/preempted since dispatch: discard
+                # device-time attribution: this pass's busy span split
+                # evenly across its occupied rows — the per-tenant
+                # device_seconds the usage ledger accounts at retire
+                req.device_s += share
+                credited += 1
+                done = False
+                for k in range(int(rec["valid"][i])):
+                    token = int(step_np[k, i])
+                    if self.faults is not NO_FAULTS and \
+                            self.faults.trip("logit_corrupt", req.tenant):
+                        token = self._corrupt_token(token)
+                    req.generated.append(token)
+                    req._emit(token)
+                    self.total_generated += 1
+                    emitted += 1
+                    if self._finished(req, token):
+                        done = True
+                        break
+                if done or rec["valid"][i] < self._tokens_per_pass:
+                    self._retire(i)
+        collect = emit.t1 - end
         self.stats["collect_s"] += collect
-        # goodput: rows that kept the pass are useful; empty slots,
-        # pending-prefill sentinels and retired requests riding out a
-        # pipelined pass are padding waste
-        self.goodput.add_decode(busy, credited, self.config.max_batch)
-        # fit the controller's sec/token price from the same busy span
-        # the goodput ledger bills — an accepted draft token is worth
-        # exactly what a plain-decode token costs
-        self._spec_ctrl.note_decode(busy, emitted)
-        decode_sig = self._sig_str("decode", rec.get("win", 0))
-        self._note_pass_cost("decode", decode_sig, busy,
-                             rows=credited, tokens=emitted)
-        if self.recorder.enabled:
-            # the pass record: everything here is a host int/float the
-            # collect already computed — no device reads beyond the
-            # token sync that IS the collect
-            self.recorder.record_pass(
-                "decode", dur=round(busy, 6),
-                dispatch_s=round(rec.get("disp", 0.0), 6),
-                collect_s=round(collect, 6), occupancy=occupancy,
-                sig=decode_sig,
-                queue_depth=self.waiting.qsize(), tokens=emitted,
-                h2d=rec.get("h2d", 0),
-                preemptions=self.stats["preemptions"])
-        self._note_device_idle()
+        with self.recorder.span("engine.planes", pass_id):
+            # goodput: rows that kept the pass are useful; empty slots,
+            # pending-prefill sentinels and retired requests riding out a
+            # pipelined pass are padding waste
+            self.goodput.add_decode(busy, credited, self.config.max_batch)
+            # fit the controller's sec/token price from the same busy span
+            # the goodput ledger bills — an accepted draft token is worth
+            # exactly what a plain-decode token costs
+            self._spec_ctrl.note_decode(busy, emitted)
+            decode_sig = self._sig_str("decode", rec.get("win", 0))
+            self._note_pass_cost("decode", decode_sig, busy,
+                                 rows=credited, tokens=emitted)
+            if self.recorder.enabled:
+                # the pass record: everything here is a host int/float the
+                # collect already computed — no device reads beyond the
+                # token sync that IS the collect
+                self.recorder.record_pass(
+                    "decode", rec["pass_id"], t0=rec["t0"], t1=end,
+                    rids=rec["rids"], ctx=rec["ctx"],
+                    steps=self._tokens_per_pass, win=rec.get("win", 0),
+                    dur=round(busy, 6),
+                    # the durations of this pass's engine.decode_dispatch
+                    # and engine.emit spans
+                    dispatch_s=round(rec.get("disp", 0.0), 6),
+                    collect_s=round(collect, 6), occupancy=occupancy,
+                    sig=decode_sig,
+                    queue_depth=self.waiting.qsize(), tokens=emitted,
+                    h2d=rec.get("h2d", 0),
+                    preemptions=self.stats["preemptions"])
+            self._note_device_idle()
 
     # ------------------------------------------------- speculative decode
     def _get_spec_verify(self) -> Callable:
@@ -3757,9 +3858,11 @@ class Engine:
         if self._native_verify:
             self._note_view_avoided(b)
         self._note_pass("spec_passes", start)
-        spec_dur = time.perf_counter() - start
+        t1 = time.perf_counter()
+        spec_dur = t1 - start
         w1 = time.time()
         pass_drafted = pass_accepted = pass_rows = 0
+        rids: list[int] = []  # the rows verified, for the pass record
         row_stats: list[tuple[int, int]] = []  # (drafted, accepted)
         live = sum(1 for r in self.active
                    if r is not None and not r.pending_prefill)
@@ -3768,6 +3871,7 @@ class Engine:
             if req is None or req.pending_prefill:
                 continue
             req.device_s += verify_share
+            rids.append(req.rid)
             tree = trees.get(i)
             n_drafted = tree.n_draft if tree is not None else 0
             n_acc = min(int(accepted[i]), n_drafted)
@@ -3839,7 +3943,8 @@ class Engine:
         self._update_kv_watermarks()
         if self.recorder.enabled:
             self.recorder.record_pass(
-                "spec_verify", rows=pass_rows, drafted=pass_drafted,
+                "spec_verify", t0=start, t1=t1, rids=rids,
+                drafted=pass_drafted,
                 accepted=pass_accepted,
                 dur=round(time.perf_counter() - start, 6),
                 occupancy=pass_rows, sig=spec_sig,
@@ -4056,27 +4161,21 @@ class Engine:
                         if not (r.pending_prefill and r.slot >= 0
                                 and self.active[r.slot] is r))
                     take = free - needing_slots
-                    if take > 0:
-                        popped = self.waiting.pop_batch(
-                            take,
-                            first_wait_s=0.0 if (busy or batch) else 0.05,
-                            drain_wait_s=0.0)
-                        batch = batch + (popped or [])
-                    if batch:
-                        live = []
-                        for r in batch:
-                            if r.cancelled:  # dropped before prefill
-                                if (r.pending_prefill and r.slot >= 0
-                                        and self.active[r.slot] is r):
-                                    # mid chunk-walk: free the slot too
-                                    self._retire(r.slot)
-                                elif r.finished_at is None:
-                                    r.finished_at = time.time()
-                                    r._emit(None)
-                            else:
-                                live.append(r)
-                        if live:
-                            self._admit_batch(live)
+                    if take > 0 and not (busy or batch):
+                        # the one pop that may block: its span is the
+                        # loop waiting for requests, not host work
+                        with self.recorder.span("engine.wait"):
+                            batch = self.waiting.pop_batch(
+                                take, first_wait_s=0.05,
+                                drain_wait_s=0.0) or []
+                        take = 0
+                    if take > 0 or batch:
+                        with self.recorder.span("engine.admit"):
+                            if take > 0:
+                                batch = batch + (self.waiting.pop_batch(
+                                    take, first_wait_s=0.0,
+                                    drain_wait_s=0.0) or [])
+                            self._admit_live(batch)
                 if any(r is not None for r in self.active):
                     proposals: dict[int, Any] = {}  # slot -> DraftTree
                     decoding = 0
@@ -4109,7 +4208,8 @@ class Engine:
                     # final tokens reach their streams
                     self._drain_pending()
                     self._collect_prefills()
-                self._update_gauges()
+                with self.recorder.span("engine.gauges"):
+                    self._update_gauges()
             # clean stop with work still in flight: the tokens are
             # real — emit them before failing what remains
             self._drain_pending()

@@ -29,24 +29,118 @@ from typing import Any
 from .events import NO_EVENTS
 
 
-class FlightRecorder:
-    """Fixed-size ring of per-pass records plus a short log of retired
-    requests' event trails — the engine's black box. Served as JSON at
-    ``/debug/engine``, summarized in ``Engine.health_check()``, dumped
-    through the logger when the hot loop crashes.
+#: logs of the engines built in this process, newest last (see
+#: :func:`flight_logs`); bounded so a test suite's hundreds of engines
+#: leave at most this many rings behind
+_KEPT_LOGS = 8
+_logs: deque = deque(maxlen=_KEPT_LOGS)
 
-    Writer side (the engine thread) only ever appends plain dicts to
-    bounded deques; reader side (``snapshot``) copies under the GIL.
-    ``size <= 0`` disables recording entirely.
+#: salt of ``request_summary``'s ``prompt_hash``: fixed, so a reader
+#: that holds the prompt ids (a load generator's own record) can
+#: recompute the digest and join its record to the engine's
+PROMPT_HASH_SALT = "gofr-flight-v1"
+
+
+class FlightLog:
+    """What a :class:`FlightRecorder` writes, and all that outlives its
+    engine: the pass ring, the retired-request ring, the span ring and
+    one clock anchor. Plain data — it holds no callback and no
+    reference to the engine, so keeping it keeps no weights and no KV
+    pool alive.
+
+    ``passes`` and ``requests`` hold dicts; ``spans`` holds
+    ``(name, t0, t1, pass_id)`` tuples on ``time.perf_counter()``.
+    ``anchor`` is ``(time.time(), time.perf_counter())`` read together
+    at creation: request timestamps are wall clock, spans and pass
+    ``t0``/``t1`` monotonic, and the anchor puts both on one axis."""
+
+    def __init__(self, passes: int, requests: int, spans: int) -> None:
+        self.anchor = (time.time(), time.perf_counter())
+        self.passes: deque = deque(maxlen=max(1, passes))
+        self.requests: deque = deque(maxlen=max(1, requests))
+        self.spans: deque = deque(maxlen=max(1, spans))
+
+    def mono(self, t: float) -> float:
+        """A ``time.time()`` reading on the ``perf_counter`` axis."""
+        return self.anchor[1] + (t - self.anchor[0])
+
+
+def flight_logs() -> list[FlightLog]:
+    """The flight logs of the engines built in this process, newest
+    last, at most ``_KEPT_LOGS`` of them. A log stays readable here
+    after its engine is freed (a benchmark frees the engine to make
+    room on the device and reads the spans afterwards)."""
+    return list(_logs)
+
+
+class _Span:
+    """One open span of :meth:`FlightRecorder.span`. ``t0``/``t1`` are
+    the ``perf_counter`` readings at its edges, for the caller that
+    needs the same instants (so the clock is read once, not twice)."""
+
+    __slots__ = ("_rec", "name", "pass_id", "t0", "t1", "_ann")
+
+    def __init__(self, rec: "FlightRecorder", name: str,
+                 pass_id: int | None) -> None:
+        self._rec = rec
+        self.name = name
+        self.pass_id = pass_id
+
+    def __enter__(self) -> "_Span":
+        rec = self._rec
+        if rec.enabled:
+            if self.pass_id is None and rec._open:
+                self.pass_id = rec._open[-1].pass_id  # the parent's
+            rec._open.append(self)
+            # a flag test unless a profile is being taken; then the
+            # span lands in the trace, on the device events' clock
+            self._ann = (rec._annotation(self.name)
+                         if self.pass_id is None else
+                         rec._annotation(self.name, pass_id=self.pass_id))
+            self._ann.__enter__()
+        self.t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, *exc: Any) -> None:
+        self.t1 = time.perf_counter()
+        rec = self._rec
+        if rec.enabled:
+            self._ann.__exit__(*exc)
+            rec._open.pop()
+            rec.log.spans.append((self.name, self.t0, self.t1,
+                                  self.pass_id))
+
+
+class FlightRecorder:
+    """The engine's black box and its span log: a fixed ring of
+    per-pass records (what each pass worked on, when it was enqueued
+    and when its result was on the host), a ring of the engine loop's
+    phase spans, and a short log of retired requests' event trails.
+    Served as JSON at ``/debug/engine``, summarized in
+    ``Engine.health_check()``, dumped through the logger when the hot
+    loop crashes. The three rings live in ``self.log``
+    (:class:`FlightLog`), which :func:`flight_logs` keeps reachable
+    after the engine is gone.
+
+    Writer side (the engine thread) only ever appends plain dicts and
+    tuples to bounded deques; reader side (``snapshot``) copies under
+    the GIL. ``size <= 0`` disables recording entirely.
     """
 
-    def __init__(self, size: int = 256, request_logs: int = 32) -> None:
+    SPAN_RING = 32768
+
+    def __init__(self, size: int = 4096, request_logs: int = 512) -> None:
         self.enabled = size > 0
         self.size = max(0, int(size))
-        self._passes: deque = deque(maxlen=max(1, self.size))
-        self._requests: deque = deque(maxlen=max(1, int(request_logs)))
-        self._seq = 0
+        self.log = FlightLog(self.size, int(request_logs), self.SPAN_RING)
+        self._seq = 0          # pass ids handed out
+        self._recorded = 0     # pass records written
         self._by_kind: dict[str, int] = {}
+        self._open: list[_Span] = []   # the engine thread's open spans
+        if self.enabled:
+            from jax.profiler import TraceAnnotation
+            self._annotation = TraceAnnotation
+            _logs.append(self.log)
         #: optional () -> goodput summary (GoodputMeter.summary); the
         #: engine wires its meter here so fleet_summary carries the
         #: waste breakdown and the leader can say WHY a host is slow
@@ -67,31 +161,57 @@ class FlightRecorder:
         self.integrity_source: Any = None
 
     # ------------------------------------------------------------ writers
-    def record_pass(self, kind: str, **fields: Any) -> None:
+    def new_pass(self) -> int:
+        """The id of a pass about to be enqueued: its spans carry it
+        from here on, its record takes it at :meth:`record_pass`."""
+        self._seq += 1
+        return self._seq
+
+    def span(self, name: str, pass_id: int | None = None) -> _Span:
+        """Context manager around one phase of the engine loop: on exit
+        ``(name, t0, t1, pass_id)`` joins the span ring, and while a
+        JAX profile is being taken the same span is in that trace. A
+        span opened inside another inherits its ``pass_id`` unless
+        given one. Disabled, it records and annotates nothing and only
+        reads the clock for its caller."""
+        return _Span(self, name, pass_id)
+
+    def record_pass(self, kind: str, pass_id: int | None = None,
+                    **fields: Any) -> None:
         if not self.enabled:
             return
-        self._seq += 1
-        rec = {"seq": self._seq, "kind": kind, "t": time.time()}
+        rec = {"pass_id": self.new_pass() if pass_id is None else pass_id,
+               "kind": kind, "t": time.time()}
         rec.update(fields)
-        self._passes.append(rec)
+        self.log.passes.append(rec)
+        self._recorded += 1
         self._by_kind[kind] = self._by_kind.get(kind, 0) + 1
 
     def record_request(self, summary: dict) -> None:
         if self.enabled:
-            self._requests.append(summary)
+            self.log.requests.append(summary)
 
     # ------------------------------------------------------------ readers
     def snapshot(self, n: int | None = None) -> dict:
-        passes = list(self._passes)
+        passes = list(self.log.passes)
+        spans = list(self.log.spans) if self.enabled else []
         if n is not None and n > 0:
-            passes = passes[-n:]
+            passes, spans = passes[-n:], spans[-n:]
         return {"enabled": self.enabled, "ring_size": self.size,
-                "passes_recorded": self._seq, "passes": passes,
-                "requests": list(self._requests)}
+                "passes_recorded": self._recorded,
+                "passes": passes,
+                "requests": list(self.log.requests),
+                "anchor": {"wall": self.log.anchor[0],
+                           "monotonic": self.log.anchor[1]},
+                "spans": [{"name": name, "t0": t0, "t1": t1,
+                           "pass_id": pid}
+                          for name, t0, t1, pid in spans]}
 
     def summary(self) -> dict:
-        last = self._passes[-1] if self._passes else None
-        out = {"passes_recorded": self._seq, "by_kind": dict(self._by_kind)}
+        passes = self.log.passes
+        last = passes[-1] if passes else None
+        out = {"passes_recorded": self._recorded,
+               "by_kind": dict(self._by_kind)}
         if last is not None:
             out["last_pass_kind"] = last["kind"]
             out["last_pass_age_s"] = round(time.time() - last["t"], 3)
@@ -103,8 +223,8 @@ class FlightRecorder:
         occupancy, last queue depth, tokens/s — computed over the pass
         ring, on the heartbeat thread, from fields already recorded.
         The leader derives fleet skew and straggler gauges from these."""
-        passes = list(self._passes)
-        out: dict = {"passes_recorded": self._seq,
+        passes = list(self.log.passes)
+        out: dict = {"passes_recorded": self._recorded,
                      "by_kind": dict(self._by_kind)}
         durs = sorted(p["dur"] for p in passes
                       if isinstance(p.get("dur"), (int, float)))
@@ -163,7 +283,9 @@ class FlightRecorder:
         if logger is None or not self.enabled:
             return
         try:
-            text = json.dumps(self.snapshot(), default=str)
+            # the newest passes and spans: the text is cut below, and
+            # what led up to the crash is at the rings' ends
+            text = json.dumps(self.snapshot(32), default=str)
             logger.error(f"engine flight recorder ({reason or 'dump'}): "
                          f"{text[:16384]}")
         except Exception:
@@ -173,7 +295,11 @@ class FlightRecorder:
 def request_summary(req: Any) -> dict:
     """Flight-recorder entry for a retired request — plain host fields."""
     return {
+        "rid": getattr(req, "rid", None),
         "prompt_tokens": len(req.prompt_tokens),
+        # joins this entry to a client's own record of the same prompt
+        "prompt_hash": salted_token_hash(req.prompt_tokens,
+                                         PROMPT_HASH_SALT),
         "generated": len(req.generated),
         "slot": req.slot,
         "tenant": getattr(req, "tenant", None),
@@ -701,7 +827,8 @@ def emit_engine_spans(tracer: Any, req: Any) -> None:
     trace_id, parent_id = trace
     end = req.finished_at or time.time()
     status = "OK" if req.error is None else f"ERROR: {req.error}"
-    attrs = {"prompt_tokens": len(req.prompt_tokens),
+    attrs = {"rid": getattr(req, "rid", None),
+             "prompt_tokens": len(req.prompt_tokens),
              "generated_tokens": len(req.generated),
              "slot": req.slot, "cancelled": req.cancelled}
     if getattr(req, "tenant", None):

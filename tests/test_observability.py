@@ -198,11 +198,196 @@ def test_flight_recorder_ring_and_request_logs():
     assert len(snap["passes"]) == 4                    # ring bounded
     assert [p["tokens"] for p in snap["passes"]] == [6, 7, 8, 9]
     assert snap["passes_recorded"] == 10
-    assert rec.snapshot(2)["passes"][-1]["seq"] == 10  # last-N works
+    assert rec.snapshot(2)["passes"][-1]["pass_id"] == 10  # last-N works
     assert rec.summary()["by_kind"] == {"decode": 10}
     disabled = FlightRecorder(size=0)
     disabled.record_pass("decode")
     assert disabled.snapshot()["passes"] == []
+
+
+# ------------------------------------------- span log and pass records
+SPAN_TABLE = {
+    "engine.wait", "engine.admit", "engine.prefill_dispatch",
+    "engine.chunk_walk", "engine.chunk_wait", "engine.decode_dispatch",
+    "engine.decode_wait", "engine.emit", "engine.finalize",
+    "engine.planes", "engine.prefill_collect", "engine.prefill_wait",
+    "engine.gauges"}
+
+
+@pytest.fixture(scope="module")
+def served():
+    """A tiny paged engine that has served two bucket prompts and one
+    long enough to walk chunks (29 tokens through width-8 chunks), and
+    the (rid, lengths-after-the-pass) the test saw each decode pass
+    enqueue, to hold the records' ``ctx`` against."""
+    eng = demo_llama_engine(EngineConfig(
+        max_batch=4, max_seq=128, prefill_buckets=(8,), seed=1,
+        kv_layout="paged", page_size=16, paged_attention="view"))
+    seen = {}
+    enqueue = eng._enqueue_decode
+
+    def watching(pass_id):
+        rec = enqueue(pass_id)
+        if rec is not None:
+            rows = [i for i, on in enumerate(rec["mask"]) if on]
+            seen[pass_id] = ([eng.active[i].rid for i in rows],
+                             [int(eng.lengths[i]) for i in rows])
+        return rec
+
+    eng._enqueue_decode = watching
+    reqs = _run(eng, [[1, 2, 3], [4, 5, 6, 7], list(range(1, 30))], 10)
+    return eng, reqs, seen
+
+
+def test_every_pass_record_says_when_and_what(served):
+    eng, reqs, seen = served
+    passes = eng.recorder.snapshot()["passes"]
+    assert {p["kind"] for p in passes} == {"prefill", "prefill_chunk",
+                                           "decode"}
+    rids = {r.rid for r in reqs}
+    assert rids == {1, 2, 3}
+    for p in passes:
+        assert isinstance(p["t0"], float) and p["pass_id"] >= 1
+        assert p["t1"] is None or p["t0"] <= p["t1"]
+        assert p["rids"] and set(p["rids"]) <= rids
+    assert len({p["pass_id"] for p in passes}) == len(passes)
+    decode = [p for p in passes if p["kind"] == "decode"]
+    assert decode and all(p["t1"] is not None for p in decode)
+    for p in decode:  # the rows' lengths after the pass, as enqueued
+        assert (p["rids"], p["ctx"]) == seen[p["pass_id"]]
+        assert p["steps"] == eng._tokens_per_pass
+    chunks = [p for p in passes if p["kind"] == "prefill_chunk"]
+    walked = sorted((p["offsets"][0], p["lens"][0]) for p in chunks)
+    assert walked == [(0, 8), (8, 8), (16, 8), (24, 5)]
+    # only the walk's last chunk is waited for: its first token is read
+    assert [p["t1"] is not None for p in
+            sorted(chunks, key=lambda p: p["offsets"][0])] \
+        == [False, False, False, True]
+    bucket = [p for p in passes if p["kind"] == "prefill"]
+    assert sorted(n for p in bucket for n in p["lens"]) == [3, 4]
+    assert all(p["bucket"] == 8 and p["group"] >= len(p["rids"])
+               for p in bucket)
+
+
+def test_request_log_carries_rid_and_a_prompt_digest(served):
+    eng, reqs, _ = served
+    from gofr_tpu.serving.observability import (PROMPT_HASH_SALT,
+                                                salted_token_hash)
+    by_rid = {e["rid"]: e for e in eng.recorder.snapshot()["requests"]}
+    for r in reqs:
+        assert by_rid[r.rid]["prompt_hash"] == salted_token_hash(
+            r.prompt_tokens, PROMPT_HASH_SALT)
+    walker = by_rid[reqs[2].rid]
+    # a chunk's request event times the enqueue and says so
+    assert {e["name"] for e in walker["events"]} == {"prefill_dispatch"}
+    assert all(e["pass_id"] >= 1 for e in walker["events"])
+
+
+def test_every_phase_of_the_loop_is_a_span(served):
+    eng, _, _ = served
+    spans = list(eng.recorder.log.spans)
+    assert {s[0] for s in spans} == SPAN_TABLE
+    assert all(t0 <= t1 for _, t0, t1, _ in spans)
+    # one thread wrote them: taken outermost first, a span lies inside
+    # the one open before it or after its end — never across an edge
+    open_spans: list = []
+    top = []
+    for span in sorted(spans, key=lambda s: (s[1], -s[2])):
+        while open_spans and open_spans[-1][2] <= span[1]:
+            open_spans.pop()
+        if open_spans:
+            assert span[2] <= open_spans[-1][2], (span, open_spans[-1])
+        else:
+            top.append(span)
+        open_spans.append(span)
+    assert all(a[2] <= b[1] for a, b in zip(top, top[1:]))
+    # a pass's spans carry its id, and a child with none its parent's
+    passes = {p["pass_id"]: p["kind"]
+              for p in eng.recorder.snapshot()["passes"]}
+    kinds = {"engine.prefill_dispatch": "prefill",
+             "engine.prefill_wait": "prefill",
+             "engine.chunk_walk": "prefill_chunk",
+             "engine.chunk_wait": "prefill_chunk",
+             "engine.decode_wait": "decode", "engine.emit": "decode",
+             "engine.planes": "decode"}
+    for name, _, _, pass_id in spans:
+        if name in kinds:
+            assert passes[pass_id] == kinds[name], (name, pass_id)
+    by_pass: dict = {}
+    for name, _, _, pass_id in spans:
+        by_pass.setdefault(pass_id, set()).add(name)
+    for pass_id, kind in passes.items():
+        if kind == "decode":
+            assert {"engine.decode_dispatch", "engine.decode_wait",
+                    "engine.emit", "engine.planes"} <= by_pass[pass_id]
+    # the decode record's host times are its spans' durations
+    dur = {(n, pid): t1 - t0 for n, t0, t1, pid in spans}
+    for p in eng.recorder.snapshot()["passes"]:
+        if p["kind"] == "decode":
+            assert p["dispatch_s"] == round(
+                dur["engine.decode_dispatch", p["pass_id"]], 6)
+            assert p["collect_s"] >= round(
+                dur["engine.emit", p["pass_id"]], 6)
+
+
+def test_flight_log_outlives_its_engine():
+    import gc
+    import weakref
+
+    from gofr_tpu.serving.observability import flight_logs
+    eng = demo_llama_engine(EngineConfig(max_batch=2, max_seq=64, seed=4))
+    _run(eng, [[1, 2, 3]], 6)
+    log = eng.recorder.log
+    assert flight_logs()[-1] is log
+    n_spans, n_passes = len(log.spans), len(log.passes)
+    assert n_spans and n_passes and len(log.requests) == 1
+    # wall-clock request stamps and monotonic spans share one axis
+    entry = log.requests[0]
+    first_wait = min(t0 for name, t0, _, _ in log.spans)
+    assert abs(log.mono(entry["submitted_at"]) - first_wait) < 60.0
+    dead = weakref.ref(eng)
+    del eng
+    gc.collect()
+    assert dead() is None, "the kept log holds its engine alive"
+    assert flight_logs()[-1] is log
+    assert (len(log.spans), len(log.passes)) == (n_spans, n_passes)
+    assert len(flight_logs()) <= 8
+
+
+def test_flight_recorder_size_zero_records_nothing():
+    from gofr_tpu.serving.observability import flight_logs
+    kept = flight_logs()
+    eng = demo_llama_engine(EngineConfig(
+        max_batch=2, max_seq=64, seed=4, flight_recorder_size=0))
+    reqs = _run(eng, [[1, 2, 3]], 6)
+    assert len(reqs[0].generated) == 6
+    log = eng.recorder.log
+    assert not log.spans and not log.passes and not log.requests
+    assert flight_logs() == kept           # and is not kept
+    with eng.recorder.span("engine.admit") as sp:
+        pass
+    assert sp.t0 <= sp.t1 and not log.spans
+    assert eng.stats["dispatch_s"] > 0.0   # the engine's own timings hold
+
+
+def test_spans_land_in_a_profile_of_the_process(tmp_path):
+    """The same spans are in any trace taken of the process: the
+    recorder enters a TraceAnnotation beside each ring entry."""
+    from jax.profiler import ProfileData
+    rec = FlightRecorder(size=8)
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        with rec.span("engine.decode_dispatch", rec.new_pass()):
+            with rec.span("engine.finalize"):
+                pass
+    finally:
+        jax.profiler.stop_trace()
+    (path,) = tmp_path.glob("plugins/profile/*/*.xplane.pb")
+    names = {e.name for plane in ProfileData.from_file(str(path)).planes
+             for line in plane.lines for e in line.events}
+    assert {"engine.decode_dispatch", "engine.finalize"} <= names
+    assert [(s[0], s[3]) for s in rec.log.spans] == [
+        ("engine.finalize", 1), ("engine.decode_dispatch", 1)]
 
 
 def test_engine_health_and_crash_dump_carry_flight_summary():
@@ -311,7 +496,12 @@ def test_e2e_debug_engine_returns_pass_records(obs_app):
     assert llm["flight"]["passes"], "no pass records served"
     assert len(llm["flight"]["passes"]) <= 8
     last = llm["flight"]["passes"][-1]
-    assert {"seq", "kind", "t"} <= set(last)
+    assert {"pass_id", "kind", "t", "t0"} <= set(last)
+    spans = llm["flight"]["spans"]
+    assert 0 < len(spans) <= 8                         # ?n= limits them
+    assert {"name", "t0", "t1", "pass_id"} == set(spans[-1])
+    assert spans[-1]["name"].startswith("engine.")
+    assert {"wall", "monotonic"} == set(llm["flight"]["anchor"])
 
 
 def test_e2e_metrics_expose_engine_surface(obs_app):

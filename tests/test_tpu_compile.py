@@ -11,9 +11,27 @@ kernels of the serving path compiling for v5e at the Llama-3.2-1B
 widths (Hq 32 / Hkv 8 / head_dim 64 / page 64), about two seconds a
 case, at no chip time. A compile that passes is not a chip run:
 ``chip_smoke.py`` is.
+
+The last cases compile the ENGINE's own step programs — the paged decode
+step, one bucket prefill, one chunk prefill — for a one-layer model at
+SmolLM2-1.7B's published widths, and hold their names against
+``benchmarks/data/trace_names.json``: the benchmark finds the step
+programs and the attention kernels in the profiler's trace by the names
+the compiler gives them today (``jit__decode_sample``, ``jit_fused``,
+``%closed_call.N = ... custom-call(``). A ``name=`` on a
+``pallas_call``, a ``jax.named_scope`` around one, or a renamed jitted
+function changes those names; this fails then, here, instead of two
+roofline metrics reading nothing on the chip.
+
+The topology is described in a fixture, never at import: only one
+process may load the TPU's library, and every test worker imports
+every test file (on-chip-measurement guide §2).
 """
 
+import importlib.util
 import os
+import re
+from pathlib import Path
 
 os.environ.setdefault("TPU_LOG_DIR", "disabled")  # else it logs to /tmp
 # libtpu admits one process at a time behind /tmp/libtpu_lockfile; no
@@ -33,43 +51,43 @@ from gofr_tpu.ops.paged_attention import (paged_chunk_attention_pallas,
                                           paged_tree_attention_pallas)
 from gofr_tpu.ops.paged_kv import head_pack, scale_width
 
-try:
-    _CHIP = SingleDeviceSharding(topologies.get_topology_desc(
-        platform="tpu", topology_name="v5e:2x2").devices[0])
-except Exception as exc:  # no TPU compiler in this installation
-    pytest.skip(f"cannot describe a v5e topology here: {exc!r}",
-                allow_module_level=True)
-
 B, HQ, HKV, PAGE, N_PAGES, MAX_PAGES = 4, 32, 8, 64, 512, 16
+REPO = Path(__file__).resolve().parent.parent
 
 
-@pytest.fixture(autouse=True, scope="module")
-def _no_persistent_cache():
-    """A compile for a described chip is written to the persistent
-    cache but cannot be read back without one — the next run would
-    warn ("Error reading persistent compilation cache entry") and
-    compile again. Keep these out of it."""
+@pytest.fixture(scope="module")
+def chip():
+    """One chip of a described v5e, with the persistent compile cache
+    off while this file's tests run: a compile for a described chip is
+    written to it but cannot be read back without one — the next run
+    would warn ("Error reading persistent compilation cache entry") and
+    compile again."""
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as exc:  # no TPU compiler in this installation
+        pytest.skip(f"cannot describe a v5e topology here: {exc!r}")
     was = jax.config.jax_enable_compilation_cache
     jax.config.update("jax_enable_compilation_cache", False)
     compilation_cache.reset_cache()
-    yield
+    yield SingleDeviceSharding(topo.devices[0])
     jax.config.update("jax_enable_compilation_cache", was)
     compilation_cache.reset_cache()
 
 
-def _shape(shape, dtype):
-    return jax.ShapeDtypeStruct(shape, dtype, sharding=_CHIP)
+def _shape(shape, dtype, chip):
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=chip)
 
 
-def _pool(hd, quantized):
+def _pool(hd, quantized, chip):
     """One layer's pool as the engine lays it out (ops/paged_kv.py)."""
     pack = head_pack(HKV, hd)
     shape = (HKV // pack, N_PAGES, PAGE, pack * hd)
     if not quantized:
-        return _shape(shape, jnp.bfloat16)
-    return {"q": _shape(shape, jnp.int8),
+        return _shape(shape, jnp.bfloat16, chip)
+    return {"q": _shape(shape, jnp.int8, chip),
             "s": _shape((*shape[:2], 1, scale_width(pack, PAGE)),
-                        jnp.float32)}
+                        jnp.float32, chip)}
 
 
 def _compiles_to_kernel(fn, *args):
@@ -77,32 +95,118 @@ def _compiles_to_kernel(fn, *args):
     assert "tpu_custom_call" in text
 
 
-def test_flash_attention_compiles_for_v5e():
-    q = _shape((B, 1024, HQ, 64), jnp.bfloat16)
-    kv = _shape((B, 1024, HKV, 64), jnp.bfloat16)
+def test_flash_attention_compiles_for_v5e(chip):
+    q = _shape((B, 1024, HQ, 64), jnp.bfloat16, chip)
+    kv = _shape((B, 1024, HKV, 64), jnp.bfloat16, chip)
     _compiles_to_kernel(
         lambda q, k, v, n: flash_attention(q, k, v, kv_lengths=n),
-        q, kv, kv, _shape((B,), jnp.int32))
+        q, kv, kv, _shape((B,), jnp.int32, chip))
 
 
 @pytest.mark.parametrize("quantized", [False, True],
                          ids=["bf16", "int8"])
 @pytest.mark.parametrize("kind,hd", [("decode", 64), ("decode", 128),
                                      ("chunk", 64), ("tree", 64)])
-def test_paged_kernel_compiles_for_v5e(kind, hd, quantized):
-    pool = _pool(hd, quantized)
-    tables = _shape((B, MAX_PAGES), jnp.int32)
-    lens = _shape((B,), jnp.int32)
+def test_paged_kernel_compiles_for_v5e(kind, hd, quantized, chip):
+    pool = _pool(hd, quantized, chip)
+    tables = _shape((B, MAX_PAGES), jnp.int32, chip)
+    lens = _shape((B,), jnp.int32, chip)
     if kind == "decode":
         _compiles_to_kernel(paged_decode_attention_pallas,
-                            _shape((B, HQ, hd), jnp.bfloat16),
+                            _shape((B, HQ, hd), jnp.bfloat16, chip),
                             pool, pool, tables, lens)
     elif kind == "chunk":       # a 256-row prefill chunk: two q blocks
         _compiles_to_kernel(paged_chunk_attention_pallas,
-                            _shape((B, 256, HQ, hd), jnp.bfloat16),
+                            _shape((B, 256, HQ, hd), jnp.bfloat16, chip),
                             pool, pool, tables, lens, lens)
     else:                       # an 8-node draft tree
         _compiles_to_kernel(paged_tree_attention_pallas,
-                            _shape((B, 8, HQ, hd), jnp.bfloat16),
+                            _shape((B, 8, HQ, hd), jnp.bfloat16, chip),
                             pool, pool, tables, lens, lens,
-                            _shape((B, 8), jnp.int32))
+                            _shape((B, 8), jnp.int32, chip))
+
+
+# ------------------------------ the names the benchmark's trace reader uses
+@pytest.fixture(scope="module")
+def trace_reader():
+    """benchmarks/harness/trace.py, by path: the classification the
+    benchmark itself applies to a program's and an operation's name."""
+    spec = importlib.util.spec_from_file_location(
+        "bench_trace", REPO / "benchmarks" / "harness" / "trace.py")
+    module = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(module)
+    return module
+
+
+@pytest.fixture(scope="module")
+def engine():
+    """The benchmark's builder on a one-layer model at SmolLM2-1.7B's
+    widths (benchmarks/configs/smollm2-1.7b.json), kernels by name: no
+    chip is attached, so nothing may be left to ``auto``."""
+    from gofr_tpu.models.llama import LlamaConfig, llama_init
+    from gofr_tpu.serving.engine import EngineConfig
+    from gofr_tpu.serving.glue import llama_engine
+    c = LlamaConfig(vocab_size=49152, dim=2048, n_layers=1, n_heads=32,
+                    n_kv_heads=32, ffn_dim=8192, max_seq=8192,
+                    rope_theta=130000.0, norm_eps=1e-5,
+                    tie_embeddings=True)
+    return llama_engine(
+        llama_init(jax.random.key(0), c), c,
+        EngineConfig(max_batch=B, max_seq=2048, prefill_buckets=(128,),
+                     prefill_batch=4, kv_layout="paged",
+                     paged_attention="kernel", page_size=PAGE, kv_pages=64,
+                     eos_id=-1, autoprof=False),
+        implementation="pallas")
+
+
+def _step_program(engine, kind, chip):
+    """(jitted function, its arguments as shapes on the chip) for one
+    of the engine's step programs, in the order ``Engine.warmup``
+    passes them."""
+    def like(tree):
+        return jax.tree.map(lambda x: jax.ShapeDtypeStruct(
+            x.shape, x.dtype, sharding=chip), tree)
+
+    def sh(shape, dtype):
+        return _shape(shape, dtype, chip)
+
+    params, kc, vc = (like(t) for t in (engine.params, engine.k_cache,
+                                        engine.v_cache))
+    pages = engine._pages_per_slot
+    if kind == "decode":
+        return engine._decode, (
+            params, sh((B,), jnp.int32), sh((B,), bool),
+            like(engine._dev_zero), kc, vc, sh((B, pages), jnp.int32),
+            sh((B,), jnp.int32), sh((B,), bool), sh((), jnp.int32),
+            sh((B,), jnp.float32), sh((B,), jnp.float32),
+            sh((B,), jnp.int32), like(engine._dev_decode_key))
+    sampling = (sh((), jnp.int32), sh((1,), jnp.float32),
+                sh((1,), jnp.float32), sh((1,), jnp.int32),
+                like(engine._prefill_base_key))
+    if kind == "bucket":
+        return engine._get_prefill(128, 1), (
+            params, sh((1, 128), jnp.int32), sh((1,), jnp.int32), kc, vc,
+            sh((1, pages), jnp.int32), *sampling)
+    return engine._get_chunk_prefill(), (
+        params, sh((1, 128), jnp.int32), kc, vc,
+        sh((1, pages), jnp.int32), sh((1,), jnp.int32),
+        sh((1,), jnp.int32), *sampling)
+
+
+@pytest.mark.parametrize("kind,program", [
+    ("decode", "decode"), ("bucket", "prefill"), ("chunk", "prefill")])
+def test_trace_names_find_the_engines_programs_and_kernels(
+        kind, program, engine, chip, trace_reader):
+    names = trace_reader.load_names()
+    fn, args = _step_program(engine, kind, chip)
+    text = fn.lower(*args).compile().as_text()
+    # the profiler names an execution jit_<function>(<fingerprint>)
+    module = re.match(r"HloModule (\S+?),", text).group(1)
+    assert trace_reader.classify(module, names["programs"]) == program
+    # ... and an operation by its HLO line, as the compiled text has it
+    kernels = [line.strip() for line in text.splitlines()
+               if 'custom_call_target="tpu_custom_call"' in line]
+    assert kernels, "no Pallas kernel in the engine's step program"
+    for line in kernels:
+        assert trace_reader.classify(line, names["kernels"]) \
+            == "attention", line[:160]
