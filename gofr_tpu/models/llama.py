@@ -304,28 +304,20 @@ def llama_decode_step_paged(params: dict, tokens: jnp.ndarray,
     the ragged kernel dequantizes per page.
     """
     from ..ops.paged_attention import paged_decode_attention
-    from ..ops.paged_kv import pool_layer, pool_shape, pool_write
+    from ..ops.paged_kv import pool_write
     c = config
     b = tokens.shape[0]
     hd = c.head_dim
-    n_pages, pg = pool_shape(k_pool)[2:4]
     inv_freq = rope_frequencies(c.head_dim, c.rope_theta, c.rope_scaling)
     positions = lengths[:, None]
+    one = jnp.ones_like(lengths)
     x = qgather(params["embed"], tokens, c.dtype)[:, None, :]  # [B, 1, D]
-    # the new row's page id and in-page offset via the table; rows at
-    # or past the allocation see the OOB id and drop on scatter
-    pids = jnp.take_along_axis(
-        tables, jnp.minimum(lengths // pg, tables.shape[1] - 1)[:, None],
-        axis=1)[:, 0]
-    pids = jnp.where(lengths < tables.shape[1] * pg, pids, n_pages)
-    offs = lengths % pg
 
-    # pools ride the scan CARRY (see llama_decode_step): the fresh row
-    # scatters straight into the full pool — ys emission would copy
-    # every layer's whole pool slice per step. Advanced-index note:
-    # ``at[li, :, pids, offs]`` puts the broadcast [B] index result in
-    # front of the sliced head axis, so the update value is k[:, 0]
-    # ([B, Hkv, hd]) with no transpose.
+    # pools ride the scan CARRY (see llama_decode_step) and never leave
+    # it: the fresh row lands at position ``lengths`` through the table
+    # (rows at or past the allocation drop), by whole pages, and the
+    # kernel reads the whole pool at ``li`` — a layer's slice taken out
+    # of the carry is a copy per layer-step (ops/paged_kv.pool_write)
     def layer_fn(carry, scanned):
         x, kp_all, vp_all = carry     # [L, Hkv, Np, pg, hd]
         lp, li = scanned
@@ -335,11 +327,10 @@ def llama_decode_step_paged(params: dict, tokens: jnp.ndarray,
         v = qmatmul(h, lp["wv"]).reshape(b, 1, c.n_kv_heads, hd)
         q = apply_rope(q, positions, inv_freq)
         k = apply_rope(k, positions, inv_freq)
-        kp_all = pool_write(kp_all, li, pids, offs, k[:, 0])
-        vp_all = pool_write(vp_all, li, pids, offs, v[:, 0])
-        kp = pool_layer(kp_all, li)
-        vp = pool_layer(vp_all, li)
-        out = paged_decode_attention(q[:, 0], kp, vp, tables, lengths + 1,
+        kp_all = pool_write(kp_all, li, tables, lengths, one, k)
+        vp_all = pool_write(vp_all, li, tables, lengths, one, v)
+        out = paged_decode_attention(q[:, 0], kp_all, vp_all, tables,
+                                     lengths + 1, layer=li,
                                      implementation=implementation)
         x = x + qmatmul(out.reshape(b, 1, c.n_heads * hd), lp["wo"])
         x = x + _mlp_block(x, lp, c)
@@ -485,29 +476,19 @@ def llama_prefill_chunk_paged(params: dict, tokens: jnp.ndarray,
     """
     from ..ops.paged_attention import (paged_chunk_attention,
                                        paged_tree_attention)
-    from ..ops.paged_kv import pool_layer, pool_shape, pool_write
+    from ..ops.paged_kv import pool_write
     c = config
     b, s = tokens.shape
     hd = c.head_dim
-    n_pages, pg = pool_shape(k_pool)[2:4]
-    mp = tables.shape[1]
     inv_freq = rope_frequencies(c.head_dim, c.rope_theta, c.rope_scaling)
-    node_pos = offsets[:, None] + jnp.arange(s)[None, :]       # [B, S]
-    positions = node_pos if tree_depths is None \
-        else offsets[:, None] + tree_depths
-    valid = jnp.arange(s)[None, :] < chunk_lengths[:, None]    # [B, S]
-    # page id + in-page offset per written position; padding rows and
-    # positions past the table map to the OOB id and drop on scatter
-    pids = jnp.take_along_axis(
-        tables, jnp.clip(node_pos // pg, 0, mp - 1), axis=1)   # [B, S]
-    pids = jnp.where(valid & (node_pos < mp * pg), pids, n_pages)
-    offs = node_pos % pg
+    positions = offsets[:, None] + (
+        jnp.arange(s)[None, :] if tree_depths is None else tree_depths)
     x = qgather(params["embed"], tokens, c.dtype)
 
-    # pools ride the scan carry (see llama_decode_step_paged); the
-    # advanced-index write puts the broadcast [B, S] index result in
-    # front of the sliced head axis, so the update value is the raw
-    # [B, S, Hkv, hd] chunk K/V with no transpose
+    # pools ride the scan carry (see llama_decode_step_paged): row i of
+    # the chunk — tree NODE i in verify mode — lands at position
+    # ``offsets + i``; padding rows past ``chunk_lengths`` and positions
+    # past the table drop
     def layer_fn(carry, scanned):
         x, kp_all, vp_all = carry     # [L, Hkv, Np, pg, hd]
         lp, li = scanned
@@ -517,17 +498,15 @@ def llama_prefill_chunk_paged(params: dict, tokens: jnp.ndarray,
         v = qmatmul(h, lp["wv"]).reshape(b, s, c.n_kv_heads, hd)
         q = apply_rope(q, positions, inv_freq)
         k = apply_rope(k, positions, inv_freq)
-        kp_all = pool_write(kp_all, li, pids, offs, k)
-        vp_all = pool_write(vp_all, li, pids, offs, v)
-        kp = pool_layer(kp_all, li)
-        vp = pool_layer(vp_all, li)
+        kp_all = pool_write(kp_all, li, tables, offsets, chunk_lengths, k)
+        vp_all = pool_write(vp_all, li, tables, offsets, chunk_lengths, v)
         if tree_masks is None:
-            out = paged_chunk_attention(q, kp, vp, tables, offsets,
-                                        chunk_lengths,
+            out = paged_chunk_attention(q, kp_all, vp_all, tables, offsets,
+                                        chunk_lengths, layer=li,
                                         implementation=implementation)
         else:
-            out = paged_tree_attention(q, kp, vp, tables, offsets,
-                                       chunk_lengths, tree_masks,
+            out = paged_tree_attention(q, kp_all, vp_all, tables, offsets,
+                                       chunk_lengths, tree_masks, layer=li,
                                        implementation=implementation)
         x = x + qmatmul(out.reshape(b, s, c.n_heads * hd), lp["wo"])
         x = x + _mlp_block(x, lp, c)

@@ -1,7 +1,7 @@
 """Ragged paged attention — pages read in place via block table.
 
 The paged KV layout (:mod:`.paged_kv`) stores K/V in a head-major,
-lane-packed page pool ``[Hg, Np, pg, W]`` per layer with per-slot block
+lane-packed page pool ``[L, Hg, Np, pg, W]`` with per-slot block
 tables. The generic engine path materialises a dense per-slot view of
 the WHOLE pool allocation every K-step pass (``gather_view``), which
 costs O(full-cache) extra HBM traffic on top of attention's own reads.
@@ -29,8 +29,14 @@ What the TPU's compiler takes (established by ahead-of-time compiles
 for v5e — ``tests/test_tpu_compile.py`` keeps them):
 
 - Mosaic tiles the trailing two dims of every memref, 8 sublanes x 128
-  lanes. ``pool.at[h, pid]`` slices only untiled leading dims, so the
-  head axis leads (head-major) and a page is one contiguous block.
+  lanes. ``pool.at[layer, h, pid]`` slices only untiled leading dims,
+  so the head axis leads (head-major) and a page is one contiguous
+  block.
+- The kernel takes the WHOLE pool, all layers, and the layer index as
+  a prefetched scalar. A layer's slice of the scan carry handed in as
+  the operand is materialised by XLA — a copy of one layer's K and V
+  per layer-step — and the pool must arrive row-major, which is why
+  :mod:`.paged_kv` writes it by whole pages.
 - The block's last dim must fill the 128 lanes. ``head_dim`` 64 alone
   does not ("Slice shape along dimension 3 must be aligned to tiling
   (128), but is 64"), so :mod:`.paged_kv` packs ``pack = 128 //
@@ -52,7 +58,9 @@ trace out of warmup — never a silent switch to another path.
 
 Layouts:
 - ``q``        [B, Sq, Hq, hd] (decode: [B, Hq, hd])
-- ``k_pool``   [Hg, Np, pg, W] (one layer's pool; bf16 in serving)
+- ``k_pool``   [L, Hg, Np, pg, W] with ``layer=`` a (traced) index —
+  what the model steps pass; or one layer's [Hg, Np, pg, W] with
+  ``layer=None`` (viewed as a pool of one layer: kernel tests, tools)
 - ``tables``   [B, Mp] int32 — page ids, out-of-range = unallocated
 - out          like ``q``
 
@@ -61,7 +69,7 @@ Each ``paged_*_attention`` dispatches: 'pallas' (TPU), 'interpret'
 reference), 'auto' (pallas on TPU, xla elsewhere).
 
 Quantized pools (``kv_dtype="int8"``) arrive as the two-leaf pytree
-``{"q": int8 [Hg, Np, pg, W], "s": f32 [Hg, Np, 1, SW]}`` from
+``{"q": int8 [L, Hg, Np, pg, W], "s": f32 [L, Hg, Np, 1, SW]}`` from
 :mod:`.paged_kv`. The kernel DMAs each int8 page plus its one-row
 scale block and never dequantizes a page: the scale of kv row t is
 constant along the contraction, so it multiplies the SCORE column
@@ -142,10 +150,12 @@ def check_kernel_layout(pool) -> None:
 # ancestor of node i, or j == i), which caps the tree at 32 nodes —
 # far above any sane draft budget.
 
-def _ragged_kernel(tables_ref, history_ref, chunk_ref, *refs, page: int,
+def _ragged_kernel(tables_ref, history_ref, chunk_ref, layer_ref, *refs,
+                   page: int,
                    pages_per_chunk: int, max_pages: int, n_pages: int,
                    scale: float, block_q: int, group: int, pack: int,
                    tree: bool, quantized: bool):
+    li = layer_ref[0]
     tree_ref = None
     if tree:
         tree_ref, *refs = refs
@@ -185,17 +195,17 @@ def _ragged_kernel(tables_ref, history_ref, chunk_ref, *refs, page: int,
             pid = jnp.minimum(tables_ref[b, page_idx], n_pages - 1)
             dst = pl.ds(j * page, page)
             dmas.append(pltpu.make_async_copy(
-                k_hbm.at[h, pid], k_buf.at[slot, dst, :],
+                k_hbm.at[li, h, pid], k_buf.at[slot, dst, :],
                 sems.at[slot, 0, j]))
             dmas.append(pltpu.make_async_copy(
-                v_hbm.at[h, pid], v_buf.at[slot, dst, :],
+                v_hbm.at[li, h, pid], v_buf.at[slot, dst, :],
                 sems.at[slot, 1, j]))
             if quantized:
                 dmas.append(pltpu.make_async_copy(
-                    ks_hbm.at[h, pid], ks_buf.at[slot, j],
+                    ks_hbm.at[li, h, pid], ks_buf.at[slot, j],
                     sems.at[slot, 2, j]))
                 dmas.append(pltpu.make_async_copy(
-                    vs_hbm.at[h, pid], vs_buf.at[slot, j],
+                    vs_hbm.at[li, h, pid], vs_buf.at[slot, j],
                     sems.at[slot, 3, j]))
         return dmas
 
@@ -318,14 +328,20 @@ def _pick_block_q(sq: int) -> int:
 
 
 def _ragged_attention(q, k_pool, v_pool, tables, history_lens, chunk_lens,
-                      tree_masks, *, scale, block_q, interpret):
-    """The pallas_call behind all three paths. q [B, Sq, Hq, hd]."""
+                      tree_masks, *, layer, scale, block_q, interpret):
+    """The pallas_call behind all three paths. q [B, Sq, Hq, hd]; the
+    pools whole, [L, Hg, Np, pg, W], read at ``layer`` (a traced
+    scalar, prefetched) — or one layer's [Hg, Np, pg, W] with
+    ``layer=None``, viewed as a pool of one layer (a bitcast)."""
+    if layer is None:
+        k_pool, v_pool = jax.tree.map(lambda x: x[None], (k_pool, v_pool))
+        layer = 0
     k_codes, k_scales = _split_pool(k_pool)
     v_codes, v_scales = _split_pool(v_pool)
     quantized = k_scales is not None
     tree = tree_masks is not None
     b, sq, hq, hd = q.shape
-    hg, n_pages, page, width = k_codes.shape
+    _, hg, n_pages, page, width = k_codes.shape
     _, max_pages = tables.shape
     pack = width // hd
     g = hq // (hg * pack)
@@ -374,7 +390,7 @@ def _ragged_attention(q, k_pool, v_pool, tables, history_lens, chunk_lens,
     scale_bufs = [pltpu.VMEM((2, pages_per_chunk, 1, k_scales.shape[-1]),
                              jnp.float32)] * 2 if quantized else []
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=4 if tree else 3,
+        num_scalar_prefetch=5 if tree else 4,
         grid=(b, hg, sq // block_q),
         in_specs=[q_spec] + [in_hbm] * (4 if quantized else 2),
         out_specs=q_spec,
@@ -390,7 +406,8 @@ def _ragged_attention(q, k_pool, v_pool, tables, history_lens, chunk_lens,
         ],
     )
     args = [tables.astype(jnp.int32), history_lens.astype(jnp.int32),
-            chunk_lens.astype(jnp.int32)]
+            chunk_lens.astype(jnp.int32),
+            jnp.asarray(layer, jnp.int32).reshape(1)]
     if tree:
         args.append(tree_masks.astype(jnp.int32))
     args += [q4, k_codes, v_codes]
@@ -418,26 +435,30 @@ def paged_chunk_attention_pallas(q: jnp.ndarray, k_pool,
                                  v_pool, tables: jnp.ndarray,
                                  history_lens: jnp.ndarray,
                                  chunk_lens: jnp.ndarray, *,
+                                 layer=None,
                                  scale: float | None = None,
                                  block_q: int | None = None,
                                  interpret: bool = False) -> jnp.ndarray:
     """Ragged chunk attention. q [B, Sq, Hq, hd] holds Sq new positions
     per slot, already written into the pool at rows
     ``[history_lens, history_lens + chunk_lens)``; pools
-    [Hg, Np, pg, W] (plain) or the ``{"q", "s"}`` quantized pytree.
+    [L, Hg, Np, pg, W] read at ``layer``, or one layer's
+    [Hg, Np, pg, W] with ``layer=None`` (plain, or the ``{"q", "s"}``
+    quantized pytree).
     Query row i of slot b attends causally to pool
     rows <= history_lens[b] + i, bounded by the slot's written total
     ``history + chunk``. Rows past ``chunk_lens[b]`` are padding the
     caller discards; zero-length slots (history == chunk == 0) return
     exact zeros."""
     return _ragged_attention(q, k_pool, v_pool, tables, history_lens,
-                             chunk_lens, None, scale=scale,
+                             chunk_lens, None, layer=layer, scale=scale,
                              block_q=block_q, interpret=interpret)
 
 
 def paged_decode_attention_pallas(q: jnp.ndarray, k_pool,
                                   v_pool, tables: jnp.ndarray,
                                   lengths: jnp.ndarray, *,
+                                  layer=None,
                                   scale: float | None = None,
                                   interpret: bool = False) -> jnp.ndarray:
     """Decode: q [B, Hq, hd] is the one new position per slot,
@@ -446,8 +467,8 @@ def paged_decode_attention_pallas(q: jnp.ndarray, k_pool,
     zeros."""
     return _ragged_attention(
         q[:, None], k_pool, v_pool, tables, jnp.maximum(lengths - 1, 0),
-        jnp.minimum(lengths, 1), None, scale=scale, block_q=1,
-        interpret=interpret)[:, 0]
+        jnp.minimum(lengths, 1), None, layer=layer, scale=scale,
+        block_q=1, interpret=interpret)[:, 0]
 
 
 def paged_tree_attention_pallas(q: jnp.ndarray, k_pool,
@@ -455,6 +476,7 @@ def paged_tree_attention_pallas(q: jnp.ndarray, k_pool,
                                 history_lens: jnp.ndarray,
                                 chunk_lens: jnp.ndarray,
                                 tree_masks: jnp.ndarray, *,
+                                layer=None,
                                 scale: float | None = None,
                                 block_q: int | None = None,
                                 interpret: bool = False) -> jnp.ndarray:
@@ -467,31 +489,37 @@ def paged_tree_attention_pallas(q: jnp.ndarray, k_pool,
     rows j with bit j of tree_masks[b, i] set. Nodes past
     ``chunk_lens[b]`` are padding; a fully-masked row returns zeros."""
     return _ragged_attention(q, k_pool, v_pool, tables, history_lens,
-                             chunk_lens, tree_masks, scale=scale,
-                             block_q=block_q, interpret=interpret)
+                             chunk_lens, tree_masks, layer=layer,
+                             scale=scale, block_q=block_q,
+                             interpret=interpret)
 
 
 # ---------------------------------------------------------- xla reference
 
-def _slot_view(pool, tables: jnp.ndarray, head_dim: int) -> jnp.ndarray:
-    """Gather one layer's pool into the dense slot view
-    [B, Mp*pg, Hkv, hd]; quantized pools dequantize to f32 with the
-    scales the kernel applies."""
-    return gather_view(jax.tree.map(lambda x: x[None], pool), tables,
-                       dtype=jnp.float32, head_dim=head_dim)[0]
+def _slot_view(pool, layer, tables: jnp.ndarray,
+               head_dim: int) -> jnp.ndarray:
+    """Gather one layer of the pool (``layer=None``: the pool IS one
+    layer) into the dense slot view [B, Mp*pg, Hkv, hd]; quantized
+    pools dequantize to f32 with the scales the kernel applies."""
+    one = jax.tree.map(
+        lambda x: x[None] if layer is None else
+        jax.lax.dynamic_index_in_dim(x, layer, 0), pool)
+    return gather_view(one, tables, dtype=jnp.float32,
+                       head_dim=head_dim)[0]
 
 
 def paged_decode_attention_xla(q: jnp.ndarray, k_pool,
                                v_pool, tables: jnp.ndarray,
-                               lengths: jnp.ndarray, *,
+                               lengths: jnp.ndarray, *, layer=None,
                                scale: float | None = None) -> jnp.ndarray:
     """Reference path: gather the slot views, run dense masked decode
     attention. Correct everywhere; materialises [B, Mp*pg, Hkv, hd]."""
     from .attention import decode_attention
     hd = q.shape[-1]
-    out = decode_attention(q[:, None], _slot_view(k_pool, tables, hd),
-                           _slot_view(v_pool, tables, hd), lengths,
-                           scale=scale)[:, 0]
+    out = decode_attention(q[:, None],
+                           _slot_view(k_pool, layer, tables, hd),
+                           _slot_view(v_pool, layer, tables, hd),
+                           lengths, scale=scale)[:, 0]
     # zero-length slots: every position is masked, so the dense softmax
     # degrades to a uniform average over garbage rows — the kernel's
     # denom clamp returns exact zeros there. Match it, so the reference
@@ -503,15 +531,15 @@ def paged_decode_attention_xla(q: jnp.ndarray, k_pool,
 def paged_chunk_attention_xla(q: jnp.ndarray, k_pool,
                               v_pool, tables: jnp.ndarray,
                               history_lens: jnp.ndarray,
-                              chunk_lens: jnp.ndarray, *,
+                              chunk_lens: jnp.ndarray, *, layer=None,
                               scale: float | None = None) -> jnp.ndarray:
     """Reference path: gather the slot views, run dense causal
     attention offset by the history. Materialises [B, Mp*pg, Hkv, hd]
     per call — the traffic the kernel exists to avoid."""
     from .attention import xla_attention
     hd = q.shape[-1]
-    out = xla_attention(q, _slot_view(k_pool, tables, hd),
-                        _slot_view(v_pool, tables, hd), causal=True,
+    out = xla_attention(q, _slot_view(k_pool, layer, tables, hd),
+                        _slot_view(v_pool, layer, tables, hd), causal=True,
                         q_offset=history_lens,
                         kv_lengths=history_lens + chunk_lens,
                         scale=scale)
@@ -525,14 +553,14 @@ def paged_tree_attention_xla(q: jnp.ndarray, k_pool,
                              v_pool, tables: jnp.ndarray,
                              history_lens: jnp.ndarray,
                              chunk_lens: jnp.ndarray,
-                             tree_masks: jnp.ndarray, *,
+                             tree_masks: jnp.ndarray, *, layer=None,
                              scale: float | None = None) -> jnp.ndarray:
     """Reference path: gather the slot views, run dense tree-masked
     attention. Materialises [B, Mp*pg, Hkv, hd] per call."""
     from .attention import tree_attention
     hd = q.shape[-1]
-    out = tree_attention(q, _slot_view(k_pool, tables, hd),
-                         _slot_view(v_pool, tables, hd),
+    out = tree_attention(q, _slot_view(k_pool, layer, tables, hd),
+                         _slot_view(v_pool, layer, tables, hd),
                          history_lens=history_lens,
                          chunk_lens=chunk_lens,
                          tree_masks=tree_masks, scale=scale)
@@ -546,44 +574,45 @@ def paged_tree_attention_xla(q: jnp.ndarray, k_pool,
 
 # --------------------------------------------------------------- dispatch
 
-def _dispatch(implementation: str, pallas_fn, xla_fn, *args, scale):
+def _dispatch(implementation: str, pallas_fn, xla_fn, *args, **kw):
     """implementation: 'pallas' | 'interpret' | 'xla' | 'auto'."""
     if implementation == "pallas" or (
             implementation == "auto" and is_tpu()):
-        return pallas_fn(*args, scale=scale)
+        return pallas_fn(*args, **kw)
     if implementation == "interpret":
-        return pallas_fn(*args, scale=scale, interpret=True)
-    return xla_fn(*args, scale=scale)
+        return pallas_fn(*args, interpret=True, **kw)
+    return xla_fn(*args, **kw)
 
 
 def paged_tree_attention(q: jnp.ndarray, k_pool,
                          v_pool, tables: jnp.ndarray,
                          history_lens: jnp.ndarray,
                          chunk_lens: jnp.ndarray,
-                         tree_masks: jnp.ndarray, *,
+                         tree_masks: jnp.ndarray, *, layer=None,
                          scale: float | None = None,
                          implementation: str = "auto") -> jnp.ndarray:
     return _dispatch(implementation, paged_tree_attention_pallas,
                      paged_tree_attention_xla, q, k_pool, v_pool, tables,
-                     history_lens, chunk_lens, tree_masks, scale=scale)
+                     history_lens, chunk_lens, tree_masks, layer=layer,
+                     scale=scale)
 
 
 def paged_chunk_attention(q: jnp.ndarray, k_pool,
                           v_pool, tables: jnp.ndarray,
                           history_lens: jnp.ndarray,
-                          chunk_lens: jnp.ndarray, *,
+                          chunk_lens: jnp.ndarray, *, layer=None,
                           scale: float | None = None,
                           implementation: str = "auto") -> jnp.ndarray:
     return _dispatch(implementation, paged_chunk_attention_pallas,
                      paged_chunk_attention_xla, q, k_pool, v_pool, tables,
-                     history_lens, chunk_lens, scale=scale)
+                     history_lens, chunk_lens, layer=layer, scale=scale)
 
 
 def paged_decode_attention(q: jnp.ndarray, k_pool,
                            v_pool, tables: jnp.ndarray,
-                           lengths: jnp.ndarray, *,
+                           lengths: jnp.ndarray, *, layer=None,
                            scale: float | None = None,
                            implementation: str = "auto") -> jnp.ndarray:
     return _dispatch(implementation, paged_decode_attention_pallas,
                      paged_decode_attention_xla, q, k_pool, v_pool, tables,
-                     lengths, scale=scale)
+                     lengths, layer=layer, scale=scale)

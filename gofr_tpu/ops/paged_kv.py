@@ -37,14 +37,40 @@ Everything here is a pure jittable function on static shapes:
   view once per K-step decode pass (NOT per token) — the engine then
   runs the model family's ordinary dense decode step on the view, so
   paged mode needs zero model changes.
-- :func:`scatter_prefill` / :func:`scatter_decode` write prompt slabs /
-  freshly decoded rows back through the table. Unallocated positions
-  map to the out-of-range page id (``n_pages``), which XLA's scatter
-  drops — padding rows and dummy slots cost nothing and corrupt
-  nothing.
+- :func:`pool_write` is the one writer: each slot's contiguous run of
+  new positions goes through its table by WHOLE PAGES (below).
+  :func:`scatter_prefill` / :func:`scatter_chunk` /
+  :func:`scatter_decode` are its all-layer entries for prompt slabs
+  and the view path's freshly decoded rows. A table's unallocated
+  entries hold the out-of-range page id (``n_pages``), which the
+  gather clamps and XLA's scatter drops — padding rows and dummy
+  slots cost nothing and corrupt nothing.
 
 Free-list bookkeeping is host-side (``serving/engine.py``): the device
 never sees an allocator, only tables.
+
+One physical layout: writes are by page
+---------------------------------------
+The Mosaic kernel reads the pool row-major (``{4,3,2,1,0}``: a page is
+one contiguous ``[page, W]`` block). XLA picks a buffer's layout for
+the op that writes it, and for a scatter of single ``[Hg, W]`` rows —
+``pool.at[li, :, pids, offs].set(rows)``, how this module wrote until
+PR 26 — it picks TOKEN-major, ``{4,1,3,2,0}``, the head-group axis next
+to the lanes. The pool then changed layout four times a program (K and
+V, on entry and on exit: whole-pool copies) and every layer-step sliced
+one layer out token-major and transposed it for the kernel: a third of
+the device's time in the benchmark's traces (PERF.md section 6, PR 26).
+Handing the kernel the whole pool instead makes XLA copy the WHOLE pool
+per layer-step; a layout constraint on the scan carry copies around
+every scatter; ``dynamic_update_slice`` row by row is laid out
+token-major too. XLA does not write single rows of a ``[..., pg,
+W]``-tiled pool in place. It does write whole pages in place: a page is
+a window over the pool's leading dims. So every writer gathers the
+pages its run touches, lays the new rows over them, and scatters the
+pages back — and the kernel takes the whole pool and a layer index
+(``ops/paged_attention.py``). The pool keeps one layout from program
+entry to exit, with no copy of it and no temp of its size
+(``tests/test_tpu_compile.py`` holds the compiled programs to that).
 
 Quantized pools
 ---------------
@@ -188,14 +214,6 @@ def pool_row_bytes(pool) -> int:
     return -(-total // (n_pages * pg))
 
 
-def pool_layer(pool, li):
-    """Layer ``li``'s [Hg, Np, pg, W] slice (pytree-aware) — what the
-    ragged attention dispatchers take as ``k_pool`` / ``v_pool``."""
-    return jax.tree.map(
-        lambda x: jax.lax.dynamic_index_in_dim(x, li, 0, keepdims=False),
-        pool)
-
-
 def _scale_lanes(offs: jnp.ndarray, pack: int, pg: int) -> jnp.ndarray:
     """Lane of each packed head's scale for rows at in-page offsets
     ``offs`` [...] -> [..., pack]."""
@@ -203,50 +221,97 @@ def _scale_lanes(offs: jnp.ndarray, pack: int, pg: int) -> jnp.ndarray:
         (1,) * offs.ndim + (pack,))
 
 
-def pool_write(pool, li, pids, offs, rows):
-    """Write ``rows`` into layer ``li`` at (page, offset) coordinates —
-    the single-layer scatter the model families use inside their layer
-    scan. ``pids``/``offs`` are the advanced-index arrays ([B] decode,
-    [B, S] chunk); ``rows`` is the model's [..., Hkv, hd] K or V for
-    those positions. Packs heads into lanes (a reshape), quantizes on
-    write for quantized pools; plain pools absorb the dtype cast here so
-    callers never touch the pool dtype. The advanced indices are split
-    by the sliced head-group axis, so their broadcast dims lead the
-    update: [..., Hg, W] codes, [..., pack, Hg] scales."""
-    hg, _, pg, w = pool_shape(pool)[1:]
-    pack = w // rows.shape[-1]
-    if not is_quantized_pool(pool):
-        rows = rows.reshape(*rows.shape[:-2], hg, w)
-        return pool.at[li, :, pids, offs].set(rows.astype(pool.dtype),
-                                              mode="drop")
-    q, s = quantize_rows(rows)
-    s = jnp.swapaxes(s.reshape(*s.shape[:-2], hg, pack), -1, -2)
-    return {"q": pool["q"].at[li, :, pids, offs].set(
-                q.reshape(*q.shape[:-2], hg, w), mode="drop"),
-            "s": pool["s"].at[li, :, pids[..., None], 0,
-                              _scale_lanes(offs, pack, pg)].set(
-                s, mode="drop")}
+def pool_write(pool, layer, tables, starts, counts, rows):
+    """The one pool writer: slot b's ``rows[..., b, :counts[b]]`` land
+    at logical positions ``[starts[b], starts[b] + counts[b])`` of its
+    table, by WHOLE PAGES (module docstring: written by rows, XLA lays
+    the pool out token-major and copies it for the kernel in every
+    program and layer-step). ``rows`` is the model's token-major K or V:
+    ``[B, S, Hkv, hd]`` for one ``layer`` (a traced index — what the
+    model families call inside their layer scan; decode is S = 1) or
+    ``[L, B, S, Hkv, hd]`` with ``layer=None`` (all layers: the
+    ``scatter_*`` entries below). Packs heads into lanes, quantizes on
+    write for quantized pools; plain pools absorb the dtype cast here
+    so callers never touch the pool dtype.
 
+    A run of static width S touches at most ``P = (S + pg - 2) // pg +
+    1`` consecutive pages of a table. Gather those pages, lay the new
+    rows over them — old bytes stay wherever a position is outside the
+    run — and scatter the pages back. Pages the run does not reach, a
+    table's unallocated entries (page id ``n_pages``) and positions
+    past the table drop: the gather clamps, the scatter drops.
 
-def _pool_set(pool, pids, offs, rows):
-    """All-layer scatter: token-major rows [L, P, S, Hkv, hd] at
-    pids/offs [P, S]."""
-    hg, _, pg, w = pool_shape(pool)[1:]
-    l, p, s_, _, d = rows.shape
+    Why it is safe: no scatter here carries two different updates for
+    one page. A slot's rows within a page are merged before the
+    scatter (tree-verify nodes included: they are the run ``[offset,
+    offset + nodes)``); the pages a run reaches are distinct entries
+    of one table; and two slots never write one page in one call —
+    tail pages have one owner, and the prefix cache shares
+    page-ALIGNED prefixes only (``Engine._register_prefix``), which a
+    later run starts behind."""
+    hg, n_pages, pg, w = pool_shape(pool)[1:]
+    s, _, d = rows.shape[-3:]
     pack = w // d
+    mp = tables.shape[1]
+    p = (s + pg - 2) // pg + 1
+    counts = jnp.minimum(counts, s)
+    page_idx = (starts // pg)[:, None] + jnp.arange(p)[None, :]  # [B, P]
+    pids = jnp.take_along_axis(tables, jnp.minimum(page_idx, mp - 1),
+                               axis=1)
+    reached = page_idx * pg < (starts + counts)[:, None]
+    pids = jnp.where(reached & (page_idx < mp), pids, n_pages)
+    # the window: the P pages' rows; its row t is slab row t - shift
+    shift = starts % pg
+    src = jnp.arange(p * pg)[None, :] - shift[:, None]          # [B, T]
+    fresh = ((src >= 0) & (src < counts[:, None])).reshape(-1, p, 1, pg)
+
+    def pages(x):
+        """Rows [..., B, S, Hg, X] -> their window as pages [..., B, P,
+        Hg, pg, X]: head-major, and only the NEW rows are transposed.
+        Rows of the window outside the run hold no matter what."""
+        if s == 1:      # one row has nothing to shift: ``fresh`` places it
+            x = jnp.broadcast_to(x, (*x.shape[:-3], pg, *x.shape[-2:]))
+        else:           # one slice a slot out of the zero-padded slab
+            pad = [(0, 0)] * x.ndim
+            pad[-3] = (pg - 1, p * pg - s)
+            x = jax.vmap(lambda xb, lo: jax.lax.dynamic_slice_in_dim(
+                xb, lo, p * pg, axis=-3), in_axes=(-4, 0), out_axes=-4)(
+                    jnp.pad(x, pad), pg - 1 - shift)
+        return jnp.swapaxes(
+            x.reshape(*x.shape[:-3], p, pg, *x.shape[-2:]), -3, -2)
+
+    at = (slice(None) if layer is None else layer, slice(None), pids)
+
+    def merge(leaf, new, mask):
+        """``new`` [..., B, P, Hg, rows, X] over the pages ``leaf`` holds
+        where ``mask`` [B, P, 1, rows | 1, X | 1]. The head axis sits
+        where the indexing puts it: behind [B, P] under a layer index,
+        in place ([L, Hg, B, P, ...]) without one."""
+        old = leaf.at[at].get(mode="clip")
+        if layer is None:
+            new, mask = jnp.moveaxis(new, -3, 1), mask[None, None, :, :, 0]
+        return leaf.at[at].set(
+            jnp.where(mask, new.astype(leaf.dtype), old), mode="drop")
+
     if not is_quantized_pool(pool):
-        packed = rows.reshape(l, p, s_, hg, w).transpose(0, 3, 1, 2, 4)
-        return pool.at[:, :, pids, offs].set(packed.astype(pool.dtype),
-                                             mode="drop")
-    q, s = quantize_rows(rows)
-    q = q.reshape(l, p, s_, hg, w).transpose(0, 3, 1, 2, 4)
-    # advanced indices (page, 0, lane) are adjacent: their broadcast
-    # [P, S, pack] lands where they sat, after [L, Hg]
-    s = s.reshape(l, p, s_, hg, pack).transpose(0, 3, 1, 2, 4)
-    return {"q": pool["q"].at[:, :, pids, offs].set(q, mode="drop"),
-            "s": pool["s"].at[:, :, pids[..., None], 0,
-                              _scale_lanes(offs, pack, pg)].set(
-                s, mode="drop")}
+        return merge(pool, pages(rows.reshape(*rows.shape[:-2], hg, w)),
+                     fresh[..., None])
+    q, sc = quantize_rows(rows)
+
+    def scale_row(x):
+        """Per-row values [..., pg, pack] -> the page's ONE lane-major
+        row [..., 1, SW]: packed head p's at lanes [p * pg, (p + 1) *
+        pg), zero-padded to the scale width."""
+        x = jnp.swapaxes(x, -1, -2).reshape(*x.shape[:-2], 1, pack * pg)
+        return jnp.pad(x, [(0, 0)] * (x.ndim - 1)
+                       + [(0, pool["s"].shape[-1] - pack * pg)])
+
+    return {"q": merge(pool["q"], pages(q.reshape(*q.shape[:-2], hg, w)),
+                       fresh[..., None]),
+            "s": merge(pool["s"],
+                       scale_row(pages(sc.reshape(*sc.shape[:-2], hg, pack))),
+                       scale_row(jnp.broadcast_to(
+                           fresh[..., None], (*fresh.shape, pack))))}
 
 
 def gather_view(pool, tables: jnp.ndarray, dtype=None,
@@ -286,12 +351,9 @@ def scatter_prefill(pool, tables: jnp.ndarray,
     per-row tables [P, Mp]. Positions whose table entry is the OOB page
     id are dropped (padding beyond each row's allocation, dummy rows).
     """
-    pg = pool_shape(pool)[3]
-    s = k_slab.shape[2]
-    pos = jnp.arange(s)
-    pids = jnp.take(tables, pos // pg, axis=1)          # [P, S]
-    offs = jnp.broadcast_to(pos % pg, pids.shape)       # [P, S]
-    return _pool_set(pool, pids, offs, k_slab)
+    zero = jnp.zeros(k_slab.shape[1], jnp.int32)
+    return pool_write(pool, None, tables, zero, zero + k_slab.shape[2],
+                      k_slab)
 
 
 def scatter_chunk(pool, tables: jnp.ndarray,
@@ -306,15 +368,7 @@ def scatter_chunk(pool, tables: jnp.ndarray,
     the OOB page id and drop, so a 5-token suffix in a 512-wide bucket
     writes one page, not the slot's whole allocation.
     """
-    n_pages, pg = pool_shape(pool)[2:4]
-    mp = tables.shape[1]
-    s = slab.shape[2]
-    pos = offsets[:, None] + jnp.arange(s)[None, :]             # [P, S]
-    valid = jnp.arange(s)[None, :] < chunk_lens[:, None]        # [P, S]
-    pids = jnp.take_along_axis(
-        tables, jnp.clip(pos // pg, 0, mp - 1), axis=1)         # [P, S]
-    pids = jnp.where(valid & (pos < mp * pg), pids, n_pages)
-    return _pool_set(pool, pids, pos % pg, slab)
+    return pool_write(pool, None, tables, offsets, chunk_lens, slab)
 
 
 def scatter_decode(pool, tables: jnp.ndarray,
@@ -324,17 +378,14 @@ def scatter_decode(pool, tables: jnp.ndarray,
     (at logical positions lengths .. lengths+K-1 per slot) back into
     the pool. view [L, B, S, H, d], tables [B, Mp], lengths [B].
     """
-    n_pages, pg = pool_shape(pool)[2:4]
-    s = view.shape[2]
     positions = lengths[:, None] + jnp.arange(k_steps)[None, :]   # [B, K]
-    clamped = jnp.minimum(positions, s - 1)
     new_rows = jnp.take_along_axis(
-        view, clamped[None, :, :, None, None], axis=2)  # [L, B, K, H, d]
-    pids = jnp.take_along_axis(tables, clamped // pg, axis=1)     # [B, K]
+        view, jnp.minimum(positions, view.shape[2] - 1)[
+            None, :, :, None, None], axis=2)            # [L, B, K, H, d]
     # positions past the logical view (a slot at the cache ceiling
-    # taking a partial pass) must drop, not overwrite the last row
-    pids = jnp.where(positions < s, pids, n_pages)
-    return _pool_set(pool, pids, clamped % pg, new_rows)
+    # taking a partial pass) lie past the table, and drop
+    return pool_write(pool, None, tables, lengths,
+                      jnp.full_like(lengths, k_steps), new_rows)
 
 
 def pool_move_rows(pool, tables: jnp.ndarray,

@@ -221,6 +221,100 @@ def test_quantized_scatter_drops_like_plain():
     np.testing.assert_array_equal(np.asarray(pool["s"]), s0)
 
 
+# ------------------------------------ the page-granular writer, row by row
+
+from gofr_tpu.ops.paged_kv import empty_pool, pool_write  # noqa: E402
+
+#: (run width S, table width Mp, per slot: table, start, count). Page 4,
+#: 40 pages (id 40 = unallocated), two kv heads packed in one row.
+_RUNS = {
+    # start mid-page, end in the next page; a second slot inside one page
+    "two_pages": (6, 4, [([7, 3, 40, 40], 2, 6), ([9, 1, 40, 40], 5, 2)]),
+    # positions 3..64: seventeen pages, the first and last partly
+    "seventeen_pages": (62, 20, [(list(range(20, 40)), 3, 62)]),
+    # decode: one row a slot, one at a page's last offset, one past the
+    # table (dropped)
+    "one_row": (1, 3, [([5, 6, 40], 7, 1), ([8, 40, 40], 0, 1),
+                       ([2, 3, 4], 12, 1)]),
+    # nothing to write: both slots keep every byte
+    "count_zero": (5, 3, [([5, 6, 7], 3, 0), ([8, 9, 10], 0, 0)]),
+    # the run's middle page is unallocated: its rows drop, the rest land
+    "dropped_page": (10, 4, [([11, 40, 13, 14], 1, 10)]),
+    # ends with the table's last page; the second slot runs past it
+    "table_end": (6, 3, [([1, 2, 3], 6, 6), ([4, 5, 6], 9, 6)]),
+    # padding rows past ``count`` stay out, mid-page on both ends
+    "short_count": (8, 4, [([21, 22, 23, 24], 5, 3)]),
+}
+_PG, _NP, _HKV, _HD = 4, 40, 4, 64
+
+
+def _rows_reference(pool, layer, tables, starts, counts, rows):
+    """``pool_write`` one row at a time, on the host: the plain
+    statement of what it means (numpy pools in, numpy pools out)."""
+    quantized = is_quantized_pool(pool)
+    codes = np.array(pool["q"] if quantized else pool)
+    scales = np.array(pool["s"]) if quantized else None
+    pg, w = codes.shape[3:]
+    rows = rows[None] if layer is not None else rows
+    layers = [layer] if layer is not None else range(codes.shape[0])
+    s, hkv, d = rows.shape[2:]
+    pack = w // d
+    if quantized:
+        # jitted like the writer: XLA folds the division by 127
+        vals, sc = (np.asarray(x) for x in jax.jit(quantize_rows)(rows))
+    else:
+        vals = np.asarray(rows.astype(pool.dtype))
+    for k, li in enumerate(layers):
+        for b, (start, count) in enumerate(zip(starts, counts)):
+            for i in range(min(count, s)):
+                page, off = divmod(start + i, pg)
+                if page >= tables.shape[1] or tables[b, page] >= _NP:
+                    continue
+                for h in range(hkv):
+                    at = (li, h // pack, tables[b, page])
+                    lane = (h % pack) * d
+                    codes[at][off, lane:lane + d] = vals[k, b, i, h]
+                    if quantized:
+                        scales[at][0, (h % pack) * pg + off] = \
+                            sc[k, b, i, h, 0]
+    return {"q": codes, "s": scales} if quantized else codes
+
+
+@pytest.mark.parametrize("entry", ["one_layer", "all_layers"])
+@pytest.mark.parametrize("quantized", [False, True], ids=["bf16", "int8"])
+@pytest.mark.parametrize("case", sorted(_RUNS))
+def test_pool_write_matches_the_row_by_row_reference(case, quantized,
+                                                     entry):
+    """Whole pages go through the writer, single rows must come out:
+    the pool is bit-identical to writing each row of each run where its
+    table says, and every other byte keeps its value."""
+    s, mp, slots = _RUNS[case]
+    tables = np.asarray([t for t, _, _ in slots], np.int32)
+    starts = [a for _, a, _ in slots]
+    counts = [n for _, _, n in slots]
+    keys = jax.random.split(jax.random.key(len(case)), 4)
+    like = jnp.zeros((L, _HKV, 1, _PG, _HD), jnp.bfloat16)
+    pool = empty_pool(like, _NP, quantized)
+    # a pool full of old bytes, scales and pad lanes included
+    if quantized:
+        pool = {"q": jax.random.randint(keys[0], pool["q"].shape, -127, 128,
+                                        jnp.int8),
+                "s": jax.random.uniform(keys[1], pool["s"].shape) + 0.5}
+    else:
+        pool = jax.random.normal(keys[0], pool.shape, jnp.bfloat16)
+    layer = 1 if entry == "one_layer" else None
+    rows = 3.0 * jax.random.normal(
+        keys[2], (*(() if layer is not None else (L,)), len(slots), s,
+                  _HKV, _HD), jnp.float32)
+    want = _rows_reference(pool, layer, tables, starts, counts, rows)
+    got = jax.jit(pool_write)(pool, layer, jnp.asarray(tables),
+                              jnp.asarray(starts, jnp.int32),
+                              jnp.asarray(counts, jnp.int32), rows)
+    for leaf_got, leaf_want in zip(jax.tree.leaves(got),
+                                   jax.tree.leaves(want)):
+        np.testing.assert_array_equal(np.asarray(leaf_got), leaf_want)
+
+
 def test_quantized_row_bytes_accounting():
     """Rows are billed AS ALLOCATED — the engine's byte-budget sizing
     leans on this. A page's scales are one 128-lane f32 row per head

@@ -13,15 +13,24 @@ case, at no chip time. A compile that passes is not a chip run:
 ``chip_smoke.py`` is.
 
 The last cases compile the ENGINE's own step programs — the paged decode
-step, one bucket prefill, one chunk prefill — for a one-layer model at
-SmolLM2-1.7B's published widths, and hold their names against
-``benchmarks/data/trace_names.json``: the benchmark finds the step
-programs and the attention kernels in the profiler's trace by the names
-the compiler gives them today (``jit__decode_sample``, ``jit_fused``,
-``%closed_call.N = ... custom-call(``). A ``name=`` on a
-``pallas_call``, a ``jax.named_scope`` around one, or a renamed jitted
-function changes those names; this fails then, here, instead of two
-roofline metrics reading nothing on the chip.
+step, one bucket prefill, one chunk prefill — for a two-layer model at
+SmolLM2-1.7B's and Mistral-7B's published widths (with one layer the
+layer slice folds away and proves nothing). Two things are held:
+
+- their names against ``benchmarks/data/trace_names.json``: the
+  benchmark finds the step programs and the attention kernels in the
+  profiler's trace by the names the compiler gives them today
+  (``jit__decode_sample``, ``jit_fused``, ``%closed_call.N = ...
+  custom-call(``). A ``name=`` on a ``pallas_call``, a
+  ``jax.named_scope`` around one, or a renamed jitted function changes
+  those names; this fails then, here, instead of two roofline metrics
+  reading nothing on the chip;
+- the page pool's ONE physical layout: row-major ``{4,3,2,1,0}`` from
+  program entry to exit, no copy of the pool or of a layer's slice of
+  it, no temp that follows the pool's size. A pool written by ROWS is
+  laid out token-major by XLA and copied for the kernel in every
+  program and every layer-step — a third of the device's time in
+  PR 25's traces (``ops/paged_kv.pool_write``).
 
 The topology is described in a fixture, never at import: only one
 process may load the TPU's library, and every test worker imports
@@ -106,12 +115,21 @@ def test_flash_attention_compiles_for_v5e(chip):
 @pytest.mark.parametrize("quantized", [False, True],
                          ids=["bf16", "int8"])
 @pytest.mark.parametrize("kind,hd", [("decode", 64), ("decode", 128),
-                                     ("chunk", 64), ("tree", 64)])
+                                     ("chunk", 64), ("tree", 64),
+                                     ("layer", 64)])
 def test_paged_kernel_compiles_for_v5e(kind, hd, quantized, chip):
     pool = _pool(hd, quantized, chip)
     tables = _shape((B, MAX_PAGES), jnp.int32, chip)
     lens = _shape((B,), jnp.int32, chip)
-    if kind == "decode":
+    if kind == "layer":         # the whole pool and a traced layer index
+        whole = jax.tree.map(lambda x: _shape((4, *x.shape), x.dtype, chip),
+                             pool)
+        _compiles_to_kernel(
+            lambda q, k, v, t, n, li: paged_decode_attention_pallas(
+                q, k, v, t, n, layer=li),
+            _shape((B, HQ, hd), jnp.bfloat16, chip), whole, whole, tables,
+            lens, _shape((), jnp.int32, chip))
+    elif kind == "decode":
         _compiles_to_kernel(paged_decode_attention_pallas,
                             _shape((B, HQ, hd), jnp.bfloat16, chip),
                             pool, pool, tables, lens)
@@ -138,20 +156,37 @@ def trace_reader():
     return module
 
 
-@pytest.fixture(scope="module")
-def engine():
-    """The benchmark's builder on a one-layer model at SmolLM2-1.7B's
-    widths (benchmarks/configs/smollm2-1.7b.json), kernels by name: no
-    chip is attached, so nothing may be left to ``auto``."""
+#: published widths of the benchmark's two configurations
+#: (benchmarks/configs/): SmolLM2-1.7B packs two kv heads a pool row
+#: (Hg 16, head_dim 64), Mistral-7B-v0.3 is the unpacked path (Hg 8,
+#: head_dim 128)
+WIDTHS = {
+    "smollm2": dict(vocab_size=49152, dim=2048, n_heads=32, n_kv_heads=32,
+                    ffn_dim=8192, rope_theta=130000.0,
+                    tie_embeddings=True),
+    "mistral": dict(vocab_size=32768, dim=4096, n_heads=32, n_kv_heads=8,
+                    ffn_dim=14336, rope_theta=1e6, tie_embeddings=False),
+}
+#: pages of the pool the programs are compiled for: a layer's slice
+#: (128 MiB and up) then fits no fast memory the compiler could stage
+#: it in, as a deployment's does not
+POOL_PAGES = 1024
+
+
+def _engine(widths):
+    """The benchmark's builder on a two-layer model, kernels by name:
+    no chip is attached, so nothing may be left to ``auto``. Weights
+    are zeros of the right shapes — only shapes are compiled."""
     from gofr_tpu.models.llama import LlamaConfig, llama_init
     from gofr_tpu.serving.engine import EngineConfig
     from gofr_tpu.serving.glue import llama_engine
-    c = LlamaConfig(vocab_size=49152, dim=2048, n_layers=1, n_heads=32,
-                    n_kv_heads=32, ffn_dim=8192, max_seq=8192,
-                    rope_theta=130000.0, norm_eps=1e-5,
-                    tie_embeddings=True)
+    c = LlamaConfig(n_layers=2, max_seq=8192, norm_eps=1e-5,
+                    **WIDTHS[widths])
+    params = jax.tree.map(
+        lambda x: jnp.zeros(x.shape, x.dtype),
+        jax.eval_shape(lambda: llama_init(jax.random.key(0), c)))
     return llama_engine(
-        llama_init(jax.random.key(0), c), c,
+        params, c,
         EngineConfig(max_batch=B, max_seq=2048, prefill_buckets=(128,),
                      prefill_batch=4, kv_layout="paged",
                      paged_attention="kernel", page_size=PAGE, kv_pages=64,
@@ -159,10 +194,10 @@ def engine():
         implementation="pallas")
 
 
-def _step_program(engine, kind, chip):
+def _step_program(engine, kind, pages, chip):
     """(jitted function, its arguments as shapes on the chip) for one
     of the engine's step programs, in the order ``Engine.warmup``
-    passes them."""
+    passes them, over a pool of ``pages`` pages."""
     def like(tree):
         return jax.tree.map(lambda x: jax.ShapeDtypeStruct(
             x.shape, x.dtype, sharding=chip), tree)
@@ -170,13 +205,16 @@ def _step_program(engine, kind, chip):
     def sh(shape, dtype):
         return _shape(shape, dtype, chip)
 
-    params, kc, vc = (like(t) for t in (engine.params, engine.k_cache,
-                                        engine.v_cache))
-    pages = engine._pages_per_slot
+    def pool(x):
+        return sh((*x.shape[:2], pages, *x.shape[3:]), x.dtype)
+
+    params = like(engine.params)
+    kc, vc = pool(engine.k_cache), pool(engine.v_cache)
+    slot_pages = engine._pages_per_slot
     if kind == "decode":
         return engine._decode, (
             params, sh((B,), jnp.int32), sh((B,), bool),
-            like(engine._dev_zero), kc, vc, sh((B, pages), jnp.int32),
+            like(engine._dev_zero), kc, vc, sh((B, slot_pages), jnp.int32),
             sh((B,), jnp.int32), sh((B,), bool), sh((), jnp.int32),
             sh((B,), jnp.float32), sh((B,), jnp.float32),
             sh((B,), jnp.int32), like(engine._dev_decode_key))
@@ -186,20 +224,40 @@ def _step_program(engine, kind, chip):
     if kind == "bucket":
         return engine._get_prefill(128, 1), (
             params, sh((1, 128), jnp.int32), sh((1,), jnp.int32), kc, vc,
-            sh((1, pages), jnp.int32), *sampling)
+            sh((1, slot_pages), jnp.int32), *sampling)
     return engine._get_chunk_prefill(), (
         params, sh((1, 128), jnp.int32), kc, vc,
-        sh((1, pages), jnp.int32), sh((1,), jnp.int32),
+        sh((1, slot_pages), jnp.int32), sh((1,), jnp.int32),
         sh((1,), jnp.int32), *sampling)
+
+
+@pytest.fixture(scope="module")
+def compiled(chip):
+    """``compiled(widths, kind, pages)`` -> (optimised HLO text, temp
+    bytes, the pool's shape) of one engine step program, each compiled
+    once for the file (about 20 s apiece)."""
+    engines, programs = {}, {}
+
+    def get(widths, kind, pages=POOL_PAGES):
+        if (widths, kind, pages) not in programs:
+            if widths not in engines:
+                engines[widths] = _engine(widths)
+            fn, args = _step_program(engines[widths], kind, pages, chip)
+            exe = fn.lower(*args).compile()
+            programs[widths, kind, pages] = (
+                exe.as_text(), exe.memory_analysis().temp_size_in_bytes,
+                (*engines[widths].k_cache.shape[:2], pages,
+                 *engines[widths].k_cache.shape[3:]))
+        return programs[widths, kind, pages]
+    return get
 
 
 @pytest.mark.parametrize("kind,program", [
     ("decode", "decode"), ("bucket", "prefill"), ("chunk", "prefill")])
 def test_trace_names_find_the_engines_programs_and_kernels(
-        kind, program, engine, chip, trace_reader):
+        kind, program, compiled, trace_reader):
     names = trace_reader.load_names()
-    fn, args = _step_program(engine, kind, chip)
-    text = fn.lower(*args).compile().as_text()
+    text, _, _ = compiled("smollm2", kind)
     # the profiler names an execution jit_<function>(<fingerprint>)
     module = re.match(r"HloModule (\S+?),", text).group(1)
     assert trace_reader.classify(module, names["programs"]) == program
@@ -210,3 +268,57 @@ def test_trace_names_find_the_engines_programs_and_kernels(
     for line in kernels:
         assert trace_reader.classify(line, names["kernels"]) \
             == "attention", line[:160]
+
+
+# -------------------------------------- the pool's one physical layout
+#: results that hand the pool on or update it in place
+POOL_CARRIERS = {"parameter", "tuple", "get-tuple-element", "bitcast",
+                 "while", "conditional", "call", "scatter"}
+_RESULT = re.compile(r"^\s*(?:ROOT )?%(\S+) = (.*?) ([\w-]+)\(")
+_ARRAY = re.compile(r"\b[a-z]+\d*\[([\d,]*)\](?:\{([\d,]*))?")
+
+
+def _pool_shaped_results(text, pool_shape):
+    """(instruction, opcode, dims, layout) for every result in the
+    optimised HLO that has the pool's shape or one layer's slice of it;
+    a fusion's opcode is its root's (``fusion:scatter``)."""
+    roots, computation = {}, None
+    for line in text.splitlines():
+        if line.startswith("%") and line.rstrip().endswith("{"):
+            computation = line.split()[0]
+        m = _RESULT.match(line)
+        if m and "ROOT" in line.split("=")[0]:
+            roots[computation] = m.group(3)
+    whole = ",".join(map(str, pool_shape))
+    layer = ",".join(map(str, pool_shape[1:]))
+    found = []
+    for line in text.splitlines():
+        m = _RESULT.match(line)
+        if not m:
+            continue
+        name, types, op = m.groups()
+        if op == "fusion":
+            op = "fusion:" + roots.get(
+                re.search(r"calls=(%[\w.-]+)", line).group(1), "?")
+        for dims, layout in _ARRAY.findall(types):
+            if dims in (whole, layer, "1," + layer):
+                found.append((name, op, dims, layout))
+    return found
+
+
+@pytest.mark.parametrize("widths", sorted(WIDTHS))
+@pytest.mark.parametrize("kind", ["decode", "bucket", "chunk"])
+def test_pool_keeps_one_layout_and_is_never_copied(kind, widths, compiled):
+    text, temp, pool_shape = compiled(widths, kind)
+    results = _pool_shaped_results(text, pool_shape)
+    assert any(op in ("scatter", "fusion:scatter")
+               for _, op, _, _ in results), "the program writes no pool?"
+    copies = [r for r in results
+              if r[1] not in POOL_CARRIERS | {"fusion:scatter"}]
+    assert not copies, f"the pool, or a layer of it, is copied: {copies}"
+    relaid = [r for r in results if r[3] and r[3] != "4,3,2,1,0"]
+    assert not relaid, f"the pool leaves row-major: {relaid}"
+    # a temp that follows the slab is fine; one that follows the pool
+    # is the relayout
+    _, temp_twice, _ = compiled(widths, kind, 2 * POOL_PAGES)
+    assert temp_twice <= temp, (temp, temp_twice)
