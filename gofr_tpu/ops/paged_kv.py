@@ -31,6 +31,15 @@ pool's row width, so callers never see the packing
 (:func:`gather_view`, which has no such operand, takes ``head_dim``).
 A pool whose row width equals ``head_dim`` is simply ``pack == 1``.
 
+A model family states its cache row through its cache constructor,
+``make_cache(batch, max_seq) -> (k, v)`` each ``[L, B, S, heads,
+width]``, and the pool's row follows from that statement side by side
+(:func:`empty_pool`). A family that keeps ONE vector per token — latent
+attention's ``[L, B, S, 1, R]`` (models/deepseek.py) — states a V side
+of zero lanes: that side's pool holds zero bytes, every writer here
+hands it back untouched, and :func:`pool_row_bytes` counts nothing for
+it. No pool-sized stand-in takes its place.
+
 Everything here is a pure jittable function on static shapes:
 
 - :func:`gather_view` materialises a slot-contiguous ``[L, B, S, ...]``
@@ -185,6 +194,8 @@ def empty_pool(like: jnp.ndarray, n_pages: int, quantized: bool):
     ``n_pages`` in its final representation, built in place: no
     unpacked or unquantized transient the size of the pool."""
     l, h, _, pg, d = like.shape
+    if d == 0:      # the side a one-vector family does not keep
+        return jnp.zeros((l, h, n_pages, pg, 0), like.dtype)
     # placed like ``like`` only where it was placed on purpose (a mesh):
     # a pool pinned to the default device would commit every array the
     # jitted steps return and recompile each program at first use
@@ -207,7 +218,9 @@ def pool_shape(pool) -> tuple:
 def pool_row_bytes(pool) -> int:
     """HBM bytes per KV ROW (one token, all layers/heads, K or V side
     only) as allocated — a quantized pool's scale rows, pad lanes
-    included, are spread over the page's rows."""
+    included, are spread over the page's rows; a row's own pad lanes
+    (a latent row's 576 numbers in 640 lanes) count, since they are
+    stored. Nought for the side a one-vector family does not keep."""
     _, _, n_pages, pg, _ = pool_shape(pool)
     total = sum(x.size * x.dtype.itemsize
                 for x in jax.tree_util.tree_leaves(pool))
@@ -248,7 +261,12 @@ def pool_write(pool, layer, tables, starts, counts, rows):
     of one table; and two slots never write one page in one call —
     tail pages have one owner, and the prefix cache shares
     page-ALIGNED prefixes only (``Engine._register_prefix``), which a
-    later run starts behind."""
+    later run starts behind.
+
+    A side of zero lanes (the V side of a one-vector family) has
+    nothing to write and comes back as it is."""
+    if rows.shape[-1] == 0:
+        return pool
     hg, n_pages, pg, w = pool_shape(pool)[1:]
     s, _, d = rows.shape[-3:]
     pack = w // d

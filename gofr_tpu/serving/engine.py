@@ -725,15 +725,24 @@ class Engine:
             # ARGUMENT (not a captured constant) so the compiled HLO is
             # seed-independent — unseeded engines still hit the
             # persistent compile cache across processes.
+            # A family's step may return a fourth value: a small int32
+            # vector of counters it took on the device (the sparse-
+            # expert families' routing facts). They ride the token
+            # array as extra COLUMNS [T, B + n], so the collect's one
+            # download brings them: no second read, no sync.
             def one(carry, t):
                 toks, kc, vc, lens = carry
                 key = jax.random.fold_in(rng_key, step * T + t)
-                logits, kc, vc = step_fn(toks, kc, vc, lens)
+                logits, kc, vc, *facts = step_fn(toks, kc, vc, lens)
                 nxt = _sample_batch(logits, key, temps, top_ps, top_ks)
-                return (nxt, kc, vc, lens + 1), nxt
+                return (nxt, kc, vc, lens + 1), (nxt, *facts)
 
-            return jax.lax.scan(
+            carry, (toks, *facts) = jax.lax.scan(
                 one, (tokens, kc, vc, lengths), jnp.arange(T))
+            if facts:
+                toks = jnp.concatenate(
+                    [toks, facts[0].astype(toks.dtype)], axis=1)
+            return carry, toks
 
         def _advance_lengths(lengths, active):
             # persistent device lengths: advance active rows exactly as
@@ -795,10 +804,10 @@ class Engine:
                         return paged_decode_fn(params, toks, kp, vp,
                                                tables, lens)
 
-                    (_, k_pool, v_pool, _), toks = _fused_decode(
+                    (last, k_pool, v_pool, _), toks = _fused_decode(
                         step_fn, rng_key, toks_in, k_pool, v_pool,
                         lengths, step, temps, top_ps, top_ks)
-                    return (toks, toks[-1], k_pool, v_pool,  # [T,B],[B]
+                    return (toks, last, k_pool, v_pool,  # [T,B(+n)],[B]
                             _advance_lengths(lengths, active), step + 1)
                 self._decode = jax.jit(_decode_sample,
                                        donate_argnums=(4, 5))
@@ -1018,6 +1027,13 @@ class Engine:
         from ..ops.quant import quantized_bytes
         self._kv_bytes_total = int(quantized_bytes(
             (self.k_cache, self.v_cache)))
+        #: bytes one token's cache row takes as stored, all layers, both
+        #: sides (a one-vector family's V side counts nought)
+        self._kv_row_bytes = 0
+        if cfg.kv_layout == "paged":
+            from ..ops.paged_kv import pool_row_bytes
+            self._kv_row_bytes = pool_row_bytes(self.k_cache) \
+                + pool_row_bytes(self.v_cache)
         self.lengths = np.zeros(cfg.max_batch, np.int32)       # kv length per slot
         self.active: list[GenRequest | None] = [None] * cfg.max_batch
         # already-admitted work bounced back (preemption, slot races,
@@ -2410,11 +2426,13 @@ class Engine:
 
     def _pool_probe(self, page: int):
         """A ONE-page allocation from the model family's cache
-        constructor, re-laid head-major [L, Hkv, 1, pg, hd]: the dims,
-        dtype and (under a mesh) head-axis sharding the pool
-        constructor reads."""
+        constructor, each side re-laid head-major [L, Hkv, 1, pg, hd]:
+        the dims, dtype and (under a mesh) head-axis sharding the pool
+        constructor reads. The family states its row here — K and V of
+        ``Hkv`` heads, or one latent vector and a V side of no lanes."""
         from ..ops.paged_kv import pool_from_cache_shape
-        return pool_from_cache_shape(self._make_cache(1, page)[0])
+        k, v = self._make_cache(1, page)
+        return pool_from_cache_shape(k), pool_from_cache_shape(v)
 
     def _alloc_pool(self, page: int):
         """Allocate the paged pool in its final representation
@@ -2425,13 +2443,13 @@ class Engine:
         packs/quantizes inside the jitted scatters; this is the only
         place the representation is chosen."""
         from ..ops.paged_kv import empty_pool
-        probe = self._pool_probe(page)
+        k_probe, v_probe = self._pool_probe(page)
         # what the view fallback unpacks / dequantizes back to
-        self._kv_view_dtype = probe.dtype
-        self._kv_head_dim = probe.shape[-1]
+        self._kv_view_dtype = k_probe.dtype
+        self._kv_head_dim = k_probe.shape[-1]
         quantized = self.config.kv_dtype == "int8"
-        return (empty_pool(probe, self._n_pages, quantized),
-                empty_pool(probe, self._n_pages, quantized))
+        return (empty_pool(k_probe, self._n_pages, quantized),
+                empty_pool(v_probe, self._n_pages, quantized))
 
     def _gather_view(self, pool, tables):
         """Dense per-slot view [L, B, S, Hkv, hd] of the pool — the view
@@ -2451,12 +2469,12 @@ class Engine:
         if cfg.kv_dtype == "bf16" and cfg.kv_pool_bytes is None:
             return max(1, int(base_pages))
         from ..ops.paged_kv import empty_pool, pool_row_bytes
-        probe = self._pool_probe(page)
+        probes = self._pool_probe(page)
 
         def page_bytes(quantized: bool) -> int:
             # K + V, one page each, as allocated (scale rows included)
-            return 2 * probe.shape[3] * pool_row_bytes(
-                empty_pool(probe, 1, quantized))
+            return sum(probe.shape[3] * pool_row_bytes(
+                empty_pool(probe, 1, quantized)) for probe in probes)
 
         budget = (cfg.kv_pool_bytes if cfg.kv_pool_bytes is not None
                   else base_pages * page_bytes(False))
@@ -3147,11 +3165,10 @@ class Engine:
         verify dispatch, the view path leaves it flat."""
         if self.config.kv_layout != "paged":
             return
-        from ..ops.paged_kv import pool_row_bytes, pool_shape
+        from ..ops.paged_kv import pool_shape
         pg = pool_shape(self.k_cache)[3]
-        row_bytes = pool_row_bytes(self.k_cache)
         self.stats["view_bytes_avoided"] += \
-            2 * n_rows * self._pages_per_slot * pg * row_bytes
+            n_rows * self._pages_per_slot * pg * self._kv_row_bytes
 
     def _note_prefill_span(self, start: float) -> None:
         """prefill_s accumulates a UNION of dispatch→sync spans: two
@@ -3528,9 +3545,17 @@ class Engine:
             if self.recorder.enabled:
                 # the pass record: everything here is a host int/float the
                 # collect already computed — no device reads beyond the
-                # token sync that IS the collect
+                # token sync that IS the collect. A family whose step
+                # counts its routing on the device sent the counts as
+                # extra columns of the token array (_fused_decode)
+                facts = step_np[:, self.config.max_batch:]
+                routing = {} if not facts.shape[1] else {
+                    "experts_touched": int(facts[:, 0].sum()),
+                    "assignments": int(facts[:, 1].sum()),
+                    "kv_row_bytes": self._kv_row_bytes}
                 self.recorder.record_pass(
                     "decode", rec["pass_id"], t0=rec["t0"], t1=end,
+                    **routing,
                     rids=rec["rids"], ctx=rec["ctx"],
                     steps=self._tokens_per_pass, win=rec.get("win", 0),
                     dur=round(busy, 6),

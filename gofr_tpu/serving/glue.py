@@ -208,6 +208,91 @@ def moe_engine(params: Any, model_config, engine_config: EngineConfig | None = N
                   metrics=metrics, logger=logger, tracer=tracer)
 
 
+def deepseek_engine(params: Any, model_config,
+                    engine_config: EngineConfig | None = None, *,
+                    mesh: Any = None,
+                    metrics: Any = None, logger: Any = None,
+                    tracer: Any = None) -> Engine:
+    """The ``deepseek_v3`` family (models/deepseek.py) on the paged
+    serving path: bucket prefill with materialised attention, chunk
+    prefill and decode with absorbed attention straight against the
+    latent page pool, experts computed sparsely. The family states its
+    cache row through ``make_cache`` — one latent vector a token, a V
+    side of no lanes — and the engine's pool, writers and capacity maths
+    follow from that statement.
+
+    What the family does not support is refused here, by name, not
+    found out in warm-up."""
+    from ..models.deepseek import (deepseek_decode_step_paged,
+                                   deepseek_prefill_chunk_paged,
+                                   deepseek_prefill_last,
+                                   make_latent_cache)
+    from ..ops.latent_attention import check_latent_layout
+    from ..ops.paged_kv import pool_from_cache_shape
+    from dataclasses import replace
+    from ..ops.attention import is_tpu
+    c = model_config
+    cfg = engine_config or EngineConfig(kv_layout="paged")
+    if cfg.paged_attention == "auto":
+        # the engine's own "auto" falls back to the dense view off the
+        # TPU, which this family has no step for
+        cfg = replace(cfg,
+                      paged_attention="kernel" if is_tpu() else "xla")
+    refused = [
+        (mesh is not None,
+         "mesh=: the latent kernel and the grouped expert matmul are "
+         "single-device programs; neither is shard_mapped yet"),
+        (cfg.kv_layout != "paged",
+         f"kv_layout={cfg.kv_layout!r}: the family has paged step "
+         f"functions only (chunk prefill and decode read history from the "
+         f"latent page pool); set kv_layout='paged'"),
+        (cfg.kv_dtype != "bf16",
+         f"kv_dtype={cfg.kv_dtype!r}: a latent row's 512 compressed lanes "
+         f"and 64 rope lanes have no int8 scale layout yet"),
+        (cfg.speculative,
+         "speculative=True: the latent kernel has no tree-verify mask"),
+        (cfg.paged_attention == "view",
+         "paged_attention='view': the family has no dense-view decode "
+         "step; use 'auto', 'kernel', 'interpret' or 'xla'"),
+    ]
+    for bad, why in refused:
+        if bad:
+            raise ValueError(f"deepseek_engine does not support {why}")
+    impl = {"kernel": "pallas", "interpret": "interpret",
+            "xla": "xla"}.get(cfg.paged_attention, "auto")
+    if impl == "pallas":
+        # a row the compiled kernel cannot take fails HERE, by name
+        check_latent_layout(
+            pool_from_cache_shape(make_latent_cache(c, 1, cfg.page_size)[0]),
+            c.kv_lora_rank)
+
+    def prefill_fn(params, tokens, kv_lengths):
+        return deepseek_prefill_last(params, tokens, c,
+                                     kv_lengths=kv_lengths)
+
+    def make_cache(batch, max_seq):
+        return make_latent_cache(c, batch, max_seq)
+
+    def paged_decode_fn(params, tokens, pool, v_pool, tables, lengths):
+        return deepseek_decode_step_paged(params, tokens, pool, v_pool,
+                                          tables, lengths, c,
+                                          implementation=impl)
+
+    def paged_chunk_fn(params, tokens, pool, v_pool, tables, offsets,
+                       chunk_lengths):
+        return deepseek_prefill_chunk_paged(
+            params, tokens, pool, v_pool, tables, offsets, chunk_lengths,
+            c, implementation=impl)
+
+    # prefill_chunk_fn is what switches chunk walks and the prefix cache
+    # on; the native path never calls it (the view path is refused above)
+    return Engine(params, cfg, prefill_fn=prefill_fn, decode_fn=None,
+                  make_cache=make_cache, prefill_chunk_fn=paged_chunk_fn,
+                  paged_decode_fn=paged_decode_fn,
+                  paged_chunk_fn=paged_chunk_fn,
+                  metrics=metrics, logger=logger, tracer=tracer)
+
+
 def demo_llama_engine(engine_config: EngineConfig | None = None,
                       seed: int = 0, **kw) -> Engine:
     """Tiny random-weight engine for tests and examples."""

@@ -178,6 +178,12 @@ class FlightRecorder:
 
     def record_pass(self, kind: str, pass_id: int | None = None,
                     **fields: Any) -> None:
+        """One device pass: the engine names the rows and contexts it
+        served (docs/observability.md has the fields). A decode pass of
+        a sparse-expert family also carries ``experts_touched``,
+        ``assignments`` (counted on the device in the model's step,
+        read with the sampled tokens) and ``kv_row_bytes`` (the pool's
+        stored bytes a token, from the model's stated row)."""
         if not self.enabled:
             return
         rec = {"pass_id": self.new_pass() if pass_id is None else pass_id,

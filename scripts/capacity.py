@@ -19,6 +19,16 @@ waste rise as the batch saturates).
 
 The engine is the demo tiny-llama family (same as scripts/replay.py);
 for a production model call :func:`sweep` against your own engine.
+
+    python scripts/capacity.py --kv-row benchmarks/configs/NAME.json
+
+prints, for a benchmark configuration file, what one token costs the
+page pool and what the configured pool therefore holds. The row comes
+from the model family's own statement of it (its cache constructor, as
+``Engine._alloc_pool`` reads it) laid out by ``ops/paged_kv``: K and V
+of every kv head for the Llama family, ONE latent vector and no V side
+for the ``deepseek_v3`` family, whose 576 numbers a layer are stored in
+640 lanes (``needed`` against ``stored``).
 """
 
 import argparse
@@ -119,10 +129,50 @@ def setpoint_doc(result: dict) -> dict:
     }
 
 
+def kv_row_report(cfg: dict) -> dict:
+    """Cache bytes of one token for a benchmark configuration file's
+    model and engine keys, from the family's stated row."""
+    import importlib
+
+    from gofr_tpu.ops.paged_kv import (empty_pool, pool_from_cache_shape,
+                                       pool_row_bytes)
+    b, eng = cfg["builder"], cfg["engine"]
+    model = getattr(importlib.import_module(b["model_module"]),
+                    b["model_class"])(
+        **{field: cfg[key] for field, key in b["model_keys"].items()})
+    if b["model_class"] == "DeepseekConfig":
+        from gofr_tpu.models.deepseek import (latent_row_bytes,
+                                              make_latent_cache)
+        k, v = make_latent_cache(model, 1, eng["page_size"])
+        needed = latent_row_bytes(model)[0]
+    else:
+        from gofr_tpu.models.llama import make_empty_cache
+        k, v = make_empty_cache(model, 1, max_seq=eng["page_size"])
+        needed = None
+    quantized = eng.get("kv_dtype", "bf16") == "int8"
+    sides = [pool_row_bytes(empty_pool(pool_from_cache_shape(x), 1,
+                                       quantized)) for x in (k, v)]
+    stored = sum(sides)
+    pages = eng.get("kv_pages")
+    out = {"config": cfg.get("name"), "kv_dtype": eng.get("kv_dtype", "bf16"),
+           "k_side_bytes_per_token": sides[0],
+           "v_side_bytes_per_token": sides[1],
+           "stored_bytes_per_token": stored,
+           "needed_bytes_per_token": stored if needed is None else needed,
+           "page_size": eng["page_size"], "kv_pages": pages}
+    if pages:
+        out["pool_tokens"] = pages * eng["page_size"]
+        out["pool_bytes"] = pages * eng["page_size"] * stored
+    return out
+
+
 def main() -> int:
     ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
-    ap.add_argument("workload", help="workload JSONL file "
+    ap.add_argument("workload", nargs="?", help="workload JSONL file "
                     "(GET /debug/workload)")
+    ap.add_argument("--kv-row", metavar="CONFIG.json", default=None,
+                    help="print the cache bytes a token costs for a "
+                    "benchmark configuration file, and stop")
     ap.add_argument("--levels", default="1,2,4,8,16",
                     help="comma-separated closed-loop concurrency "
                     "ladder (default 1,2,4,8,16)")
@@ -144,6 +194,12 @@ def main() -> int:
                     "(max_concurrency, qps, per-level goodput) for "
                     "the router autoscaler and CI")
     args = ap.parse_args()
+    if args.kv_row:
+        with open(args.kv_row) as f:
+            print(json.dumps(kv_row_report(json.load(f)), indent=2))
+        return 0
+    if not args.workload:
+        ap.error("a workload file, or --kv-row CONFIG.json")
 
     try:
         levels = sorted({int(x) for x in args.levels.split(",")

@@ -538,7 +538,10 @@ class TestLiveProxy:
         assert resp.status == 200
         assert w1.app._test_state["started"].wait(10)
         # the client walks away after the first chunk
-        conn.sock.recv(1)  # ensure the first write landed
+        resp.read(1)  # ensure the first write landed (through the
+        #               response's own buffer: the first chunk may have
+        #               arrived with the headers, and a read of the bare
+        #               socket would then wait for the second)
         conn.close()
         # unblock the worker: the router's NEXT chunk write hits the
         # dead client socket and must cancel the upstream

@@ -177,6 +177,8 @@ def _engine(widths):
     """The benchmark's builder on a two-layer model, kernels by name:
     no chip is attached, so nothing may be left to ``auto``. Weights
     are zeros of the right shapes — only shapes are compiled."""
+    if widths == "kanana":
+        return _kanana_engine()
     from gofr_tpu.models.llama import LlamaConfig, llama_init
     from gofr_tpu.serving.engine import EngineConfig
     from gofr_tpu.serving.glue import llama_engine
@@ -192,6 +194,24 @@ def _engine(widths):
                      paged_attention="kernel", page_size=PAGE, kv_pages=64,
                      eos_id=-1, autoprof=False),
         implementation="pallas")
+
+
+def _kanana_engine():
+    """``deepseek_engine`` at Kanana-2-30B-A3B's published widths
+    (benchmarks/configs/kanana-2-30b-a3b-6l.json), one dense and two
+    expert layers, every expert of each. The weights are SHAPES: three
+    such layers are 3.7 GB, and only shapes are compiled."""
+    from gofr_tpu.models.deepseek import DeepseekConfig, deepseek_init
+    from gofr_tpu.serving.engine import EngineConfig
+    from gofr_tpu.serving.glue import deepseek_engine
+    c = DeepseekConfig(num_hidden_layers=3)
+    params = jax.eval_shape(lambda: deepseek_init(jax.random.key(0), c))
+    return deepseek_engine(
+        params, c,
+        EngineConfig(max_batch=B, max_seq=2048, prefill_buckets=(128,),
+                     prefill_batch=4, kv_layout="paged",
+                     paged_attention="kernel", page_size=PAGE, kv_pages=64,
+                     eos_id=-1, autoprof=False))
 
 
 def _step_program(engine, kind, pages, chip):
@@ -270,6 +290,30 @@ def test_trace_names_find_the_engines_programs_and_kernels(
             == "attention", line[:160]
 
 
+def test_kanana_programs_keep_one_attention_kernel_class(compiled,
+                                                          trace_reader):
+    """The latent kernel is the one Pallas call of the decode and chunk
+    programs that the trace names ``%closed_call``: XLA's grouped
+    matmul is a Mosaic kernel too, under its own name
+    (``%ragged-dot-...``), and must not read as attention."""
+    names = trace_reader.load_names()
+    for kind, program in (("decode", "decode"), ("chunk", "prefill"),
+                          ("bucket", "prefill")):
+        text, _, _ = compiled("kanana", kind)
+        module = re.match(r"HloModule (\S+?),", text).group(1)
+        assert trace_reader.classify(module, names["programs"]) == program
+        kernels = [line.strip() for line in text.splitlines()
+                   if 'custom_call_target="tpu_custom_call"' in line]
+        classes = {line.split(" = ")[0].rstrip(".0123456789"):
+                   trace_reader.classify(line, names["kernels"])
+                   for line in kernels}
+        assert all(k.startswith("%ragged-dot") for k, cls in classes.items()
+                   if cls is None), classes
+        # the bucket program attends on XLA (PERF.md section 7)
+        assert ("attention" in classes.values()) == (kind != "bucket"), \
+            classes
+
+
 # -------------------------------------- the pool's one physical layout
 #: results that hand the pool on or update it in place
 POOL_CARRIERS = {"parameter", "tuple", "get-tuple-element", "bitcast",
@@ -291,6 +335,11 @@ def _pool_shaped_results(text, pool_shape):
             roots[computation] = m.group(3)
     whole = ",".join(map(str, pool_shape))
     layer = ",".join(map(str, pool_shape[1:]))
+    shapes = {whole, layer, "1," + layer}
+    # a one-head pool (a latent row, Hg 1) is handed on with its unit
+    # dim squeezed away — a bitcast: the same buffer under another shape
+    shapes |= {",".join(d for d in x.split(",") if d != "1")
+               for x in (whole, layer)}
     found = []
     for line in text.splitlines():
         m = _RESULT.match(line)
@@ -301,12 +350,12 @@ def _pool_shaped_results(text, pool_shape):
             op = "fusion:" + roots.get(
                 re.search(r"calls=(%[\w.-]+)", line).group(1), "?")
         for dims, layout in _ARRAY.findall(types):
-            if dims in (whole, layer, "1," + layer):
+            if dims in shapes:
                 found.append((name, op, dims, layout))
     return found
 
 
-@pytest.mark.parametrize("widths", sorted(WIDTHS))
+@pytest.mark.parametrize("widths", [*sorted(WIDTHS), "kanana"])
 @pytest.mark.parametrize("kind", ["decode", "bucket", "chunk"])
 def test_pool_keeps_one_layout_and_is_never_copied(kind, widths, compiled):
     text, temp, pool_shape = compiled(widths, kind)
@@ -316,7 +365,8 @@ def test_pool_keeps_one_layout_and_is_never_copied(kind, widths, compiled):
     copies = [r for r in results
               if r[1] not in POOL_CARRIERS | {"fusion:scatter"}]
     assert not copies, f"the pool, or a layer of it, is copied: {copies}"
-    relaid = [r for r in results if r[3] and r[3] != "4,3,2,1,0"]
+    relaid = [r for r in results if r[3] and r[3] != ",".join(
+        map(str, reversed(range(r[2].count(",") + 1))))]
     assert not relaid, f"the pool leaves row-major: {relaid}"
     # a temp that follows the slab is fine; one that follows the pool
     # is the relayout
