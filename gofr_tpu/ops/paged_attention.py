@@ -6,24 +6,31 @@ tables. The generic engine path materialises a dense per-slot view of
 the WHOLE pool allocation every K-step pass (``gather_view``), which
 costs O(full-cache) extra HBM traffic on top of attention's own reads.
 
-The kernel here removes the materialisation: each grid cell (slot b,
-head group h, q block) walks ONLY the pages covering the rows that
-block may attend (ragged — shorter slots read fewer pages), DMA-ing
-pages HBM→VMEM double-buffered and folding them into an online-softmax
-accumulator. The pool is never reshaped, copied, or padded to the
-per-slot maximum.
+The kernels here remove the materialisation: a grid cell walks ONLY
+the pages covering the rows it may attend (ragged — shorter slots read
+fewer pages), DMA-ing pages HBM→VMEM double-buffered and folding them
+into an online-softmax accumulator. The pool is never reshaped,
+copied, or padded to the per-slot maximum.
 
-One kernel serves the three hot paths; they differ only in the mask:
+One algorithm, two ``pallas_call``s that share the fold
+(:func:`_softmax_fold`, :func:`_row_scales`):
 
-- *chunk* (``paged_chunk_attention``): Sq new positions per slot —
-  chunked prefill, prefix-cache suffix reattachment — already written
-  at pool rows ``[history, history + chunk_len)``; query row i attends
-  causally to rows ``<= history + i``.
-- *decode* (``paged_decode_attention``): the chunk of one row —
-  ``history = length - 1``, ``chunk_len = 1``.
-- *tree* (``paged_tree_attention``): speculative verify; the Sq rows
-  are NODES of a draft tree and in-chunk visibility is a packed
-  ancestor bitmask instead of causal order (see below).
+- the *q-block* walk (:func:`_ragged_kernel`), a cell a (slot, head
+  group, q block), serves the paths with many query rows a slot; they
+  differ only in the mask:
+
+  - *chunk* (``paged_chunk_attention``): Sq new positions per slot —
+    chunked prefill, prefix-cache suffix reattachment — already
+    written at pool rows ``[history, history + chunk_len)``; query row
+    i attends causally to rows ``<= history + i``.
+  - *tree* (``paged_tree_attention``): speculative verify; the Sq rows
+    are NODES of a draft tree and in-chunk visibility is a packed
+    ancestor bitmask instead of causal order (see below).
+
+- the *decode* walk (:func:`_decode_kernel`, ``paged_decode_attention``)
+  serves the chunk of ONE row — ``history = length - 1`` — where the
+  price is the walk itself: a cell a slot, every head group of a page
+  in one fold (see "the decode walk" below).
 
 What the TPU's compiler takes (established by ahead-of-time compiles
 for v5e — ``tests/test_tpu_compile.py`` keeps them):
@@ -51,6 +58,27 @@ for v5e — ``tests/test_tpu_compile.py`` keeps them):
   axis is zero-padded up to the tile and sliced back after the call.
 - A page is DMA'd to row offset ``j * page`` of the VMEM double
   buffer, so the page size must be a multiple of 8.
+- What the decode walk asked of Mosaic, and got at the first compile
+  (v5e, jax 0.9.0): ``pool.at[layer, :, pid]`` — EVERY head group of
+  a page as one strided DMA ``[Hg, page, W]`` (only untiled leading
+  dims are sliced) into ``buf.at[half, :, pl.ds(j * page, page), :]``;
+  a DMA started in one grid step and waited in the next (the axis is
+  ``"arbitrary"``; v5e has one TensorCore); SMEM scratch that lives
+  across grid steps; ``dot_general`` batched over the head-group axis
+  with the contraction on the LAST dim of both operands
+  (``[Hg, rows, W] x [Hg, kv, W]``); bf16 x bf16 with a float32
+  result; int8 -> bf16 of a whole fold; a float32 lhs split into
+  three bf16 terms, concatenated along sublanes as float32 and cast
+  (32 rows: a bf16 tile is 16). What it refused: a fold of 8 MiB —
+  two of them are 16.19 MiB of scoped VMEM against the 16 MiB a
+  kernel gets unasked; folds of 1, 2 and 4 MiB compile.
+- What the chip said (PERF.md section 6, PR 29): a fold of the q-block
+  walk costs 0.43 us and a cell 0.62 us WHATEVER they hold, because a
+  fold is a chain (matmul -> row max -> exp -> row sum -> matmul ->
+  accumulate) on one 8 x 128 tile, priced by latency; bf16 instead of
+  float32 operands change nothing there. More work under one chain —
+  head groups batched, 256-512 kv rows a fold — is what made a fold
+  cost its bytes (78% of 819 GB/s at 31 slots of ~800 rows).
 
 A shape the kernel cannot take is a ``ValueError`` from
 :func:`check_kernel_layout` — at engine construction, not a Mosaic
@@ -70,8 +98,8 @@ reference), 'auto' (pallas on TPU, xla elsewhere).
 
 Quantized pools (``kv_dtype="int8"``) arrive as the two-leaf pytree
 ``{"q": int8 [L, Hg, Np, pg, W], "s": f32 [L, Hg, Np, 1, SW]}`` from
-:mod:`.paged_kv`. The kernel DMAs each int8 page plus its one-row
-scale block and never dequantizes a page: the scale of kv row t is
+:mod:`.paged_kv`. The kernels DMA each int8 page plus its one-row
+scale block and never dequantize a page: the scale of kv row t is
 constant along the contraction, so it multiplies the SCORE column
 (``(q @ codes^T) * ks``) and the probability column (``(p * vs) @
 codes``) instead — both have the kv row on the lane axis, which is
@@ -98,6 +126,8 @@ NEG_INF = -1e30
 #: rows: a BlockSpec block or memref slice along it must cover a
 #: multiple of 8.
 SUBLANE = 8
+#: ... and of a bf16 memref in units of 16 (two rows share a sublane)
+BF16_SUBLANE = 16
 
 
 def _pad_group(group: int, block_q: int = 1) -> int:
@@ -136,6 +166,93 @@ def check_kernel_layout(pool) -> None:
             f"to row offset j * page of a VMEM buffer tiled in "
             f"{SUBLANE}-row sublanes — use a page_size multiple of "
             f"{SUBLANE} (or paged_attention='xla'/'view' explicitly).")
+
+
+# ------------------------------------------------- the fold, shared
+
+def _mxu_dot(lhs, rhs, contract: int):
+    """float32 ``lhs [..., R, X] . rhs`` over ``rhs``'s axis ``contract``
+    (leading axes batched), with ``rhs`` a tile of the pool entering
+    the MXU in the dtype it is STORED in. A bf16 x bf16 product is exact
+    in float32, so nothing is rounded that the float32 matmul keeps:
+
+    - a bf16 or int8 tile (int8 codes are exact in bf16) against a bf16
+      ``lhs`` is one pass;
+    - against a float32 ``lhs`` (the probabilities) the lhs is split
+      into three bf16 terms ``hi + mid + lo`` that sum to it exactly —
+      what a float32 matmul does inside, except that the tile, which is
+      all ``hi``, is loaded into the MXU once instead of six times;
+    - a float32 tile (tests, tools) takes the float32 matmul."""
+    batch = tuple(range(lhs.ndim - 2))
+    dims = (((lhs.ndim - 1,), (contract,)), (batch, batch))
+
+    def dot(a, b):
+        return jax.lax.dot_general(a, b, dims,
+                                   preferred_element_type=jnp.float32)
+
+    if rhs.dtype not in (jnp.bfloat16, jnp.int8):
+        return dot(lhs.astype(jnp.float32), rhs.astype(jnp.float32))
+    rhs = rhs.astype(jnp.bfloat16)
+    if lhs.dtype == jnp.bfloat16:
+        return dot(lhs, rhs)
+    terms, rest = [], lhs.astype(jnp.float32)
+    for _ in range(3):
+        terms.append(rest.astype(jnp.bfloat16).astype(jnp.float32))
+        rest = rest - terms[-1]
+    r = lhs.shape[-2]
+    pad = -3 * r % BF16_SUBLANE        # a bf16 tile is 16 rows
+    if pad:
+        terms.append(jnp.zeros((*lhs.shape[:-2], pad, lhs.shape[-1]),
+                               jnp.float32))
+    out = dot(jnp.concatenate(terms, axis=-2).astype(jnp.bfloat16), rhs)
+    return (out[..., :r, :] + out[..., r:2 * r, :]
+            + out[..., 2 * r:3 * r, :])
+
+
+def _softmax_fold(s, visible, pv, acc_ref, m_ref, l_ref):
+    """Fold one block of scores ``s [..., rows, kv]`` into the online
+    softmax: ``visible`` masks it, ``pv(p)`` is the probabilities'
+    product with the block's values."""
+    s = jnp.where(visible, s, NEG_INF)
+    m_prev = m_ref[...]
+    m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+    alpha = jnp.exp(m_prev - m_new)
+    # mask p explicitly: a fully-masked row has s == m_new == NEG_INF
+    # and exp(s - m_new) would be 1
+    p = jnp.where(visible, jnp.exp(s - m_new), 0.0)
+    l_ref[...] = l_ref[...] * alpha + jnp.sum(p, axis=-1, keepdims=True)
+    acc_ref[...] = acc_ref[...] * alpha + pv(p)
+    m_ref[...] = m_new
+
+
+def _row_scales(pages, row_head, *, page: int, pack: int):
+    """Per-(q row, kv row) dequant scales ``[..., rows | 1, kv]`` of a
+    fold, from its pages' scale rows ``[..., 1, SW]`` in walk order.
+    Page j's row holds packed head p's ``page`` scales at lanes
+    [p*page, (p+1)*page); the fold's kv rows want them at lanes
+    [j*page, (j+1)*page) — a static lane rotate per (page, head) and a
+    select per q row's head, a 128-lane tile of the fold at a time."""
+    sw = pages[0].shape[-1]
+    axis = pages[0].ndim - 1
+    lane = jax.lax.broadcasted_iota(jnp.int32, (1,) * axis + (sw,), axis)
+    per_tile = max(1, LANES // page)
+    tiles = []
+    for t in range(0, len(pages), per_tile):
+        tile, out = pages[t:t + per_tile], None
+        for p in range(pack):
+            sc = None
+            for j, row in enumerate(tile):
+                shift = ((j - p) * page) % sw
+                if shift:
+                    row = pltpu.roll(row, shift, axis)
+                sc = row if sc is None else \
+                    jnp.where(lane >= j * page, row, sc)
+            sc = sc[..., :len(tile) * page]
+            out = sc if out is None else \
+                jnp.where(row_head == p, sc, out)
+        tiles.append(out)
+    return tiles[0] if len(tiles) == 1 else \
+        jnp.concatenate(tiles, axis=-1)
 
 
 # ------------------------------------------------------------------ kernel
@@ -236,29 +353,6 @@ def _ragged_kernel(tables_ref, history_ref, chunk_ref, layer_ref, *refs,
                                  tree_ref[b, qb * block_q + t], mask_row)
     qf = q_ref[0, 0].astype(jnp.float32) * scale        # [rows, W]
 
-    def row_scales(s_buf, slot):
-        """Per-(q row, kv row) dequant scales [rows | 1, chunk] of the
-        chunk in ``slot``. Page j's scale row [1, SW] holds packed head
-        p's ``page`` scales at lanes [p*page, (p+1)*page); the chunk's
-        kv rows want them at lanes [j*page, (j+1)*page) — a static
-        lane rotate per (page, head), then a select per q row's head."""
-        sw = s_buf.shape[-1]
-        lane = jax.lax.broadcasted_iota(jnp.int32, (1, sw), 1)
-        out = None
-        for p in range(pack):
-            sc = None
-            for j in range(pages_per_chunk):
-                row = s_buf[slot, j]                        # [1, SW]
-                shift = ((j - p) * page) % sw
-                if shift:
-                    row = pltpu.roll(row, shift, 1)
-                sc = row if sc is None else \
-                    jnp.where(lane >= j * page, row, sc)
-            sc = sc[:, :chunk]
-            out = sc if out is None else \
-                jnp.where(row_head == p, sc, out)
-        return out
-
     def body(ci, _):
         slot = jax.lax.rem(ci, 2)
 
@@ -274,7 +368,9 @@ def _ragged_kernel(tables_ref, history_ref, chunk_ref, layer_ref, *refs,
             (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32)         # [rows, chunk]
         if quantized:
-            s = s * row_scales(ks_buf, slot)
+            s = s * _row_scales(
+                [ks_buf[slot, j] for j in range(pages_per_chunk)],
+                row_head, page=page, pack=pack)
         pos = ci * chunk + jax.lax.broadcasted_iota(
             jnp.int32, s.shape, 1)
         if tree:
@@ -294,22 +390,18 @@ def _ragged_kernel(tables_ref, history_ref, chunk_ref, layer_ref, *refs,
             # position masked — into exact zeros via the denom clamp
             # instead of finite garbage.
             visible = (pos <= q_pos) & (pos < hist + clen)
-        s = jnp.where(visible, s, NEG_INF)
 
-        m_prev = m_ref[:]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
-        alpha = jnp.exp(m_prev - m_new)
-        # mask p explicitly: a fully-masked row has s == m_new ==
-        # NEG_INF and exp(s - m_new) would be 1
-        p = jnp.where(visible, jnp.exp(s - m_new), 0.0)
-        l_ref[:] = l_ref[:] * alpha + jnp.sum(p, axis=-1, keepdims=True)
-        if quantized:
-            p = p * row_scales(vs_buf, slot)
-        acc_ref[:] = acc_ref[:] * alpha + jax.lax.dot_general(
-            p, v_buf[slot].astype(jnp.float32),
-            (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)         # [rows, W]
-        m_ref[:] = m_new
+        def pv(p):
+            if quantized:
+                p = p * _row_scales(
+                    [vs_buf[slot, j] for j in range(pages_per_chunk)],
+                    row_head, page=page, pack=pack)
+            return jax.lax.dot_general(
+                p, v_buf[slot].astype(jnp.float32),
+                (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)     # [rows, W]
+
+        _softmax_fold(s, visible, pv, acc_ref, m_ref, l_ref)
         return 0
 
     jax.lax.fori_loop(0, n_chunks, body, 0)
@@ -325,6 +417,37 @@ def _pick_block_q(sq: int) -> int:
         if sq % cand == 0:
             return min(cand, sq)
     return 1
+
+
+def _group_rows(q, hg: int, pack: int, block_q: int):
+    """q [B, Sq, Hq, hd] -> ([B, Hg, Sq*group, W], padded GQA group):
+    the q rows flattened OUTSIDE the kernel so each grid cell reads
+    plain 2D [BQ*group, W] blocks — the q-block axis slices the (tiled)
+    second-to-last dim in BQ*group-row steps, which must be multiples
+    of 8: narrow blocks (decode, a spec-verify window with block_q=1)
+    pad the GQA axis up to the tile and the pad comes back off the
+    output (:func:`_ungroup_rows`). Each packed head's rows are zero
+    outside its own lanes (block diagonal)."""
+    b, sq, hq, hd = q.shape
+    g = hq // (hg * pack)
+    gp = _pad_group(g, block_q * pack)
+    q6 = q.reshape(b, sq, hg, pack, g, hd)
+    if gp != g:
+        q6 = jnp.pad(q6, ((0, 0),) * 4 + ((0, gp - g), (0, 0)))
+    eye = jnp.eye(pack, dtype=q.dtype)[None, None, None, :, None, :, None]
+    q4 = (q6[..., None, :] * eye).transpose(0, 2, 1, 3, 4, 5, 6) \
+        .reshape(b, hg, sq * pack * gp, pack * hd)
+    return q4, gp
+
+
+def _ungroup_rows(out, sq: int, hq: int, hd: int, pack: int, gp: int):
+    """The kernel's [B, Hg, Sq*group, W] back to [B, Sq, Hq, hd]: packed
+    head p's output sits in its rows' lanes [p*hd, (p+1)*hd)."""
+    b, hg = out.shape[:2]
+    g = hq // (hg * pack)
+    out = out.reshape(b, hg, sq, pack, gp, pack, hd)
+    out = jnp.stack([out[:, :, :, p, :g, p] for p in range(pack)], axis=3)
+    return out.transpose(0, 2, 1, 3, 4, 5).reshape(b, sq, hq, hd)
 
 
 def _ragged_attention(q, k_pool, v_pool, tables, history_lens, chunk_lens,
@@ -344,7 +467,6 @@ def _ragged_attention(q, k_pool, v_pool, tables, history_lens, chunk_lens,
     _, hg, n_pages, page, width = k_codes.shape
     _, max_pages = tables.shape
     pack = width // hd
-    g = hq // (hg * pack)
     scale = scale if scale is not None else hd ** -0.5
     if tree and sq > 32:
         raise ValueError(f"tree width {sq} exceeds the 32-node packed "
@@ -360,21 +482,8 @@ def _ragged_attention(q, k_pool, v_pool, tables, history_lens, chunk_lens,
     pages_per_chunk = max(1, min(max_pages, LANES // page))
     chunk = pages_per_chunk * page
 
-    # [B, Hg, Sq*group, W]: q rows flattened OUTSIDE the kernel so each
-    # grid cell reads a plain 2D [BQ*group, W] block — the q-block axis
-    # slices the (tiled) second-to-last dim in BQ*group-row steps,
-    # which must be multiples of 8: narrow blocks (decode, a
-    # spec-verify window with block_q=1) pad the GQA axis up to the
-    # tile and the pad comes back off the output below. Each packed
-    # head's rows are zero outside its own lanes (block diagonal).
-    gp = _pad_group(g, block_q * pack)
+    q4, gp = _group_rows(q, hg, pack, block_q)
     group = pack * gp
-    q6 = q.reshape(b, sq, hg, pack, g, hd)
-    if gp != g:
-        q6 = jnp.pad(q6, ((0, 0),) * 4 + ((0, gp - g), (0, 0)))
-    eye = jnp.eye(pack, dtype=q.dtype)[None, None, None, :, None, :, None]
-    q4 = (q6[..., None, :] * eye).transpose(0, 2, 1, 3, 4, 5, 6) \
-        .reshape(b, hg, sq * group, width)
     kernel = functools.partial(
         _ragged_kernel, page=page, pages_per_chunk=pages_per_chunk,
         max_pages=max_pages, n_pages=n_pages, scale=scale,
@@ -425,10 +534,243 @@ def _ragged_attention(q, k_pool, v_pool, tables, history_lens, chunk_lens,
             dimension_semantics=("parallel", "parallel", "parallel")),
         interpret=interpret,
     )(*args)
-    # packed head p's output sits in its rows' lanes [p*hd, (p+1)*hd)
-    out = out.reshape(b, hg, sq, pack, gp, pack, hd)
-    out = jnp.stack([out[:, :, :, p, :g, p] for p in range(pack)], axis=3)
-    return out.transpose(0, 2, 1, 3, 4, 5).reshape(b, sq, hq, hd)
+    return _ungroup_rows(out, sq, hq, hd, pack, gp)
+
+
+# --------------------------------------------------------- decode walk
+#
+# Decode is the chunk of one row, and the q-block grid above prices it
+# badly (v5e, PERF.md section 6, PR 29): a cell (slot, head group)
+# costs 0.62 us and a fold 0.43 us whatever they hold — a cell starts
+# its first fold and waits on it at once, a fold is a chain of
+# dependent operations on one 8 x 128 tile — at 512 cells a layer-step
+# for 32 slots x 16 head groups, one fold even for a slot that holds
+# no request. The walk below is the same algorithm with the parameters
+# one row a slot wants: a cell is a SLOT, a fold carries every head
+# group of its pages (one strided DMA a page and side, 16 or 8 times
+# the work under one chain), the next fold — the next live slot's
+# first one included — is in flight while this one is folded, a slot
+# without rows costs a grid step and a zero store, and K and V enter
+# the MXU as stored (:func:`_mxu_dot`).
+
+#: bytes of K and V one fold of the decode walk brings in; two folds
+#: are resident (the double buffer)
+FOLD_BYTES = 2 << 20
+
+
+def _fold_pages(hg: int, page: int, width: int, itemsize: int,
+                max_pages: int) -> int:
+    """Pages a fold of the decode walk carries: about ``FOLD_BYTES`` of
+    K and V over all ``hg`` head groups, in whole 128-row tiles of kv
+    rows — at most eight, so a fold's float32 scores stay a few vector
+    registers a head group — and at most the table."""
+    tile = max(1, LANES // page)
+    if (tile * page) % LANES:       # a page that does not tile the lanes
+        return min(tile, max_pages)
+    want = FOLD_BYTES // (2 * hg * page * width * itemsize)
+    return max(1, min(max(tile, want // tile * tile), 8 * tile, max_pages))
+
+
+def _has_rows(length, first_page, capacity: int, n_pages: int):
+    """Whether a decode slot holds rows to attend, from its length, its
+    table's first entry and the rows its table can hold (scalars in
+    the kernel, arrays in the XLA reference): a positive length that
+    the table can hold, and a first page that is allocated. The engine
+    never hands decode a zero length — an empty slot's length counts
+    up from 1 inside a pass and a slot mid-prefill carries ``max_seq``
+    — but an empty slot's table is all unallocated, and no table holds
+    more than ``max_pages * page`` rows."""
+    return (length > 0) & (length <= capacity) & (first_page < n_pages)
+
+
+def _decode_kernel(tables_ref, lengths_ref, layer_ref, q_ref, k_hbm, v_hbm,
+                   *refs, page: int, fold_pages: int, n_pages: int,
+                   scale: float, pack: int, quantized: bool):
+    ks_hbm = vs_hbm = ks_buf = vs_buf = None
+    if quantized:
+        (ks_hbm, vs_hbm, o_ref, k_buf, v_buf, ks_buf, vs_buf, acc_ref,
+         m_ref, l_ref, sems, next_ref, half_ref) = refs
+    else:
+        (o_ref, k_buf, v_buf, acc_ref, m_ref, l_ref, sems, next_ref,
+         half_ref) = refs
+    li = layer_ref[0]
+    b = pl.program_id(0)
+    n_slots, max_pages = tables_ref.shape
+    chunk = fold_pages * page
+    rows = q_ref.shape[2]
+
+    def live(slot):
+        return _has_rows(lengths_ref[slot], tables_ref[slot, 0],
+                         max_pages * page, n_pages)
+
+    def fold_dmas(slot, ci, half, wait=False):
+        """Start (or wait on) the pages of fold ``ci`` of ``slot`` into
+        buffer half ``half``: K and V of EVERY head group of a page in
+        one strided descriptor each (``[Hg, page, W]``, a head group's
+        page contiguous), plus the scale rows of a quantized pool. A
+        page past the slot's rows is not fetched: its buffer rows keep
+        what an earlier fold left (finite — the V side starts as
+        zeros) and are masked."""
+        for j in range(fold_pages):
+            idx = ci * fold_pages + j
+            pid = jnp.minimum(
+                tables_ref[slot, jnp.minimum(idx, max_pages - 1)],
+                n_pages - 1)
+            dst = pl.ds(j * page, page)
+            copies = [(k_hbm, k_buf.at[half, :, dst, :]),
+                      (v_hbm, v_buf.at[half, :, dst, :])]
+            if quantized:
+                copies += [(ks_hbm, ks_buf.at[half, j]),
+                           (vs_hbm, vs_buf.at[half, j])]
+
+            @pl.when(idx * page < lengths_ref[slot])
+            def _():
+                for side, (src, dst_ref) in enumerate(copies):
+                    dma = pltpu.make_async_copy(
+                        src.at[li, :, pid], dst_ref, sems.at[half, side, j])
+                    if wait:
+                        dma.wait()
+                    else:
+                        dma.start()
+
+    @pl.when(b == 0)
+    def _():
+        # thread the live slots (each one's successor, in SMEM) and put
+        # the first one's first fold in flight: from here on a cell's
+        # first fold was always started by the cell before it
+        def link(i, nxt):
+            slot = n_slots - 1 - i
+            next_ref[slot] = nxt
+            return jnp.where(live(slot), slot, nxt)
+
+        first = jax.lax.fori_loop(0, n_slots, link, n_slots)
+        half_ref[0] = 0
+        v_buf[...] = jnp.zeros_like(v_buf)
+        if quantized:
+            vs_buf[...] = jnp.zeros_like(vs_buf)
+
+        @pl.when(first < n_slots)
+        def _():
+            fold_dmas(first, 0, 0)
+
+    is_live = live(b)
+
+    @pl.when(jnp.logical_not(is_live))
+    def _():
+        o_ref[...] = jnp.zeros_like(o_ref)
+
+    @pl.when(is_live)
+    def _():
+        length = lengths_ref[b]
+        n_folds = pl.cdiv(length, chunk)
+        first_half = half_ref[0]
+        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
+        l_ref[...] = jnp.zeros_like(l_ref)
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+        q = q_ref[0]                                    # [Hg, rows, W]
+        # within a head group the rows run [pack, rows // pack]: packed
+        # kv head, then its (padded) GQA query heads
+        row_head = jax.lax.broadcasted_iota(
+            jnp.int32, (1, rows, 1), 1) // (rows // pack)
+
+        def body(ci, _):
+            half = jax.lax.rem(first_half + ci, 2)
+            # what is folded next goes in flight first: this slot's
+            # next fold, or the first fold of the next live slot
+            last = ci + 1 == n_folds
+            next_slot = jnp.where(last, next_ref[b], b)
+
+            @pl.when(next_slot < n_slots)
+            def _():
+                fold_dmas(next_slot, jnp.where(last, 0, ci + 1), 1 - half)
+
+            fold_dmas(b, ci, half, wait=True)
+            s = _mxu_dot(q, k_buf[half], 2) * scale     # [Hg, rows, chunk]
+            if quantized:
+                s = s * _row_scales(
+                    [ks_buf[half, j] for j in range(fold_pages)],
+                    row_head, page=page, pack=pack)
+            pos = ci * chunk + jax.lax.broadcasted_iota(
+                jnp.int32, (1, 1, chunk), 2)
+
+            def pv(p):
+                if quantized:
+                    p = p * _row_scales(
+                        [vs_buf[half, j] for j in range(fold_pages)],
+                        row_head, page=page, pack=pack)
+                return _mxu_dot(p, v_buf[half], 1)      # [Hg, rows, W]
+
+            # the one query row is the slot's last: every row is behind it
+            _softmax_fold(s, pos < length, pv, acc_ref, m_ref, l_ref)
+            return 0
+
+        jax.lax.fori_loop(0, n_folds, body, 0)
+        half_ref[0] = jax.lax.rem(first_half + n_folds, 2)
+        o_ref[0] = (acc_ref[...] / l_ref[...]).astype(o_ref.dtype)
+
+
+def _decode_walk(q, k_pool, v_pool, tables, lengths, *, layer, scale,
+                 interpret):
+    """The pallas_call behind decode. q [B, Hq, hd]; pools and ``layer``
+    as :func:`_ragged_attention` takes them."""
+    if layer is None:
+        k_pool, v_pool = jax.tree.map(lambda x: x[None], (k_pool, v_pool))
+        layer = 0
+    k_codes, k_scales = _split_pool(k_pool)
+    v_codes, v_scales = _split_pool(v_pool)
+    quantized = k_scales is not None
+    b, hq, hd = q.shape
+    _, hg, n_pages, page, width = k_codes.shape
+    _, max_pages = tables.shape
+    pack = width // hd
+    scale = scale if scale is not None else hd ** -0.5
+    if not interpret:
+        check_kernel_layout(k_pool)
+    fold_pages = _fold_pages(hg, page, width, k_codes.dtype.itemsize,
+                             max_pages)
+    chunk = fold_pages * page
+    q4, gp = _group_rows(q[:, None], hg, pack, 1)       # [B, Hg, rows, W]
+    rows = pack * gp
+    kernel = functools.partial(
+        _decode_kernel, page=page, fold_pages=fold_pages, n_pages=n_pages,
+        scale=scale, pack=pack, quantized=quantized)
+    q_spec = pl.BlockSpec((1, hg, rows, width), lambda i, *_: (i, 0, 0, 0),
+                          memory_space=pltpu.VMEM)
+    in_hbm = pl.BlockSpec(memory_space=pl.ANY)  # pools stay in HBM
+    scale_bufs = [pltpu.VMEM((2, fold_pages, hg, 1, k_scales.shape[-1]),
+                             jnp.float32)] * 2 if quantized else []
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=3,
+        grid=(b,),
+        in_specs=[q_spec] + [in_hbm] * (4 if quantized else 2),
+        out_specs=q_spec,
+        scratch_shapes=[
+            pltpu.VMEM((2, hg, chunk, width), k_codes.dtype),
+            pltpu.VMEM((2, hg, chunk, width), v_codes.dtype),
+            *scale_bufs,
+            pltpu.VMEM((hg, rows, width), jnp.float32),
+            pltpu.VMEM((hg, rows, 1), jnp.float32),
+            pltpu.VMEM((hg, rows, 1), jnp.float32),
+            pltpu.SemaphoreType.DMA((2, 4 if quantized else 2,
+                                     fold_pages)),
+            pltpu.SMEM((b,), jnp.int32),    # each live slot's successor
+            pltpu.SMEM((1,), jnp.int32),    # buffer half of the next fold
+        ],
+    )
+    args = [tables.astype(jnp.int32), lengths.astype(jnp.int32),
+            jnp.asarray(layer, jnp.int32).reshape(1), q4, k_codes, v_codes]
+    if quantized:
+        args += [k_scales, v_scales]
+    out = pl.pallas_call(
+        kernel,
+        out_shape=jax.ShapeDtypeStruct((b, hg, rows, width), q.dtype),
+        grid_spec=grid_spec,
+        # a cell starts the next live cell's first fold: in order
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
+        interpret=interpret,
+    )(*args)
+    return _ungroup_rows(out, 1, hq, hd, pack, gp)[:, 0]
 
 
 def paged_chunk_attention_pallas(q: jnp.ndarray, k_pool,
@@ -465,10 +807,8 @@ def paged_decode_attention_pallas(q: jnp.ndarray, k_pool,
     ``lengths`` [B] the valid rows AFTER this step's write — the chunk
     of one row ending at ``lengths``. Zero-length slots return exact
     zeros."""
-    return _ragged_attention(
-        q[:, None], k_pool, v_pool, tables, jnp.maximum(lengths - 1, 0),
-        jnp.minimum(lengths, 1), None, layer=layer, scale=scale,
-        block_q=1, interpret=interpret)[:, 0]
+    return _decode_walk(q, k_pool, v_pool, tables, lengths, layer=layer,
+                        scale=scale, interpret=interpret)
 
 
 def paged_tree_attention_pallas(q: jnp.ndarray, k_pool,
@@ -520,12 +860,14 @@ def paged_decode_attention_xla(q: jnp.ndarray, k_pool,
                            _slot_view(k_pool, layer, tables, hd),
                            _slot_view(v_pool, layer, tables, hd),
                            lengths, scale=scale)[:, 0]
-    # zero-length slots: every position is masked, so the dense softmax
-    # degrades to a uniform average over garbage rows — the kernel's
-    # denom clamp returns exact zeros there. Match it, so the reference
-    # and the kernel agree on EVERY row, not just live ones.
-    return jnp.where(lengths[:, None, None] > 0, out,
-                     jnp.zeros_like(out))
+    # slots without rows: every position is masked (or garbage), so the
+    # dense softmax degrades to an average over garbage rows — the
+    # kernel does not walk them and stores exact zeros. Match it, so
+    # the reference and the kernel agree on EVERY row, not just live
+    # ones.
+    n_pages, page = _split_pool(k_pool)[0].shape[-3:-1]
+    live = _has_rows(lengths, tables[:, 0], tables.shape[1] * page, n_pages)
+    return jnp.where(live[:, None, None], out, jnp.zeros_like(out))
 
 
 def paged_chunk_attention_xla(q: jnp.ndarray, k_pool,

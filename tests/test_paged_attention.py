@@ -320,6 +320,113 @@ def test_packed_pool_kernel_matches_unpacked_xla(hd, hkv, group, page,
                                    chunk_lens, masks), n_valid)
 
 
+# ------------------------------------------------------ the decode walk
+#
+# Decode has its own walk (ops/paged_attention.py ``_decode_kernel``): a
+# cell a slot, every head group of a page in one fold, folds of
+# ``_fold_pages`` pages, the next live slot's first fold in flight, no
+# walk for a slot without rows. These run it interpreted against the
+# XLA reference on the SAME pool at the two geometries the benchmark
+# serves (their real head counts and page: the fold is sized from
+# them), bf16 and int8: live and empty slots interleaved, nobody live,
+# contexts around the fold's edges, a table used to its last page, and
+# the two ways the ENGINE presents a slot without rows (an
+# all-unallocated table under a small length; a length no table
+# holds). Every traffic is six slots over one pool shape, so a
+# (geometry, pool, query dtype) is traced and compiled once.
+
+WALK_PAGE, WALK_SLOTS = 64, 6
+WALK_GEOMETRIES = {
+    "hd64-packed-mha": dict(hq=32, hkv=32, hd=64),      # SmolLM2-1.7B
+    "hd128-gqa-4to1": dict(hq=32, hkv=8, hd=128),       # Mistral-7B
+}
+WALK_LENGTHS = {
+    "interleaved": lambda fold, cap: (0, 700, 0, 0, 65, 0),
+    "all-empty": lambda fold, cap: (0,) * WALK_SLOTS,
+    "fold-edges": lambda fold, cap: (fold - 1, fold, fold + 1,
+                                     3 * fold + 1, 0, 0),
+    "last-page": lambda fold, cap: (cap, 0, cap - WALK_PAGE + 1, 0, 0, 0),
+    # (length, holds pages): as the engine hands an empty slot and a
+    # slot mid-prefill to decode
+    "engine-empty": lambda fold, cap: ((5, False), 130, (cap + 1, True),
+                                       (1, False), 0, (8, False)),
+}
+_walk_kernel = jax.jit(lambda *a: paged_decode_attention_pallas(
+    *a, interpret=True))
+_walk_reference = jax.jit(paged_decode_attention_xla)
+
+
+@pytest.mark.parametrize("quantized", [False, True], ids=["bf16", "int8"])
+@pytest.mark.parametrize("geometry", sorted(WALK_GEOMETRIES))
+@pytest.mark.parametrize("traffic", sorted(WALK_LENGTHS))
+def test_decode_walk_matches_xla(traffic, geometry, quantized):
+    from gofr_tpu.ops.paged_attention import _fold_pages
+    from gofr_tpu.ops.paged_kv import head_pack
+    g = WALK_GEOMETRIES[geometry]
+    hq, hkv, hd = g["hq"], g["hkv"], g["hd"]
+    pack = head_pack(hkv, hd)
+    fold_pages = _fold_pages(hkv // pack, WALK_PAGE, pack * hd,
+                             1 if quantized else 2, 10 ** 6)
+    max_pages = 3 * fold_pages + 2          # holds three folds + 1
+    fold, cap = fold_pages * WALK_PAGE, max_pages * WALK_PAGE
+    n_pages = 2 * max_pages + 4
+    spec = [x if isinstance(x, tuple) else (x, x > 0)
+            for x in WALK_LENGTHS[traffic](fold, cap)]
+    assert len(spec) == WALK_SLOTS
+    held = [min(-(-n // WALK_PAGE), max_pages) if pages else 0
+            for n, pages in spec]
+    rng = np.random.default_rng(len(traffic) + hd + quantized)
+    order = rng.permutation(n_pages)
+    tables = np.full((WALK_SLOTS, max_pages), n_pages, np.int32)
+    at = 0
+    for i, need in enumerate(held):
+        tables[i, :need] = order[at:at + need]
+        at += need
+    ks = jax.random.split(jax.random.key(hd + len(traffic)), 3)
+    kp, vp = (pack_pool(jax.random.normal(
+        k, (hkv, n_pages, WALK_PAGE, hd), jnp.float32)
+        .astype(jnp.bfloat16)) for k in ks[:2])
+    if quantized:
+        kp, vp = (quantize_pool(x, head_dim=hd) for x in (kp, vp))
+    # the reference attends a bf16 view in bf16: hand it the same
+    # values as float32 (an int8 pool it dequantizes to float32 itself)
+    ref_pools = (kp, vp) if quantized else \
+        (kp.astype(jnp.float32), vp.astype(jnp.float32))
+    tables = jnp.asarray(tables)
+    lens = jnp.asarray([n for n, _ in spec], jnp.int32)
+    live = np.asarray([pages and 0 < n <= cap for n, pages in spec])
+    # float32 queries: nothing the float32 walk kept is rounded, so the
+    # kernel and the reference differ by summation order alone; bf16
+    # queries (the engine's) take the one-pass products and round the
+    # output to bf16
+    for dtype, tol in ((jnp.float32, 2e-5), (jnp.bfloat16, 2e-2)):
+        q = jax.random.normal(ks[2], (WALK_SLOTS, hq, hd),
+                              jnp.float32).astype(dtype)
+        got = np.asarray(_walk_kernel(q, kp, vp, tables, lens), np.float32)
+        want = np.asarray(_walk_reference(q, *ref_pools, tables, lens),
+                          np.float32)
+        assert not np.isnan(got).any()
+        np.testing.assert_allclose(got[live], want[live], rtol=tol,
+                                   atol=tol)
+        # a slot without rows: exact zeros on both paths
+        np.testing.assert_array_equal(got[~live], 0.0)
+        np.testing.assert_array_equal(want[~live], 0.0)
+
+
+def test_decode_fold_is_sized_from_what_the_kernel_sees():
+    """About 2 MiB of K and V a fold whatever the geometry, in whole
+    128-row tiles, never more than the table."""
+    from gofr_tpu.ops.paged_attention import FOLD_BYTES, _fold_pages
+    for hg, itemsize in ((16, 2), (8, 2), (16, 1), (8, 1), (1, 2)):
+        pages = _fold_pages(hg, 64, 128, itemsize, 128)
+        assert pages % 2 == 0 and pages <= 16
+        assert 2 * hg * pages * 64 * 128 * itemsize <= FOLD_BYTES
+    assert _fold_pages(16, 64, 128, 2, 128) == 4      # SmolLM2, bf16
+    assert _fold_pages(8, 64, 128, 2, 128) == 8       # Mistral, bf16
+    assert _fold_pages(16, 64, 128, 2, 3) == 3        # a three-page table
+    assert _fold_pages(2, 24, 128, 2, 64) == 5        # a page off the lanes
+
+
 # ---------------------------------------------------- quantized pools
 #
 # int8 KV pages (ops/paged_kv.py: {"q": int8, "s": f32 per-row}). The

@@ -144,6 +144,44 @@ def test_paged_kernel_compiles_for_v5e(kind, hd, quantized, chip):
                             _shape((B, 8), jnp.int32, chip))
 
 
+#: the benchmark's engines (benchmarks/configs/): 32 slots of 128 pages
+#: of 64 rows; SmolLM2-1.7B 32 kv heads of 64 (16 head groups a page),
+#: Mistral-7B 8 of 128
+ENGINE_SLOTS, ENGINE_SLOT_PAGES = 32, 128
+ENGINE_HEADS = {64: (32, 32), 128: (32, 8)}
+
+
+@pytest.mark.parametrize("quantized", [False, True],
+                         ids=["bf16", "int8"])
+@pytest.mark.parametrize("hd", sorted(ENGINE_HEADS))
+def test_decode_walk_compiles_at_the_engines_geometry(hd, quantized, chip):
+    """The decode walk's fold is sized from (head groups, page, row
+    width, dtype): at the engine's real geometry its double buffer
+    must fit the scoped VMEM a kernel gets without asking (16 MiB on
+    v5e — the compile raises past it), with room for the fold's
+    float32 scores beside it."""
+    from gofr_tpu.ops.paged_attention import FOLD_BYTES, _fold_pages
+    hq, hkv = ENGINE_HEADS[hd]
+    pack = head_pack(hkv, hd)
+    shape = (4, hkv // pack, N_PAGES, PAGE, pack * hd)
+    pool = _shape(shape, jnp.bfloat16, chip)
+    if quantized:
+        pool = {"q": _shape(shape, jnp.int8, chip),
+                "s": _shape((*shape[:3], 1, scale_width(pack, PAGE)),
+                            jnp.float32, chip)}
+    pages = _fold_pages(shape[1], PAGE, shape[-1], 1 if quantized else 2,
+                        ENGINE_SLOT_PAGES)
+    fold = 2 * shape[1] * pages * PAGE * shape[-1] * (1 if quantized else 2)
+    assert FOLD_BYTES // 2 < fold <= FOLD_BYTES and 2 * fold <= 8 << 20
+    _compiles_to_kernel(
+        lambda q, k, v, t, n, li: paged_decode_attention_pallas(
+            q, k, v, t, n, layer=li),
+        _shape((ENGINE_SLOTS, hq, hd), jnp.bfloat16, chip), pool, pool,
+        _shape((ENGINE_SLOTS, ENGINE_SLOT_PAGES), jnp.int32, chip),
+        _shape((ENGINE_SLOTS,), jnp.int32, chip),
+        _shape((), jnp.int32, chip))
+
+
 # ------------------------------ the names the benchmark's trace reader uses
 @pytest.fixture(scope="module")
 def trace_reader():
