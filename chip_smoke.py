@@ -22,10 +22,12 @@ on from:
                the app's own HTTP server on a free port, requests over
                a socket: /chat twice (same greedy ids), /v1/completions,
                one streamed; health UP with a tpu device; app_engine_*
-               gauges; zero recompiles after warm-up; the prefill
-               program holds the Pallas kernel.
-3. paged     — the same weights, ``kv_layout="paged"`` with
-               ``paged_attention="auto"``, bf16 pool then int8 pool: a
+               gauges; zero recompiles after warm-up. The app's engine
+               is the default ``EngineConfig`` one: the page pool on the
+               paged kernel, so the prefill AND the decode program must
+               hold a Pallas kernel.
+3. paged     — the same weights with ``paged_attention="auto"``, bf16
+               pool then int8 pool: a
                prompt long enough for chunked prefill, plain decode, a
                speculative run. The reference is the same engine built
                with ``paged_attention="xla"`` on the same chip. Judged
@@ -43,8 +45,9 @@ on from:
 4. --chips 4 — the 1B shape under ``create_mesh({"tp": 4})`` through
                the engine vs the single-device engine on the same
                prompts, same judgement; every leaf ``llama_param_specs``
-               shards and the KV cache must sit on four devices, and
-               ``bytes_in_use`` must be of one order on all four.
+               shards and the page pool (served through the dense view:
+               the kernels are single-device) must sit on four devices,
+               and ``bytes_in_use`` must be of one order on all four.
 
 Judging rule (phases 3 and 4): ``max |logits - reference| <=
 LOGIT_ATOL``. Seeded random weights give near-flat logits, so where the
@@ -192,10 +195,22 @@ def phase_default(args):
     kernel = has_kernel(engine._prefill_fn, engine.params,
                         jnp.zeros((1, bucket), jnp.int32),
                         jnp.ones((1,), jnp.int32))
-    say("default.prefill_program", bucket=bucket, pallas_kernel=kernel)
+    b = engine.config.max_batch
+    decode_kernel = has_kernel(
+        engine._paged_decode_fn, engine.params, jnp.zeros(b, jnp.int32),
+        engine.k_cache, engine.v_cache,
+        jnp.zeros((b, engine._pages_per_slot), jnp.int32),
+        jnp.ones(b, jnp.int32))
+    say("default.programs", bucket=bucket, prefill_pallas_kernel=kernel,
+        paged_attention=engine.paged_attention_impl,
+        pool_pages=engine._n_pages, decode_pallas_kernel=decode_kernel)
     if not args.rehearse:
         check(kernel, "the prefill program holds no Pallas kernel: "
               "attention(implementation='auto') took the XLA path")
+        check(engine.paged_attention_impl == "kernel" and decode_kernel,
+              f"the default engine's decode program holds no Pallas "
+              f"kernel (paged_attention resolved to "
+              f"{engine.paged_attention_impl!r})")
 
     vocab = engine.params["embed"].shape[0]
     body = {"prompt": PROMPT, "max_tokens": 16, "temperature": 0.0}
@@ -401,9 +416,9 @@ def phase_paged(args, params) -> None:
             eng = llama_engine(params, c, EngineConfig(
                 max_batch=4, max_seq=512, prefill_buckets=(64,),
                 prefill_batch=2, decode_steps_per_pass=4, seed=0,
-                kv_layout="paged", page_size=64, kv_dtype=kv_dtype,
-                paged_attention=impl, speculative=True, spec_draft=3,
-                spec_branches=2, spec_adaptive=False))
+                page_size=64, kv_dtype=kv_dtype, paged_attention=impl,
+                speculative=True, spec_draft=3, spec_branches=2,
+                spec_adaptive=False))
             resolved = eng.paged_attention_impl
             check(resolved == {"auto": "kernel"}.get(impl, impl),
                   f"paged_attention={impl!r} resolved to {resolved!r}")
@@ -454,7 +469,7 @@ def phase_latent(args) -> None:
         eng = deepseek_engine(params, c, EngineConfig(
             max_batch=4, max_seq=512, prefill_buckets=(64,),
             prefill_batch=2, decode_steps_per_pass=4, seed=0,
-            kv_layout="paged", page_size=64, paged_attention=impl))
+            page_size=64, paged_attention=impl))
         check(eng.paged_attention_impl == impl,
               f"paged_attention={impl!r} resolved to "
               f"{eng.paged_attention_impl!r}")
@@ -549,11 +564,13 @@ def phase_sharded(args) -> None:
                       and leaf.size == 4 * int(np.prod(shards.pop())),
                       f"{jax.tree_util.keystr(path)} is not split over "
                       f"four devices: {leaf.sharding}")
-            kv = eng.k_cache
-            check(len(kv.sharding.device_set) == 4
-                  and {s.data.shape[3] for s in kv.addressable_shards}
-                  == {c.n_kv_heads // 4},
-                  f"KV cache is not split over four devices: "
+            kv = eng.k_cache    # the pool [L, Hg, Np, pg, W]: head
+            #                     groups over tp, a device's own share
+            check(eng.paged_attention_impl == "view"
+                  and len(kv.sharding.device_set) == 4
+                  and {s.data.shape[1] for s in kv.addressable_shards}
+                  == {kv.shape[1] // 4},
+                  f"the page pool is not split over four devices: "
                   f"{kv.sharding}")
             in_use = [d.memory_stats()["bytes_in_use"] for d in devices] \
                 if not args.rehearse else None
@@ -576,7 +593,8 @@ def phase_sharded(args) -> None:
             [list(r.generated) for r in reqs], evidence
 
     got, got_ids, evidence = run(create_mesh({"tp": 4}, devices))
-    say("sharded.tp4", attention="xla (glue: kernels are single-device)",
+    say("sharded.tp4", attention="xla over the pool's dense view (glue: "
+        "kernels are single-device)",
         greedy_ids=got_ids, **evidence)
     ref, ref_ids, _ = run(None)
     say("sharded.single", greedy_ids=ref_ids)

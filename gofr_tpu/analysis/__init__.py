@@ -19,7 +19,7 @@ Rules (each in ``analysis/rules/``):
 Plus the built-in ``bad-suppression`` (an ``allow()`` without a reason,
 or one that suppresses nothing) and ``parse-error``.
 
-Usage: ``python scripts/lint.py gofr_tpu/ scripts/ bench.py`` or
+Usage: ``python scripts/lint.py gofr_tpu/ scripts/ chip_smoke.py`` or
 programmatically via :func:`run_analysis`.
 """
 

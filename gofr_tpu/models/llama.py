@@ -222,8 +222,7 @@ def llama_prefill_last(params: dict, tokens: jnp.ndarray, config: LlamaConfig,
 
 def llama_decode_step(params: dict, tokens: jnp.ndarray,
                       k_cache: jnp.ndarray, v_cache: jnp.ndarray,
-                      lengths: jnp.ndarray, config: LlamaConfig, *,
-                      attn_window: int | None = None
+                      lengths: jnp.ndarray, config: LlamaConfig
                       ) -> tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
     """One decode step for a batch of sequences.
 
@@ -232,12 +231,6 @@ def llama_decode_step(params: dict, tokens: jnp.ndarray,
     (the new token is written at that position). Returns
     (logits [B, V], new_k_cache, new_v_cache). The engine donates the
     caches so XLA updates them in place.
-
-    ``attn_window``: static row count attention reads per layer (the
-    engine picks a bucket covering every live length this pass, see
-    ``EngineConfig.decode_windows``). Decode attention's HBM traffic is
-    then O(window), not O(max_seq) — the cache is still allocated and
-    written at full size. Caller guarantees lengths + 1 <= window.
     """
     c = config
     b = tokens.shape[0]
@@ -267,9 +260,6 @@ def llama_decode_step(params: dict, tokens: jnp.ndarray,
         vc_all = vc_all.at[li, batch_idx, lengths].set(v[:, 0])
         kc = jax.lax.dynamic_index_in_dim(kc_all, li, 0, keepdims=False)
         vc = jax.lax.dynamic_index_in_dim(vc_all, li, 0, keepdims=False)
-        if attn_window is not None and attn_window < kc.shape[1]:
-            kc = kc[:, :attn_window]
-            vc = vc[:, :attn_window]
         out = decode_attention(q, kc, vc, lengths + 1)
         x = x + qmatmul(out.reshape(b, 1, c.n_heads * hd), lp["wo"])
         x = x + _mlp_block(x, lp, c)

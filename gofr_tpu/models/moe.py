@@ -141,8 +141,7 @@ def moe_prefill_last(params: dict, tokens: jnp.ndarray, config: MoEConfig, *,
 
 def moe_decode_step(params: dict, tokens: jnp.ndarray,
                     k_cache: jnp.ndarray, v_cache: jnp.ndarray,
-                    lengths: jnp.ndarray, config: MoEConfig, *,
-                    attn_window: int | None = None):
+                    lengths: jnp.ndarray, config: MoEConfig):
     c = config
     b = tokens.shape[0]
     hd = c.head_dim
@@ -166,9 +165,6 @@ def moe_decode_step(params: dict, tokens: jnp.ndarray,
         vc_all = vc_all.at[li, batch_idx, lengths].set(v[:, 0])
         kc = jax.lax.dynamic_index_in_dim(kc_all, li, 0, keepdims=False)
         vc = jax.lax.dynamic_index_in_dim(vc_all, li, 0, keepdims=False)
-        if attn_window is not None and attn_window < kc.shape[1]:
-            kc = kc[:, :attn_window]
-            vc = vc[:, :attn_window]
         out = decode_attention(q, kc, vc, lengths + 1)
         x = x + (out.reshape(b, 1, c.n_heads * hd) @ lp["wo"])
         mlp_out, _ = _moe_mlp(x, lp, c)

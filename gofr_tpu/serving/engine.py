@@ -22,11 +22,17 @@ Architecture (one device or one mesh):
   (prefill-priority keeps TTFT low; decode continues for everyone else
   next step).
 
-Two KV layouts share the loop (``EngineConfig.kv_layout``): "slot"
-keeps contiguous per-slot rows; "paged" adds block-table indirection
-over a page pool (``ops/paged_kv.py``) with allocation on admission,
-frees on retire, and vLLM-style preemption-by-recompute when the pool
-runs dry — KV capacity decoupled from ``max_batch x max_seq``.
+The KV cache is ONE page pool behind per-slot block tables
+(``ops/paged_kv.py``): pages are allocated on admission and freed on
+retire, page-aligned prompt prefixes are shared by refcount, and the
+newest request is preempted by recompute when the pool runs dry — KV
+capacity is decoupled from ``max_batch x max_seq`` (``kv_pages=None``
+reserves exactly that much). Two attention paths read it
+(``EngineConfig.paged_attention``): the native path, where the model's
+paged steps write rows through the tables and the ragged kernels read
+pages in place, and the view path, where a dense per-slot view is
+gathered for the family's dense steps — the path of a mesh-sharded
+engine and of a family without paged steps.
 
 Scheduler state is **device-resident**: per-slot lengths, sampling
 params, page tables and the active mask live as persistent device
@@ -212,16 +218,14 @@ class EngineConfig:
     #: finishing request's budget) for throughput. 1 = the classic
     #: single-pass dispatch.
     decode_passes_per_dispatch: int = 1
-    #: windowed decode attention: extra decode-graph variants that
-    #: touch only the first ``window`` cache rows — attention reads
-    #: for the slot layout, gather/scatter width for the paged VIEW
-    #: path (the mesh-sharded paged path; the single-device ragged
-    #: kernel is already length-bounded and ignores this). Each pass
-    #: picks the smallest listed window covering every live length +
-    #: K; none covering -> the full-max_seq graph. HBM traffic becomes
-    #: O(longest live row), not O(max_seq) — decisive when max_seq >>
-    #: typical lengths. Each window is one extra compile (warmed in
-    #: warmup()). () = off.
+    #: widths of the VIEW path's gather: extra decode-graph variants
+    #: that gather and scatter back only the table columns covering
+    #: the first ``window`` rows of each slot (the native path walks
+    #: live pages only and ignores this). Each pass picks the smallest
+    #: listed window covering every live length + K; none covering ->
+    #: the full-max_seq graph. The view's HBM traffic becomes
+    #: O(longest live row), not O(max_seq). Each window is one extra
+    #: compile (warmed in warmup()). () = off.
     decode_windows: tuple = ()
     #: waiting requests prefilled per device call. The prefill graph is
     #: a fixed [P, bucket] shape (short groups ride with masked dummy
@@ -257,19 +261,18 @@ class EngineConfig:
     #: instead of waiting for heartbeat silence. Pure host-side
     #: polling off the hot loop. 0 disables the watchdog.
     watchdog_interval_s: float = 5.0
-    #: "slot" = contiguous per-slot rows (max_batch x max_seq, simplest
-    #: and fastest per step); "paged" = block-table indirection over a
-    #: page pool (ops/paged_kv.py) — capacity decoupled from
-    #: max_batch x max_seq, pages allocated on admission and freed on
-    #: retire, preemption-by-recompute when the pool runs dry.
-    kv_layout: str = "slot"
-    #: rows per KV page (paged layout only)
+    #: the one KV layout; any other value is refused (the contiguous
+    #: "slot" layout was removed in PR 30). The field is still accepted
+    #: only because the benchmark's configuration files name it as an
+    #: ``EngineConfig`` key: it goes when they drop the key (ROADMAP C2).
+    kv_layout: str = "paged"
+    #: rows per KV page
     page_size: int = 64
     #: pool size in pages; None sizes the pool to the full contiguous
     #: capacity (max_batch x ceil(max_seq/page_size)). Smaller values
     #: overcommit: more concurrent short requests in the same HBM.
     kv_pages: int | None = None
-    #: KV page storage dtype (paged layout only). "bf16" (default)
+    #: KV page storage dtype. "bf16" (default)
     #: stores pages in the model dtype — bit-identical to the classic
     #: pool. "int8" stores narrow codes plus one f32 scale per row
     #: (ops/paged_kv.py quantized pool): pages quantize on write
@@ -278,15 +281,15 @@ class EngineConfig:
     #: from 2·hd to hd+4 bytes — at the same byte budget the pool
     #: holds ~2x the pages (1.88x at hd=64, 1.94x at hd=128).
     kv_dtype: str = "bf16"
-    #: explicit KV pool HBM budget in bytes (paged layout only; K and
-    #: V together). None derives the budget from ``kv_pages`` (or the
-    #: full contiguous capacity) at the NATIVE page cost, so switching
+    #: explicit KV pool HBM budget in bytes (K and V together). None
+    #: derives the budget from ``kv_pages`` (or the full contiguous
+    #: capacity) at the NATIVE page cost, so switching
     #: ``kv_dtype`` to int8 under the same budget grows the page count
     #: instead of shrinking the footprint — capacity is the point.
     kv_pool_bytes: int | None = None
-    #: paged layout only: retain retired requests' page-aligned prompt
-    #: prefixes and share them with later requests bearing the same
-    #: prefix (the common system prompt) — the suffix prefills through
+    #: retain retired requests' page-aligned prompt prefixes and share
+    #: them with later requests bearing the same prefix (the common
+    #: system prompt) — the suffix prefills through
     #: the chunk-with-history path, skipping the shared compute
     #: entirely. Shared pages are read-only by construction (decode
     #: and suffix writes land past the aligned prefix) and refcounted;
@@ -329,13 +332,14 @@ class EngineConfig:
     spec_accept_floor: float = 0.1
     #: passes between single-node probes of a disabled slot
     spec_probe_interval: int = 32
-    #: paged layout decode path: "auto" = the ragged paged-attention
-    #: kernel on TPU (pages read in place, no per-pass view
-    #: materialisation) and the gather/scatter view path elsewhere;
-    #: "kernel" / "interpret" / "xla" force the native path with that
-    #: paged-attention implementation; "view" forces gather/scatter.
-    #: Takes effect only when the model family supplies a
-    #: ``paged_decode_fn`` (llama does).
+    #: attention path over the pool: "auto" = the ragged
+    #: paged-attention kernel on TPU (pages read in place, no per-pass
+    #: view materialisation) and the gather/scatter view path
+    #: elsewhere; "kernel" / "interpret" / "xla" force the native path
+    #: with that paged-attention implementation; "view" forces
+    #: gather/scatter. A family that supplies no ``paged_decode_fn``
+    #: (the Mixtral-style MoE, any engine under a mesh) serves through
+    #: the view whatever this says.
     paged_attention: str = "auto"
     #: decode-pipeline depth: dispatched passes left uncollected after
     #: each iteration. 1 overlaps the host round-trip (token download,
@@ -486,18 +490,26 @@ class EngineConfig:
 
 
 class Engine:
-    """Continuous batching over a (prefill_fn, decode_fn) model pair.
+    """Continuous batching over a model family's step functions.
 
     prefill_fn(params, tokens[P, S], kv_lengths[P]) -> (logits,
         (k [L,P,S,Hkv,hd], v)) where logits is [P, V] (last-position,
         e.g. ``llama_prefill_last``) or [P, S, V] (full; the engine
         gathers each row's last prompt position).
-    decode_fn(params, tokens[B], k_cache, v_cache, lengths[B]) ->
-        (logits[B, V], k_cache, v_cache) — e.g. ``llama_decode_step``.
+    make_cache(batch, max_seq) -> (k, v) dense caches: how the family
+        states its row; the engine builds the page pool from one page
+        of it (``_pool_probe``).
+    The view path's steps run on a dense per-slot view gathered from
+    the pool: decode_fn(params, tokens[B], k_view, v_view, lengths[B])
+        -> (logits[B, V], k_view, v_view) — e.g. ``llama_decode_step``;
+    ``prefill_chunk_fn`` / ``spec_verify_fn`` likewise. The native
+    path's steps take the pools and the block tables
+    (``paged_decode_fn``, ``paged_chunk_fn``, ``paged_verify_fn`` — e.g.
+    ``llama_decode_step_paged``). A family passes the steps it has.
     """
 
     def __init__(self, params: Any, config: EngineConfig, *,
-                 prefill_fn: Callable, decode_fn: Callable,
+                 prefill_fn: Callable, decode_fn: Callable | None = None,
                  make_cache: Callable, prefill_chunk_fn: Callable
                  | None = None, spec_verify_fn: Callable | None = None,
                  paged_decode_fn: Callable | None = None,
@@ -609,10 +621,9 @@ class Engine:
         self._gauge_tokens = 0
         self._make_cache = make_cache
         # chunked prefill: long prompts in bucket-width chunks against
-        # the growing cache (slot layout slices the cache; the paged
-        # layout writes pages in place via paged_chunk_fn when the
-        # ragged kernel path is active, else gathers the slot's view
-        # and scatters the chunk back)
+        # the growing cache (pages written in place via paged_chunk_fn
+        # on the native path, else the slot's view is gathered and the
+        # chunk scattered back)
         self._prefill_chunk_fn = prefill_chunk_fn
         self._spec_verify_fn = spec_verify_fn
         self._paged_chunk_fn = paged_chunk_fn
@@ -635,9 +646,12 @@ class Engine:
         self._spec_ctrl_owner: list = [None] * config.max_batch
 
         cfg = config
-        if cfg.kv_layout not in ("slot", "paged"):
-            raise ValueError(f"kv_layout must be 'slot' or 'paged', "
-                             f"got {cfg.kv_layout!r}")
+        if cfg.kv_layout != "paged":
+            raise ValueError(
+                f"kv_layout={cfg.kv_layout!r}: the page pool is the one "
+                f"KV layout (the contiguous 'slot' layout was removed in "
+                f"PR 30); leave kv_layout unset — kv_pages=None reserves "
+                f"the same max_batch x max_seq capacity")
         if cfg.paged_attention not in ("auto", "kernel", "interpret",
                                        "xla", "view"):
             raise ValueError(
@@ -646,13 +660,6 @@ class Engine:
         if cfg.kv_dtype not in ("bf16", "int8"):
             raise ValueError(f"kv_dtype must be 'bf16' or 'int8', "
                              f"got {cfg.kv_dtype!r}")
-        if cfg.kv_dtype != "bf16" and cfg.kv_layout != "paged":
-            raise ValueError("kv_dtype='int8' requires kv_layout="
-                             "'paged' (the quantized pool is a page "
-                             "pool; the slot layout has no pages)")
-        if cfg.kv_pool_bytes is not None and cfg.kv_layout != "paged":
-            raise ValueError("kv_pool_bytes sizes the paged pool; "
-                             "set kv_layout='paged'")
         if cfg.spec_branches < 1:
             raise ValueError(f"spec_branches must be >= 1, got "
                              f"{cfg.spec_branches}")
@@ -756,146 +763,105 @@ class Engine:
         self._decode_by_window: dict = {}
         cfg_windows = tuple(sorted(
             w for w in (cfg.decode_windows or ()) if 0 < w < cfg.max_seq))
-        #: raw configured windows — chunk walks use these even when
-        #: the decode path itself is the ragged kernel (native paged),
-        #: whose _decode_windows stays empty
+        #: raw configured windows — the view path's chunk walks use
+        #: these; the native path's _decode_windows stays empty
         self._cfg_windows = cfg_windows
+        from ..ops.paged_kv import scatter_decode
+        from ..ops.attention import is_tpu
+        impl = cfg.paged_attention
+        if impl == "auto":
+            impl = "kernel" if is_tpu() else "view"
+        if paged_decode_fn is None:
+            impl = "view"
+        if impl == "view" and decode_fn is None:
+            raise ValueError(
+                "the view path needs the family's dense decode_fn and "
+                "the native path its paged_decode_fn; neither was given")
+        #: what ``paged_attention`` resolved to — "kernel" (compiled
+        #: Pallas), "interpret", "xla" (native writes, gather
+        #: reference attention) or "view" (gather/scatter round trip)
+        self.paged_attention_impl: str = impl
+        self._paged_decode_fn = paged_decode_fn
+        use_native = impl != "view"
         #: native paged hot paths: the model family writes rows/chunks
         #: through the block tables and attends with the ragged paged
         #: kernels — no per-pass dense view of the pool. Chunked
         #: prefill, prefix-suffix reattachment and speculative verify
         #: follow decode onto the native path whenever the kernel path
         #: is active and the family supplies the paged chunk step.
-        self._native_chunk = False
-        self._native_verify = False
-        #: what ``paged_attention`` resolved to — "kernel" (compiled
-        #: Pallas), "interpret", "xla" (native writes, gather
-        #: reference attention) or "view" (gather/scatter round trip);
-        #: None on the slot layout
-        self.paged_attention_impl: str | None = None
-        self._paged_decode_fn = paged_decode_fn
-        if cfg.kv_layout == "paged":
-            from ..ops.paged_kv import scatter_chunk, scatter_decode
-            self._scatter_chunk = scatter_chunk
-            from ..ops.attention import is_tpu
-            impl = cfg.paged_attention
-            if impl == "auto":
-                impl = "kernel" if is_tpu() else "view"
-            if paged_decode_fn is None:
-                impl = "view"
-            self.paged_attention_impl = impl
-            use_native = impl != "view"
-            self._native_chunk = use_native and paged_chunk_fn is not None
-            self._native_verify = use_native and \
-                paged_verify_fn is not None
+        self._native_chunk = use_native and paged_chunk_fn is not None
+        self._native_verify = use_native and paged_verify_fn is not None
+        #: whether the path that resolved can walk chunks with history
+        #: (long prompts, prefix-cache suffixes, preemption recomputes):
+        #: the native path through ``paged_chunk_fn``, else the view
+        #: through ``prefill_chunk_fn``
+        self._chunk_walks = (self._native_chunk
+                             or prefill_chunk_fn is not None)
 
-            if use_native:
+        if use_native:
+            def _decode_sample(params, tokens, use_prev, prev,
+                               k_pool, v_pool, tables, lengths,
+                               active, step, temps, top_ps, top_ks,
+                               rng_key):
+                # native paged path: the model's paged decode step
+                # writes each new row through the table and attends
+                # with the ragged kernel — the pool is only ever
+                # touched in place, no per-pass view (VERDICT r3 #2)
+                toks_in = jnp.where(use_prev, prev, tokens)
+
+                def step_fn(toks, kp, vp, lens):
+                    return paged_decode_fn(params, toks, kp, vp,
+                                           tables, lens)
+
+                (last, k_pool, v_pool, _), toks = _fused_decode(
+                    step_fn, rng_key, toks_in, k_pool, v_pool,
+                    lengths, step, temps, top_ps, top_ks)
+                return (toks, last, k_pool, v_pool,  # [T,B(+n)],[B]
+                        _advance_lengths(lengths, active), step + 1)
+            self._decode = jax.jit(_decode_sample,
+                                   donate_argnums=(4, 5))
+        else:
+            pg_rows = max(1, int(cfg.page_size))
+
+            def _make_decode(window=None):
+                # windowed variant: gather (and scatter back) only
+                # the first ceil(window/pg) table columns — the
+                # materialised view is O(window) rows per slot, not
+                # O(max_seq). This is the path mesh-sharded serving
+                # runs (the ragged kernel is single-device).
+                mp_w = (None if window is None
+                        else -(-window // pg_rows))
+
                 def _decode_sample(params, tokens, use_prev, prev,
                                    k_pool, v_pool, tables, lengths,
-                                   active, step, temps, top_ps, top_ks,
-                                   rng_key):
-                    # native paged path: the model's paged decode step
-                    # writes each new row through the table and attends
-                    # with the ragged kernel — the pool is only ever
-                    # touched in place, no per-pass view (VERDICT r3 #2)
+                                   active, step, temps, top_ps,
+                                   top_ks, rng_key):
+                    # ONE gather per T-step pass builds the
+                    # slot-contiguous view the dense decode step
+                    # runs on; only the T fresh rows scatter back —
+                    # the model family never sees pages
                     toks_in = jnp.where(use_prev, prev, tokens)
-
-                    def step_fn(toks, kp, vp, lens):
-                        return paged_decode_fn(params, toks, kp, vp,
-                                               tables, lens)
-
-                    (last, k_pool, v_pool, _), toks = _fused_decode(
-                        step_fn, rng_key, toks_in, k_pool, v_pool,
-                        lengths, step, temps, top_ps, top_ks)
-                    return (toks, last, k_pool, v_pool,  # [T,B(+n)],[B]
-                            _advance_lengths(lengths, active), step + 1)
-                self._decode = jax.jit(_decode_sample,
-                                       donate_argnums=(4, 5))
-            else:
-                pg_rows = max(1, int(cfg.page_size))
-
-                def _make_decode(window=None):
-                    # windowed variant: gather (and scatter back) only
-                    # the first ceil(window/pg) table columns — the
-                    # materialised view is O(window) rows per slot, not
-                    # O(max_seq). This is the path mesh-sharded paged
-                    # serving runs (the ragged kernel is single-device),
-                    # so the win lands on multi-chip TPU too.
-                    mp_w = (None if window is None
-                            else -(-window // pg_rows))
-
-                    def _decode_sample(params, tokens, use_prev, prev,
-                                       k_pool, v_pool, tables, lengths,
-                                       active, step, temps, top_ps,
-                                       top_ks, rng_key):
-                        # ONE gather per T-step pass builds the
-                        # slot-contiguous view the dense decode step
-                        # runs on; only the T fresh rows scatter back —
-                        # the model family never sees pages
-                        toks_in = jnp.where(use_prev, prev, tokens)
-                        tb = tables if mp_w is None else tables[:, :mp_w]
-                        k_view = self._gather_view(k_pool, tb)
-                        v_view = self._gather_view(v_pool, tb)
-
-                        def step_fn(toks, kc, vc, lens):
-                            return decode_fn(params, toks, kc, vc, lens)
-
-                        (_, k_view, v_view, _), toks = _fused_decode(
-                            step_fn, rng_key, toks_in, k_view, v_view,
-                            lengths, step, temps, top_ps, top_ks)
-                        k_pool = scatter_decode(k_pool, tb, k_view,
-                                                lengths, T)
-                        v_pool = scatter_decode(v_pool, tb, v_view,
-                                                lengths, T)
-                        return (toks, toks[-1], k_pool, v_pool,
-                                _advance_lengths(lengths, active),
-                                step + 1)
-                    return jax.jit(_decode_sample, donate_argnums=(4, 5))
-
-                self._decode = _make_decode()
-                self._decode_windows = cfg_windows
-                self._decode_by_window = {
-                    w: _make_decode(w) for w in self._decode_windows}
-        else:
-            def _make_decode(window=None):
-                def _decode_sample(params, tokens, use_prev, prev,
-                                   k_cache, v_cache, lengths, active,
-                                   step, temps, top_ps, top_ks,
-                                   rng_key):
-                    # the prev-token select and the last-row slice both
-                    # live IN the graph: an eager `where`/`toks[-1]` on
-                    # device arrays costs five op-by-op compiles the
-                    # first measured pass pays for (observed 137 ms vs
-                    # the 3 ms steady-state pass on the tiny CPU config)
-                    toks_in = jnp.where(use_prev, prev, tokens)
+                    tb = tables if mp_w is None else tables[:, :mp_w]
+                    k_view = self._gather_view(k_pool, tb)
+                    v_view = self._gather_view(v_pool, tb)
 
                     def step_fn(toks, kc, vc, lens):
-                        if window is not None:
-                            return decode_fn(params, toks, kc, vc, lens,
-                                             attn_window=window)
                         return decode_fn(params, toks, kc, vc, lens)
 
-                    (_, k_cache, v_cache, _), toks = _fused_decode(
-                        step_fn, rng_key, toks_in, k_cache, v_cache,
+                    (_, k_view, v_view, _), toks = _fused_decode(
+                        step_fn, rng_key, toks_in, k_view, v_view,
                         lengths, step, temps, top_ps, top_ks)
-                    return (toks, toks[-1], k_cache, v_cache,
-                            _advance_lengths(lengths, active), step + 1)
+                    k_pool = scatter_decode(k_pool, tb, k_view,
+                                            lengths, T)
+                    v_pool = scatter_decode(v_pool, tb, v_view,
+                                            lengths, T)
+                    return (toks, toks[-1], k_pool, v_pool,
+                            _advance_lengths(lengths, active),
+                            step + 1)
                 return jax.jit(_decode_sample, donate_argnums=(4, 5))
 
             self._decode = _make_decode()
-            # windowed decode variants: attention reads O(window) rows
-            # instead of O(max_seq) when every live length fits the
-            # bucket. Opt-in via cfg.decode_windows; each listed
-            # window is a separate compile, warmed in warmup(). Model
-            # glue must accept attn_window (probed by signature).
-            import inspect as _inspect
-            try:
-                supports_window = decode_fn is not None and \
-                    "attn_window" in _inspect.signature(
-                        decode_fn).parameters
-            except (TypeError, ValueError):
-                supports_window = False
-            self._decode_windows = cfg_windows if supports_window else ()
+            self._decode_windows = cfg_windows
             self._decode_by_window = {
                 w: _make_decode(w) for w in self._decode_windows}
         self._decode_k = K
@@ -977,50 +943,44 @@ class Engine:
             b for b in cfg.prefill_buckets if b <= cfg.max_seq)) \
             or (cfg.max_seq,)
 
-        if cfg.kv_layout == "paged":
-            pg = max(1, int(cfg.page_size))
-            self._pages_per_slot = -(-cfg.max_seq // pg)        # ceil
-            base_pages = (cfg.kv_pages if cfg.kv_pages is not None
-                          else cfg.max_batch * self._pages_per_slot)
-            # pools are sized in BYTES, not rows: the page count is
-            # budget // per-page-cost for the configured kv_dtype, so
-            # an int8 pool at the same budget holds ~2x the pages.
-            # The bf16 default without an explicit budget resolves to
-            # exactly base_pages (no probe, no arithmetic drift).
-            self._n_pages = self._sized_pool_pages(pg, base_pages)
-            self.k_cache, self.v_cache = self._alloc_pool(pg)
-            if self.paged_attention_impl == "kernel":
-                # a shape the compiled kernel cannot take fails HERE,
-                # naming the constraint — not as a Mosaic trace out of
-                # warmup, and never by quietly taking another path
-                from ..ops.paged_attention import check_kernel_layout
-                check_kernel_layout(self.k_cache)
-            self._free_pages = list(range(self._n_pages))
-            #: per-slot ordered page ids; OOB id ``n_pages`` = unallocated
-            self._tables = np.full((cfg.max_batch, self._pages_per_slot),
-                                   self._n_pages, np.int32)
-            self._slot_pages = np.zeros(cfg.max_batch, np.int32)
-            self._admit_seq = 0
-            #: page refcounts: slots and the prefix cache each hold one
-            self._page_refs = np.zeros(self._n_pages, np.int32)
-            self._prefix_cache: dict[tuple, list[int]] = {}
-            #: pins held by the cache (entries may overlap on shared
-            #: pages, so this counts references, not distinct pages)
-            self._cached_pages = 0
-            #: cached key lengths -> entry count: probes test only
-            #: these lengths instead of every aligned prefix
-            self._prefix_lens: dict[int, int] = {}
-            # reattachment needs the chunk-with-history walk; without
-            # it a populated cache could never produce a hit
-            self._prefix_enabled = (cfg.prefix_cache
-                                    and prefill_chunk_fn is not None)
-            self._prefix_budget = (cfg.prefix_cache_pages
-                                   if cfg.prefix_cache_pages is not None
-                                   else max(1, self._n_pages // 4))
-        else:
-            self.k_cache, self.v_cache = make_cache(cfg.max_batch,
-                                                    cfg.max_seq)
-            self._prefix_enabled = False  # sharing needs page tables
+        pg = max(1, int(cfg.page_size))
+        self._pages_per_slot = -(-cfg.max_seq // pg)        # ceil
+        base_pages = (cfg.kv_pages if cfg.kv_pages is not None
+                      else cfg.max_batch * self._pages_per_slot)
+        # pools are sized in BYTES, not rows: the page count is
+        # budget // per-page-cost for the configured kv_dtype, so
+        # an int8 pool at the same budget holds ~2x the pages.
+        # The bf16 default without an explicit budget resolves to
+        # exactly base_pages (no probe, no arithmetic drift).
+        self._n_pages = self._sized_pool_pages(pg, base_pages)
+        self.k_cache, self.v_cache = self._alloc_pool(pg)
+        if self.paged_attention_impl == "kernel":
+            # a shape the compiled kernel cannot take fails HERE,
+            # naming the constraint — not as a Mosaic trace out of
+            # warmup, and never by quietly taking another path
+            from ..ops.paged_attention import check_kernel_layout
+            check_kernel_layout(self.k_cache)
+        self._free_pages = list(range(self._n_pages))
+        #: per-slot ordered page ids; OOB id ``n_pages`` = unallocated
+        self._tables = np.full((cfg.max_batch, self._pages_per_slot),
+                               self._n_pages, np.int32)
+        self._slot_pages = np.zeros(cfg.max_batch, np.int32)
+        self._admit_seq = 0
+        #: page refcounts: slots and the prefix cache each hold one
+        self._page_refs = np.zeros(self._n_pages, np.int32)
+        self._prefix_cache: dict[tuple, list[int]] = {}
+        #: pins held by the cache (entries may overlap on shared
+        #: pages, so this counts references, not distinct pages)
+        self._cached_pages = 0
+        #: cached key lengths -> entry count: probes test only
+        #: these lengths instead of every aligned prefix
+        self._prefix_lens: dict[int, int] = {}
+        # reattachment needs the chunk-with-history walk; without
+        # it a populated cache could never produce a hit
+        self._prefix_enabled = cfg.prefix_cache and self._chunk_walks
+        self._prefix_budget = (cfg.prefix_cache_pages
+                               if cfg.prefix_cache_pages is not None
+                               else max(1, self._n_pages // 4))
         # allocated KV footprint (K + V, scale leaves included):
         # quantized_bytes walks the pytree so the quantized pool's q/s
         # split needs no special casing here
@@ -1029,11 +989,9 @@ class Engine:
             (self.k_cache, self.v_cache)))
         #: bytes one token's cache row takes as stored, all layers, both
         #: sides (a one-vector family's V side counts nought)
-        self._kv_row_bytes = 0
-        if cfg.kv_layout == "paged":
-            from ..ops.paged_kv import pool_row_bytes
-            self._kv_row_bytes = pool_row_bytes(self.k_cache) \
-                + pool_row_bytes(self.v_cache)
+        from ..ops.paged_kv import pool_row_bytes
+        self._kv_row_bytes = pool_row_bytes(self.k_cache) \
+            + pool_row_bytes(self.v_cache)
         self.lengths = np.zeros(cfg.max_batch, np.int32)       # kv length per slot
         self.active: list[GenRequest | None] = [None] * cfg.max_batch
         # already-admitted work bounced back (preemption, slot races,
@@ -1068,7 +1026,7 @@ class Engine:
         self._sched_dirty = True
         self._active_np = np.zeros(cfg.max_batch, bool)
         self._fresh_rows: list[int] = []
-        self._dev_tables: Any = None     # paged: device block tables
+        self._dev_tables: Any = None     # device block tables
         self._tables_dirty = True
         self._dev_rng_step = jnp.zeros((), jnp.int32)
         self._decode_busy_until = 0.0
@@ -1080,8 +1038,7 @@ class Engine:
         self._thread: threading.Thread | None = None
         self._step_count = 0
         self.total_generated = 0
-        #: per-phase wall time (device call + sync) for perf accounting;
-        #: the bench surfaces these as the per-phase breakdown.
+        #: per-phase wall time (device call + sync) for perf accounting.
         #: dispatch_s/collect_s are the summed durations of the decode
         #: passes' ``engine.decode_dispatch`` and ``engine.emit`` spans
         #: (sweep + arg prep + async dispatch / post-sync emission);
@@ -1239,7 +1196,7 @@ class Engine:
 
     def _reset_runtime_state(self) -> None:
         """Stand the runtime back up on the resident weights: no
-        in-flight passes, empty KV bookkeeping, a pristine paged
+        in-flight passes, empty KV bookkeeping, a pristine page
         allocator, device scheduler state marked for re-upload.
         Weights and every compiled graph are untouched — a restarted
         engine serves its first request without recompiling. Shared by
@@ -1256,22 +1213,10 @@ class Engine:
         self._tables_dirty = True
         self._decode_busy_until = 0.0
         self._prefill_busy_until = 0.0
-        lost = self._kv_lost()
-        if cfg.kv_layout == "paged":
-            if lost:
-                self.k_cache, self.v_cache = self._alloc_pool(
-                    max(1, int(cfg.page_size)))
-            self._free_pages = list(range(self._n_pages))
-            self._tables[:] = self._n_pages
-            self._slot_pages[:] = 0
-            self._page_refs[:] = 0
-            self._prefix_cache.clear()
-            self._prefix_lens.clear()
-            self._cached_pages = 0
-            self._prefix_digest_dirty = True
-        elif lost:
-            self.k_cache, self.v_cache = self._make_cache(
-                cfg.max_batch, cfg.max_seq)
+        if self._kv_lost():
+            self.k_cache, self.v_cache = self._alloc_pool(
+                max(1, int(cfg.page_size)))
+        self._reset_allocator()
         self.lengths[:] = 0
         # speculation: slot ownership is void (every slot re-admits),
         # so the next drafting pass re-seeds each slot's accept EWMA;
@@ -1347,15 +1292,13 @@ class Engine:
              "useful device time over total busy device time "
              "(1 - waste; see app_engine_waste_seconds for the causes)"),
             ("app_engine_kv_pages_watermark",
-             "high-water mark of KV pool pages in use (paged layout)"),
-            ("app_engine_kv_rows_watermark",
-             "high-water mark of live KV rows (slot layout)"),
+             "high-water mark of KV pool pages in use"),
             ("app_engine_prefix_pages_watermark",
              "high-water mark of page references pinned by the prefix "
              "cache"),
             ("app_engine_kv_bytes_watermark",
              "high-water mark of KV-pool HBM bytes held by in-use "
-             "pages/rows (scale leaves included for int8 pools)"),
+             "pages (scale leaves included for int8 pools)"),
             ("app_engine_host_rss_bytes_watermark",
              "host process RSS high-water mark (ru_maxrss)"),
             ("app_engine_spec_accept_rate",
@@ -1516,32 +1459,30 @@ class Engine:
         plus the decode pass. Pass ``chunked=True`` when prompts longer
         than the widest bucket are expected, so the chunked-prefill
         graph compiles here instead of inline on the first long
-        prompt. Dummy rows carry slot == max_batch so the cache
-        scatter drops them — real state is untouched. Call before
+        prompt. Dummy rows carry all-OOB block tables, so every cache
+        write drops — real state is untouched. Call before
         ``start()`` (it exercises the donated caches)."""
         cfg = self.config
-        paged = cfg.kv_layout == "paged"
+
+        def oob_tables(rows: int):
+            return jnp.full((rows, self._pages_per_slot), self._n_pages,
+                            jnp.int32)
+
         buckets = {self._bucket_for(int(n)) for n in prompt_lens}
         for bucket in sorted(buckets):
             for g in self._group_sizes():
                 self.sentinel.observe(self._sig("prefill", bucket, g))
-                if paged:  # all-OOB tables: every write drops
-                    slots = jnp.full((g, self._pages_per_slot),
-                                     self._n_pages, jnp.int32)
-                else:
-                    slots = jnp.full(g, cfg.max_batch, jnp.int32)
                 fn = self._get_prefill(bucket, g)
                 toks, self.k_cache, self.v_cache = fn(
                     self.params, jnp.zeros((g, bucket), jnp.int32),
                     jnp.ones(g, jnp.int32), self.k_cache, self.v_cache,
-                    slots, np.int32(0),
+                    oob_tables(g), np.int32(0),
                     jnp.zeros(g, jnp.float32), jnp.ones(g, jnp.float32),
                     jnp.zeros(g, jnp.int32), self._prefill_base_key)
                 jax.block_until_ready(toks)
         if decode:
             b = cfg.max_batch
-            tables = (jnp.full((b, self._pages_per_slot), self._n_pages,
-                               jnp.int32),) if paged else ()
+            tables = oob_tables(b)
             for w in (0, *self._decode_windows):
                 self.sentinel.observe(self._sig("decode", w))
             variants = [self._decode] + [
@@ -1550,7 +1491,7 @@ class Engine:
                 toks, _, self.k_cache, self.v_cache, _, _ = fn(
                     self.params, jnp.zeros(b, jnp.int32),
                     jnp.zeros(b, bool), self._dev_zero,
-                    self.k_cache, self.v_cache, *tables,
+                    self.k_cache, self.v_cache, tables,
                     jnp.ones(b, jnp.int32), jnp.zeros(b, bool),
                     jnp.zeros((), jnp.int32),
                     jnp.zeros(b, jnp.float32), jnp.ones(b, jnp.float32),
@@ -1565,7 +1506,7 @@ class Engine:
                 pass_flops = jit_cost_flops(
                     self._decode, self.params, jnp.zeros(b, jnp.int32),
                     jnp.zeros(b, bool), self._dev_zero,
-                    self.k_cache, self.v_cache, *tables,
+                    self.k_cache, self.v_cache, tables,
                     jnp.ones(b, jnp.int32), jnp.zeros(b, bool),
                     jnp.zeros((), jnp.int32),
                     jnp.zeros(b, jnp.float32), jnp.ones(b, jnp.float32),
@@ -1576,18 +1517,18 @@ class Engine:
                 self._peak_flops = device_peak_flops()
             except Exception:  # cost analysis is best-effort, never fatal
                 pass
-        if chunked and self._prefill_chunk_fn is not None:
+        if chunked and self._chunk_walks:
             # compile the chunk-walk graph at every bucket width for
             # both group sizes the walk uses (solo and full wave) —
-            # all rows dummy (OOB slots/tables): every cache write
-            # drops, the samples are discarded
+            # all rows dummy (OOB tables): every cache write drops,
+            # the samples are discarded
             P = max(1, cfg.prefill_batch)
             # full graph always; plus the single windowed chunk
-            # variant the walk dispatcher may select (paged + windows;
-            # the native chunk path is length-bounded and never picks
-            # a windowed variant)
+            # variant the view path's walk dispatcher may select (the
+            # native chunk path is length-bounded and never picks a
+            # windowed variant)
             chunk_windows = [None]
-            if paged and self._cfg_windows and not self._native_chunk:
+            if self._cfg_windows and not self._native_chunk:
                 chunk_windows.append(self._cfg_windows[-1])
             for cw in chunk_windows:
                 fn = self._get_chunk_prefill(cw)
@@ -1597,16 +1538,9 @@ class Engine:
                     for g in sorted({1, P}):
                         self.sentinel.observe(
                             self._sig("chunk", width, g, cw))
-                        if paged:
-                            slot_arg = jnp.full(
-                                (g, self._pages_per_slot),
-                                self._n_pages, jnp.int32)
-                        else:
-                            slot_arg = jnp.full(g, cfg.max_batch,
-                                                jnp.int32)
                         toks, self.k_cache, self.v_cache = fn(
                             self.params, jnp.zeros((g, width), jnp.int32),
-                            self.k_cache, self.v_cache, slot_arg,
+                            self.k_cache, self.v_cache, oob_tables(g),
                             jnp.zeros(g, jnp.int32),
                             jnp.zeros(g, jnp.int32),
                             np.int32(0), jnp.zeros(g, jnp.float32),
@@ -1623,9 +1557,6 @@ class Engine:
             # would stall the serving loop mid-stream. All rows are
             # dummies (OOB offsets/tables): every cache write drops.
             b = cfg.max_batch
-            spec_tables = (jnp.full((b, self._pages_per_slot),
-                                    self._n_pages, jnp.int32),) \
-                if paged else ()
             fn = self._get_spec_verify()
             cap = 1 + cfg.spec_draft * cfg.spec_branches
             w = 2
@@ -1636,7 +1567,7 @@ class Engine:
                     jnp.zeros((b, w), jnp.int32),
                     jnp.zeros((b, w), jnp.int32),
                     jnp.ones((b, w), jnp.int32),
-                    self.k_cache, self.v_cache, *spec_tables,
+                    self.k_cache, self.v_cache, oob_tables(b),
                     jnp.full(b, cfg.max_seq, jnp.int32),
                     jnp.ones(b, jnp.int32), np.int32(0),
                     jnp.zeros(b, jnp.float32),
@@ -1656,7 +1587,7 @@ class Engine:
         — its continuation already fit the cache.)"""
         room = max(1, min(max_new, self.config.max_seq // 2))
         limit = max(1, self.config.max_seq - room - 1)
-        if self._prefill_chunk_fn is None:
+        if not self._chunk_walks:
             limit = min(limit, max(self._usable_buckets))
         return tokens[-limit:] if len(tokens) > limit else tokens
 
@@ -1789,7 +1720,7 @@ class Engine:
         """Fused group prefill per (bucket, group-size) — ONE device
         call per group: forward [P, bucket], sample each row's first
         token, and scatter the prompt K/V straight into the donated
-        caches (dummy rows carry slot == max_batch, dropped by the
+        pools (dummy rows carry all-OOB block tables, dropped by the
         scatter). The host pulls back 4·P bytes of token ids, nothing
         else. Group sizes are powers of two up to ``prefill_batch`` so
         a lone arrival runs a [1, bucket] graph, not the full-width
@@ -1797,9 +1728,7 @@ class Engine:
         fn = self._prefill_cache.get((bucket, group))
         if fn is None:
             prefill_fn = self._prefill_fn
-
-            paged = self.config.kv_layout == "paged"
-            scatter_chunk = getattr(self, "_scatter_chunk", None)
+            from ..ops.paged_kv import scatter_chunk
 
             def fused(params, tokens, kv_len, kc, vc, slots, step,
                       temps, top_ps, top_ks, rng_key):
@@ -1810,47 +1739,38 @@ class Engine:
                         logits, jnp.maximum(kv_len - 1, 0)[:, None, None],
                         axis=1)[:, 0]
                 toks = _sample_batch(logits, key, temps, top_ps, top_ks)
-                if paged:
-                    # ``slots`` carries each row's block table [P, Mp];
-                    # scatter_chunk (offset 0, per-row prompt length)
-                    # writes only the pages each prompt spans — pad
-                    # rows past kv_len drop instead of round-tripping
-                    # the scatter owns the pool representation: plain
-                    # pools cast internally, quantized pools quantize
-                    # on write (no .astype on the pool here)
-                    zeros = jnp.zeros_like(kv_len)
-                    kc = scatter_chunk(kc, slots, k, zeros, kv_len)
-                    vc = scatter_chunk(vc, slots, v, zeros, kv_len)
-                else:
-                    s = k.shape[2]
-                    kc = kc.at[:, slots, :s].set(k.astype(kc.dtype),
-                                                 mode="drop")
-                    vc = vc.at[:, slots, :s].set(v.astype(vc.dtype),
-                                                 mode="drop")
+                # ``slots`` carries each row's block table [P, Mp];
+                # scatter_chunk (offset 0, per-row prompt length)
+                # writes only the pages each prompt spans — pad
+                # rows past kv_len drop instead of round-tripping
+                # the scatter owns the pool representation: plain
+                # pools cast internally, quantized pools quantize
+                # on write (no .astype on the pool here)
+                zeros = jnp.zeros_like(kv_len)
+                kc = scatter_chunk(kc, slots, k, zeros, kv_len)
+                vc = scatter_chunk(vc, slots, v, zeros, kv_len)
                 return toks, kc, vc
             fn = jax.jit(fused, donate_argnums=(3, 4))
             self._prefill_cache[(bucket, group)] = fn
         return fn
 
     def _get_chunk_prefill(self, window: int | None = None) -> Callable:
-        """Fused G-slot chunk step: bring each walking slot's cache
-        rows into a contiguous view (an index gather for the slot
-        layout, a page gather for the paged pool), run one [G, width]
-        chunk forward against the histories, splice the written rows
-        back, and sample (only each row's final chunk's sample is
-        used). The jit retraces per (G, width) — an admission wave of
-        prefix-cache suffixes shares ONE dispatch instead of one per
-        request, and a short tail pays for its own bucket, not the
-        widest (a [1, 512] forward for a 4-token suffix was the r4
-        bench's prefix-hit slowdown). Dummy pad rows carry OOB
-        slots/tables, so their writes drop.
+        """Fused G-slot chunk step: run one [G, width] chunk forward
+        against each walking slot's history — through the block tables
+        on the native path, on a gathered per-slot view whose written
+        rows are spliced back on the view path — and sample (only each
+        row's final chunk's sample is used). The jit retraces per
+        (G, width) — an admission wave of prefix-cache suffixes shares
+        ONE dispatch instead of one per request, and a short tail pays
+        for its own bucket, not the widest. Dummy pad rows carry OOB
+        tables, so their writes drop.
 
-        ``window`` (paged only): gather/scatter only the table columns
-        covering the first ``window`` rows — prefix-suffix walks with
-        short histories stop paying O(max_seq) view traffic. The walk
-        dispatcher uses the LARGEST configured decode window (one
-        extra compile per (G, width)) and falls back to the full graph
-        when a walker's history outgrows it."""
+        ``window`` (view path only): gather/scatter only the table
+        columns covering the first ``window`` rows — prefix-suffix
+        walks with short histories stop paying O(max_seq) view
+        traffic. The walk dispatcher uses the LARGEST configured decode
+        window (one extra compile per (G, width)) and falls back to the
+        full graph when a walker's history outgrows it."""
         fn = self._prefill_cache.get(("chunk", window))
         if fn is None:
             chunk_fn = self._prefill_chunk_fn
@@ -1875,7 +1795,7 @@ class Engine:
                     toks = _sample_batch(logits, key, temps,
                                          top_ps, top_ks)
                     return toks, kp, vp
-            elif self.config.kv_layout == "paged":
+            else:
                 from ..ops.paged_kv import scatter_decode
                 pg_rows = max(1, int(self.config.page_size))
                 mp_w = None if window is None else -(-window // pg_rows)
@@ -1903,24 +1823,6 @@ class Engine:
                     toks = _sample_batch(logits, key, temps,
                                          top_ps, top_ks)
                     return toks, kp, vp
-            else:
-                def fused(params, tokens, kc, vc, slots, offsets,
-                          chunk_lens, step, temps, top_ps, top_ks,
-                          rng_key):
-                    # dummy rows: gather clips to a real slot (read-
-                    # only, harmless), scatter drops their write-back
-                    kcs = jnp.take(kc, slots, axis=1, mode="clip")
-                    vcs = jnp.take(vc, slots, axis=1, mode="clip")
-                    logits, kcs, vcs = chunk_fn(
-                        params, tokens, kcs, vcs, offsets, chunk_lens)
-                    kc = kc.at[:, slots].set(kcs.astype(kc.dtype),
-                                             mode="drop")
-                    vc = vc.at[:, slots].set(vcs.astype(vc.dtype),
-                                             mode="drop")
-                    key = jax.random.fold_in(rng_key, step)
-                    toks = _sample_batch(logits, key, temps,
-                                         top_ps, top_ks)
-                    return toks, kc, vc
             fn = jax.jit(fused, donate_argnums=(2, 3))
             self._prefill_cache[("chunk", window)] = fn
         return fn
@@ -1929,12 +1831,11 @@ class Engine:
         """Largest configured decode window, if it covers ``needed``
         rows AND the chunk width (warmup only compiles windowed
         variants for widths <= window — the gates must agree or the
-        first wide-bucket suffix walk compiles on the serving path).
-        Paged layout only; else None (full graph). The native chunk
-        path needs no windows at all — the ragged kernel walks only
-        the pages covering each row's history + chunk."""
-        if self.config.kv_layout != "paged" or not self._cfg_windows \
-                or self._native_chunk:
+        first wide-bucket suffix walk compiles on the serving path);
+        else None (full graph). The native chunk path needs no windows
+        at all — the ragged kernel walks only the pages covering each
+        row's history + chunk."""
+        if not self._cfg_windows or self._native_chunk:
             return None
         w = self._cfg_windows[-1]
         return w if needed <= w and width <= w else None
@@ -1974,7 +1875,6 @@ class Engine:
         for every other slot interleaves instead of head-of-line
         blocking."""
         cfg = self.config
-        paged = cfg.kv_layout == "paged"
         widest = max(self._usable_buckets)
         P = max(1, cfg.prefill_batch)
         walkers: list[GenRequest] = []
@@ -1982,8 +1882,7 @@ class Engine:
             self._sched_dirty = True
         for req, slot in pairs:
             prompt = req.prompt_tokens
-            if paged and -(-(len(prompt) + 1) // cfg.page_size) \
-                    > self._n_pages:
+            if -(-(len(prompt) + 1) // cfg.page_size) > self._n_pages:
                 # an attached prefix (incref'd before this call) must
                 # not leak into the slot's table for the next occupant
                 self._release_pages(slot)
@@ -1998,7 +1897,7 @@ class Engine:
             req.slot = slot
             req.pending_prefill = True
             self._note_admitted(req)
-            if paged and req.admit_order < 0:
+            if req.admit_order < 0:
                 req.admit_order = self._admit_seq
                 self._admit_seq += 1
             walkers.append(req)
@@ -2033,23 +1932,22 @@ class Engine:
                         for r in group[i:i + P]:
                             if not owns_slot(r):
                                 continue  # a peer's headroom preempted it
-                            if paged:
-                                chunk_len = min(
-                                    width,
-                                    len(r.prompt_tokens) - r.prefill_offset)
-                                rows = min(r.prefill_offset + chunk_len + 1,
-                                           cfg.max_seq)
-                                if not self._ensure_headroom(r.slot, rows):
-                                    # the pool can't cover this walk even
-                                    # after preempting younger requests:
-                                    # release and restart from scratch
-                                    # once pages free up
-                                    self._release_pages(r.slot)
-                                    self._dev_last_reqs[r.slot] = None
-                                    self.active[r.slot] = None
-                                    r.prefill_offset = 0
-                                    self._requeue(r)
-                                    continue
+                            chunk_len = min(
+                                width,
+                                len(r.prompt_tokens) - r.prefill_offset)
+                            rows = min(r.prefill_offset + chunk_len + 1,
+                                       cfg.max_seq)
+                            if not self._ensure_headroom(r.slot, rows):
+                                # the pool can't cover this walk even
+                                # after preempting younger requests:
+                                # release and restart from scratch
+                                # once pages free up
+                                self._release_pages(r.slot)
+                                self._dev_last_reqs[r.slot] = None
+                                self.active[r.slot] = None
+                                r.prefill_offset = 0
+                                self._requeue(r)
+                                continue
                             ready.append(r)
                         ready = [r for r in ready if owns_slot(r)]
                         if not ready:
@@ -2066,13 +1964,10 @@ class Engine:
                             temps = np.zeros(G, np.float32)
                             top_ps = np.ones(G, np.float32)
                             top_ks = np.zeros(G, np.int32)
-                            if paged:  # dummy rows all-OOB: writes drop
-                                slots_arg = np.full(
-                                    (G, self._pages_per_slot),
-                                    self._n_pages, np.int32)
-                            else:
-                                slots_arg = np.full(G, cfg.max_batch,
-                                                    np.int32)
+                            # dummy rows all-OOB: writes drop
+                            slots_arg = np.full(
+                                (G, self._pages_per_slot),
+                                self._n_pages, np.int32)
                             for row, r in enumerate(ready):
                                 chunk = r.prompt_tokens[
                                     r.prefill_offset:
@@ -2083,8 +1978,7 @@ class Engine:
                                 temps[row] = r.params.temperature
                                 top_ps[row] = r.params.top_p
                                 top_ks[row] = r.params.top_k
-                                slots_arg[row] = self._tables[r.slot] \
-                                    if paged else r.slot
+                                slots_arg[row] = self._tables[r.slot]
                             self._rng_step += 1
                             dispatched = ready
                             cw = self._chunk_window(
@@ -2171,8 +2065,7 @@ class Engine:
                        and w.pending_prefill]):
                 if r.slot >= 0 and self.active[r.slot] is r:
                     self.active[r.slot] = None
-                    if paged:
-                        self._release_pages(r.slot)
+                    self._release_pages(r.slot)
                 r.pending_prefill = False
                 self._fail(r, str(exc))
             if self.logger:
@@ -2243,8 +2136,6 @@ class Engine:
         return True
 
     def _release_pages(self, slot: int) -> None:
-        if self.config.kv_layout != "paged":
-            return  # slot layout: kv rows are per-slot, nothing pooled
         n = int(self._slot_pages[slot])
         if n:
             self._tables_dirty = True
@@ -2352,7 +2243,7 @@ class Engine:
         # max_seq, non-default).
         req.prompt_tokens = list(req.prompt_tokens) + list(req.generated)
         limit = min(max(self._usable_buckets), self.config.max_seq)
-        if self._prefill_chunk_fn is not None:
+        if self._chunk_walks:
             # chunked prefill re-admits any continuation the cache can
             # hold — no bucket truncation
             limit = self.config.max_seq
@@ -2400,10 +2291,8 @@ class Engine:
                    and getattr(r, "lane", None) == "background"]
         if not victims:
             return False
-        # newest victim loses; the slot layout never stamps
-        # admit_order (-1 everywhere), so fall back to submit time
-        slot = max(victims, key=lambda i: (self.active[i].admit_order,
-                                           self.active[i].submitted_at))
+        # newest victim loses
+        slot = max(victims, key=lambda i: self.active[i].admit_order)
         req = self.active[slot]
         self._preempt(slot)
         if id(req) in self._requeued_set:
@@ -2480,6 +2369,18 @@ class Engine:
                   else base_pages * page_bytes(False))
         return max(1, int(budget) // page_bytes(cfg.kv_dtype == "int8"))
 
+    def _reset_allocator(self) -> None:
+        """Every page free, every table unallocated, the prefix cache
+        empty: what a restart and a lost pool both come back to."""
+        self._free_pages = list(range(self._n_pages))
+        self._tables[:] = self._n_pages
+        self._slot_pages[:] = 0
+        self._page_refs[:] = 0
+        self._prefix_cache.clear()
+        self._prefix_lens.clear()
+        self._cached_pages = 0
+        self._prefix_digest_dirty = True
+
     def _kv_lost(self) -> bool:
         """True when a failed donated dispatch consumed either cache —
         pytree-aware (a quantized pool is multiple leaves)."""
@@ -2496,7 +2397,6 @@ class Engine:
         new requests."""
         if not self._kv_lost():
             return
-        cfg = self.config
         for i, other in enumerate(self.active):
             if other is not None:
                 self.active[i] = None
@@ -2505,26 +2405,16 @@ class Engine:
         self.lengths[:] = 0
         self._sched_dirty = True
         self._tables_dirty = True
-        if cfg.kv_layout == "paged":  # same geometry, pristine allocator
-            self.k_cache, self.v_cache = self._alloc_pool(
-                max(1, int(cfg.page_size)))
-            self._free_pages = list(range(self._n_pages))
-            self._tables[:] = self._n_pages
-            self._slot_pages[:] = 0
-            self._page_refs[:] = 0
-            self._prefix_cache.clear()
-            self._prefix_lens.clear()
-            self._cached_pages = 0
-            self._prefix_digest_dirty = True
-        else:
-            self.k_cache, self.v_cache = self._make_cache(
-                cfg.max_batch, cfg.max_seq)
+        # same geometry, pristine allocator
+        self.k_cache, self.v_cache = self._alloc_pool(
+            max(1, int(self.config.page_size)))
+        self._reset_allocator()
 
     def _sig(self, *parts: Any) -> tuple:
         """Sentinel shape signature for a dispatch site. A non-default
-        ``kv_dtype`` changes every compiled graph on the paged path
-        (quantized pools are a different pytree), so it is folded into
-        the signature — bf16 signatures stay seed-identical."""
+        ``kv_dtype`` changes every compiled graph (quantized pools are
+        a different pytree), so it is folded into the signature — bf16
+        signatures stay seed-identical."""
         if self.config.kv_dtype != "bf16":
             return (*parts, self.config.kv_dtype)
         return parts
@@ -2917,8 +2807,7 @@ class Engine:
                                          "covered_rows": covered})
                         reserve_for_walk(req, slot)
                     continue
-            if (self._prefill_chunk_fn is not None
-                    and len(req.prompt_tokens) > widest):
+            if self._chunk_walks and len(req.prompt_tokens) > widest:
                 slot = self._free_slot()
                 if slot < 0:  # raced out of slots; try next pass
                     self._requeue(req)
@@ -2943,28 +2832,24 @@ class Engine:
 
     @hot_path
     def _prefill_group(self, bucket: int, chunk: list[GenRequest]) -> None:
-        cfg = self.config
-        paged = cfg.kv_layout == "paged"
+        pg = self.config.page_size
         placed: list[GenRequest] = []
         for req in chunk:
             slot = self._free_slot()
             if slot < 0:  # raced out of slots; back to the requeue list
                 self._requeue(req)
                 continue
-            if paged:
-                pg = cfg.page_size
-                if -(-(len(req.prompt_tokens) + 1) // pg) > self._n_pages:
-                    # can never fit, no matter what retires
-                    self._fail(req, "prompt exceeds kv pool")
-                    continue
-                if not self._alloc_pages(slot, len(req.prompt_tokens) + 1):
-                    # pool busy: requeue and wait for retires to free
-                    # pages
-                    self._requeue(req)
-                    continue
-                if req.admit_order < 0:
-                    req.admit_order = self._admit_seq
-                    self._admit_seq += 1
+            if -(-(len(req.prompt_tokens) + 1) // pg) > self._n_pages:
+                # can never fit, no matter what retires
+                self._fail(req, "prompt exceeds kv pool")
+                continue
+            if not self._alloc_pages(slot, len(req.prompt_tokens) + 1):
+                # pool busy: requeue and wait for retires to free pages
+                self._requeue(req)
+                continue
+            if req.admit_order < 0:
+                req.admit_order = self._admit_seq
+                self._admit_seq += 1
             req.slot = slot
             self._dev_last_reqs[slot] = None  # fresh occupant: host token
             self.active[slot] = req       # reserve before the next scan
@@ -2987,17 +2872,13 @@ class Engine:
                          placed: list[GenRequest], pass_id: int,
                          start: float) -> None:
         """Build the rows of one bucket prefill and enqueue it."""
-        cfg = self.config
-        paged = cfg.kv_layout == "paged"
         self.goodput.note_dispatch(start)
         try:
             tokens = np.zeros((P, bucket), np.int32)
             kv_len = np.ones(P, np.int32)                # dummy rows: length 1
-            if paged:  # per-row block tables; dummy rows all-OOB: dropped
-                slots = np.full((P, self._pages_per_slot), self._n_pages,
-                                np.int32)
-            else:      # slot ids; dummy rows OOB: dropped
-                slots = np.full(P, cfg.max_batch, np.int32)
+            # per-row block tables; dummy rows all-OOB: dropped
+            slots = np.full((P, self._pages_per_slot), self._n_pages,
+                            np.int32)
             temps = np.zeros(P, np.float32)
             top_ps = np.ones(P, np.float32)
             top_ks = np.zeros(P, np.int32)
@@ -3005,7 +2886,7 @@ class Engine:
                 n = len(req.prompt_tokens)
                 tokens[row, :n] = req.prompt_tokens
                 kv_len[row] = n
-                slots[row] = self._tables[req.slot] if paged else req.slot
+                slots[row] = self._tables[req.slot]
                 temps[row] = req.params.temperature
                 top_ps[row] = req.params.top_p
                 top_ks[row] = req.params.top_k
@@ -3021,8 +2902,7 @@ class Engine:
         except Exception as exc:
             for req in placed:
                 self.active[req.slot] = None
-                if paged:
-                    self._release_pages(req.slot)
+                self._release_pages(req.slot)
                 self._fail(req, str(exc))
             if self.logger:
                 self.logger.error(f"prefill failed: {exc!r}")  # gofrlint: allow(hot-path-purity) -- failure path: the prefill already raised; the engine is off the fast path
@@ -3078,8 +2958,7 @@ class Engine:
                     req.pending_prefill = False
                     if self.active[slot] is req:
                         self.active[slot] = None
-                        if self.config.kv_layout == "paged":
-                            self._release_pages(slot)
+                        self._release_pages(slot)
                     if req.finished_at is None:
                         self._fail(req, str(exc))
                 if self.logger:
@@ -3158,13 +3037,11 @@ class Engine:
     def _note_view_avoided(self, n_rows: int) -> None:
         """Account HBM bytes a dense-view round trip would have moved
         for a dispatch of ``n_rows`` slots that ran on the native
-        paged path instead (gather of the K and V per-slot views; the
+        path instead (gather of the K and V per-slot views; the
         write-back scatter is smaller and not counted). Surfaced in
-        ``stats`` next to ``h2d_transfers`` as the paged twin of the
-        transfer counters: steady native serving grows it every chunk/
-        verify dispatch, the view path leaves it flat."""
-        if self.config.kv_layout != "paged":
-            return
+        ``stats`` next to ``h2d_transfers``: steady native serving
+        grows it every chunk/verify dispatch, the view path leaves it
+        flat."""
         from ..ops.paged_kv import pool_shape
         pg = pool_shape(self.k_cache)[3]
         self.stats["view_bytes_avoided"] += \
@@ -3232,10 +3109,9 @@ class Engine:
         req._emit(None)
         self.active[slot] = None
         self.lengths[slot] = 0
-        if self.config.kv_layout == "paged":
-            if req.error is None and not req.cancelled:
-                self._register_prefix(slot, req)
-            self._release_pages(slot)
+        if req.error is None and not req.cancelled:
+            self._register_prefix(slot, req)
+        self._release_pages(slot)
 
     # -------------------------------------------------------------- decode
     #
@@ -3374,27 +3250,25 @@ class Engine:
         None when no row decodes."""
         cfg = self.config
         T = self._tokens_per_pass
-        paged = cfg.kv_layout == "paged"
         h2d0 = self.stats["h2d_transfers"]  # this pass's upload delta
         # pre-pass sweep retires cancelled/at-ceiling slots, which
         # settles the pipeline per-slot via _retire
         self._retire_unservable()
-        if paged:
-            # grow each slot's block table to cover this pass, evicting
-            # the newest requests when the pool runs dry (they resume
-            # by recompute); iterate oldest-first so survivors are the
-            # requests closest to completion
-            order = sorted(
-                (i for i, r in enumerate(self.active) if r is not None),
-                key=lambda i: self.active[i].admit_order)
-            for i in order:
-                if self.active[i] is None:  # preempted by an earlier slot
-                    continue
-                if self.active[i].pending_prefill:
-                    continue  # chunk walk allocates its own pages
-                rows = min(int(self.lengths[i]) + T, cfg.max_seq)
-                if not self._ensure_headroom(i, rows):
-                    self._preempt(i)  # pool can't hold even this one now
+        # grow each slot's block table to cover this pass, evicting
+        # the newest requests when the pool runs dry (they resume
+        # by recompute); iterate oldest-first so survivors are the
+        # requests closest to completion
+        order = sorted(
+            (i for i, r in enumerate(self.active) if r is not None),
+            key=lambda i: self.active[i].admit_order)
+        for i in order:
+            if self.active[i] is None:  # preempted by an earlier slot
+                continue
+            if self.active[i].pending_prefill:
+                continue  # chunk walk allocates its own pages
+            rows = min(int(self.lengths[i]) + T, cfg.max_seq)
+            if not self._ensure_headroom(i, rows):
+                self._preempt(i)  # pool can't hold even this one now
 
         if self._sched_dirty:
             self._sync_decode_state()
@@ -3430,11 +3304,10 @@ class Engine:
         self.goodput.note_dispatch(start)
         prev = (self._dev_last if self._dev_last is not None
                 else self._dev_zero)
-        tables = (self._tables_arg(),) if paged else ()
         (step_tokens, self._dev_last, self.k_cache, self.v_cache,
          new_lengths, self._dev_rng_step) = decode(
             self.params, st["tokens"], st["use_prev"], prev,
-            self.k_cache, self.v_cache, *tables, st["lengths"],
+            self.k_cache, self.v_cache, self._tables_arg(), st["lengths"],
             st["active"], self._dev_rng_step, st["temps"],
             st["top_ps"], st["top_ks"], self._dev_decode_key)
         st["lengths"] = new_lengths  # device mirror of self.lengths
@@ -3483,8 +3356,7 @@ class Engine:
         # decode_s = wall time with a decode pass in flight (dispatch →
         # sync complete), accumulated as a UNION of spans — consecutive
         # passes overlap (N+1 dispatches before N collects), and host/
-        # prefill work overlapping a pass still counts as decode here,
-        # so the bench's residual host_s is true dead time
+        # prefill work overlapping a pass still counts as decode here
         end = wait.t1
         with self.recorder.span("engine.emit", pass_id) as emit:
             busy = end - max(rec["t0"], self._decode_busy_until)
@@ -3587,12 +3459,7 @@ class Engine:
         fn = self._prefill_cache.get("spec")
         if fn is None:
             verify_fn = self._spec_verify_fn
-            paged = self.config.kv_layout == "paged" \
-                and not self._native_verify
-            if paged:
-                from ..ops.paged_kv import scatter_decode
-            if self._native_verify:
-                from ..ops.paged_kv import pool_move_rows
+            from ..ops.paged_kv import pool_move_rows, scatter_decode
             max_seq = self.config.max_seq
 
             def _resolve_tree(logits, tokens, parents, depths,
@@ -3654,7 +3521,7 @@ class Engine:
 
             def _move_rows_dense(cache, src, dst):
                 # gather ALL src rows, then scatter — overlap-safe
-                # compaction on [L, B, S, H, D] caches; OOB dst drops
+                # compaction on [L, B, S, H, D] views; OOB dst drops
                 s = cache.shape[2]
                 src_c = jnp.clip(src, 0, s - 1)
                 rows = jnp.take_along_axis(
@@ -3687,7 +3554,7 @@ class Engine:
                     kc = pool_move_rows(kc, tables, src, dst)
                     vc = pool_move_rows(vc, tables, src, dst)
                     return n_acc, bonus, path, kc, vc
-            elif paged:
+            else:
                 def fused(params, tokens, parents, depths, tree_masks,
                           kc, vc, tables, offsets, chunk_lens, step,
                           temps, top_ps, top_ks, rng_key):
@@ -3709,21 +3576,6 @@ class Engine:
                                         offsets, s_width)
                     vc = scatter_decode(vc, tables, v_view,
                                         offsets, s_width)
-                    return n_acc, bonus, path, kc, vc
-            else:
-                def fused(params, tokens, parents, depths, tree_masks,
-                          kc, vc, offsets, chunk_lens, step, temps,
-                          top_ps, top_ks, rng_key):
-                    logits, kc, vc = verify_fn(
-                        params, tokens, kc, vc, offsets, chunk_lens,
-                        tree_depths=depths, tree_masks=tree_masks)
-                    n_acc, bonus, path = _resolve_tree(
-                        logits, tokens, parents, depths, chunk_lens,
-                        step, temps, top_ps, top_ks, rng_key)
-                    src, dst = _path_moves(offsets, path, n_acc,
-                                           tokens.shape[1])
-                    kc = _move_rows_dense(kc, src, dst)
-                    vc = _move_rows_dense(vc, src, dst)
                     return n_acc, bonus, path, kc, vc
             fn = jax.jit(fused, donate_argnums=(5, 6))
             self._prefill_cache["spec"] = fn
@@ -3793,7 +3645,6 @@ class Engine:
         Slots without drafts ride along as a lone root node — for
         them this is exactly a single decode step."""
         cfg = self.config
-        paged = cfg.kv_layout == "paged"
         # verify feeds each row's true last token from host state and
         # appends host-side — the decode pipeline must be settled, its
         # device-resident last token invalidated, and the scheduler
@@ -3852,17 +3703,16 @@ class Engine:
             rows.append(i)
         if not rows:
             return
-        if paged:
-            # headroom for every fed row (draft nodes write cache rows
-            # too); an earlier row's headroom may preempt a later one
-            for i in list(rows):
-                if self.active[i] is None:  # preempted as a victim
-                    continue
-                rows_needed = min(int(self.lengths[i])
-                                  + int(chunk_lens[i]), cfg.max_seq)
-                if not self._ensure_headroom(i, rows_needed):
-                    self._preempt(i)
-        tables = (self._tables_arg(),) if paged else ()
+        # headroom for every fed row (draft nodes write cache rows
+        # too); an earlier row's headroom may preempt a later one
+        for i in list(rows):
+            if self.active[i] is None:  # preempted as a victim
+                continue
+            rows_needed = min(int(self.lengths[i])
+                              + int(chunk_lens[i]), cfg.max_seq)
+            if not self._ensure_headroom(i, rows_needed):
+                self._preempt(i)
+        tables = self._tables_arg()
         self._rng_step += 1
         self._note_dispatch_shape("spec_verify", width)
         start = time.perf_counter()
@@ -3873,7 +3723,7 @@ class Engine:
             self.v_cache = fn(
                 self.params, jnp.asarray(tokens), jnp.asarray(parents),
                 jnp.asarray(depths), jnp.asarray(masks), self.k_cache,
-                self.v_cache, *tables, jnp.asarray(offsets),
+                self.v_cache, tables, jnp.asarray(offsets),
                 jnp.asarray(chunk_lens), np.int32(self._rng_step),
                 jnp.asarray(temps), jnp.asarray(top_ps),
                 jnp.asarray(top_ks), self._prefill_base_key)
@@ -3979,25 +3829,15 @@ class Engine:
     def _update_kv_watermarks(self) -> None:
         """KV high-water marks, sampled at collect sites so a short
         burst's peak is caught before its slots retire — an O(1) page
-        count (paged) or an O(max_batch) length sum (slot), pure host
-        compares."""
+        count, pure host compares."""
         wm = self.watermarks
         if not wm.enabled:
             return
-        if self.config.kv_layout == "paged":
-            used = self._n_pages - len(self._free_pages)
-            wm.update("kv_pages", float(used))
-            wm.update("prefix_pages", float(self._cached_pages))
-            wm.update("kv_bytes",
-                      used * self._kv_bytes_total
-                      / max(1, self._n_pages))
-        else:
-            rows = float(self.lengths.sum())
-            wm.update("kv_rows", rows)
-            wm.update("kv_bytes",
-                      rows * self._kv_bytes_total
-                      / max(1, self.config.max_batch
-                            * self.config.max_seq))
+        used = self._n_pages - len(self._free_pages)
+        wm.update("kv_pages", float(used))
+        wm.update("prefix_pages", float(self._cached_pages))
+        wm.update("kv_bytes",
+                  used * self._kv_bytes_total / max(1, self._n_pages))
 
     def _update_watermarks(self) -> None:
         """Advance every memory high-water mark (throttled cadence):
@@ -4013,10 +3853,7 @@ class Engine:
         goodput classification, memory watermarks, recompile sentinel
         state — all host-side reads."""
         self._update_watermarks()
-        cfg = self.config
-        cap_tokens = (self._n_pages * max(1, int(cfg.page_size))
-                      if cfg.kv_layout == "paged"
-                      else cfg.max_batch * cfg.max_seq)
+        cap_tokens = self._n_pages * max(1, int(self.config.page_size))
         return {"goodput": self.goodput.state(),
                 "watermarks": self.watermarks.state(),
                 "recompiles": self.sentinel.state(),
@@ -4070,7 +3907,6 @@ class Engine:
         if wm.enabled:
             for mark, gauge in (
                 ("kv_pages", "app_engine_kv_pages_watermark"),
-                ("kv_rows", "app_engine_kv_rows_watermark"),
                 ("kv_bytes", "app_engine_kv_bytes_watermark"),
                 ("prefix_pages", "app_engine_prefix_pages_watermark"),
                 ("host_rss_bytes",
@@ -4087,29 +3923,22 @@ class Engine:
                         round(self._spec_ctrl.accept_rate(), 6))
         if hasattr(self.waiting, "publish_gauges"):
             self.waiting.publish_gauges(m)
-        cfg = self.config
-        if cfg.kv_layout == "paged":
-            used = self._n_pages - len(self._free_pages)
-            m.set_gauge("app_engine_kv_pool_utilization",
-                        round(used / max(1, self._n_pages), 4))
-            # fragmentation: allocated page capacity not holding live
-            # rows (pending-prefill slots report their walk progress)
-            cap_rows = int(self._slot_pages.sum()) * cfg.page_size
-            live = int(self.lengths.sum()) + sum(
-                r.prefill_offset for r in self.active
-                if r is not None and r.pending_prefill)
-            frag = 1.0 - live / cap_rows if cap_rows else 0.0
-            m.set_gauge("app_engine_kv_pool_fragmentation",
-                        round(min(1.0, max(0.0, frag)), 4))
-            m.set_gauge("app_engine_prefix_cache_entries",
-                        float(len(self._prefix_cache)))
-            m.set_gauge("app_engine_prefix_cache_pages",
-                        float(self._cached_pages))
-        else:
-            m.set_gauge("app_engine_kv_pool_utilization",
-                        round(float(self.lengths.sum())
-                              / (cfg.max_batch * cfg.max_seq), 4))
-            m.set_gauge("app_engine_kv_pool_fragmentation", 0.0)
+        used = self._n_pages - len(self._free_pages)
+        m.set_gauge("app_engine_kv_pool_utilization",
+                    round(used / max(1, self._n_pages), 4))
+        # fragmentation: allocated page capacity not holding live
+        # rows (pending-prefill slots report their walk progress)
+        cap_rows = int(self._slot_pages.sum()) * self.config.page_size
+        live = int(self.lengths.sum()) + sum(
+            r.prefill_offset for r in self.active
+            if r is not None and r.pending_prefill)
+        frag = 1.0 - live / cap_rows if cap_rows else 0.0
+        m.set_gauge("app_engine_kv_pool_fragmentation",
+                    round(min(1.0, max(0.0, frag)), 4))
+        m.set_gauge("app_engine_prefix_cache_entries",
+                    float(len(self._prefix_cache)))
+        m.set_gauge("app_engine_prefix_cache_pages",
+                    float(self._cached_pages))
 
     @hot_path_boundary(
         "prefix-digest assembly at the throttled gauge cadence: host-side "
@@ -4205,6 +4034,10 @@ class Engine:
                     proposals: dict[int, Any] = {}  # slot -> DraftTree
                     decoding = 0
                     if self._spec_enabled:
+                        # drafting reads each stream's tail on the
+                        # host: a pass still in flight holds tokens the
+                        # tree's root and n-gram must not miss
+                        self._drain_pending()
                         for i, r in enumerate(self.active):
                             if (r is None or r.pending_prefill
                                     or r.cancelled):

@@ -75,7 +75,7 @@ def llama_engine(params: Any, model_config: LlamaConfig,
             # ("Mosaic kernels cannot be automatically partitioned.
             # Please wrap the call in a shard_map" — the v5e compiler,
             # described 2x2 mesh). Until they are shard_mapped over the
-            # head axis (ROADMAP A5) a sharded engine is built on XLA
+            # head axis (ROADMAP B1) a sharded engine is built on XLA
             # attention, chosen here by name, not found out in warmup.
             implementation = "xla"
         params = shard_params(params, mesh, llama_param_specs(mesh))
@@ -97,11 +97,9 @@ def llama_engine(params: Any, model_config: LlamaConfig,
             k, v = constrain_kv(k), constrain_kv(v)
         return logits, (k, v)
 
-    def decode_fn(params, tokens, k_cache, v_cache, lengths,
-                  attn_window=None):
+    def decode_fn(params, tokens, k_cache, v_cache, lengths):
         logits, kc, vc = llama_decode_step(params, tokens, k_cache,
-                                           v_cache, lengths, c,
-                                           attn_window=attn_window)
+                                           v_cache, lengths, c)
         if constrain_kv is not None:
             kc, vc = constrain_kv(kc), constrain_kv(vc)
         return logits, kc, vc
@@ -136,13 +134,13 @@ def llama_engine(params: Any, model_config: LlamaConfig,
     paged_decode_fn = None
     paged_chunk_fn = None
     paged_verify_fn = None
-    if engine_config.kv_layout == "paged" and mesh is None:
+    if mesh is None:
         # native paged serving: rows written through the block table,
         # ragged paged-attention kernels read pages in place — no
         # per-pass view materialisation on decode, chunked prefill,
         # prefix reattachment or speculative verify. (The mesh path
-        # keeps the view: the kernels are single-device; tp-sharding
-        # them is future work and the view path already shards.)
+        # keeps the view: the kernels are single-device until they are
+        # shard_mapped, ROADMAP B1, and the view path already shards.)
         from ..models.llama import (llama_decode_step_paged,
                                     llama_prefill_chunk_paged)
         impl = {"kernel": "pallas", "interpret": "interpret",
@@ -194,10 +192,9 @@ def moe_engine(params: Any, model_config, engine_config: EngineConfig | None = N
             implementation=implementation)
         return logits, caches
 
-    def decode_fn(params, tokens, k_cache, v_cache, lengths,
-                  attn_window=None):
+    def decode_fn(params, tokens, k_cache, v_cache, lengths):
         return moe_decode_step(params, tokens, k_cache, v_cache,
-                               lengths, c, attn_window=attn_window)
+                               lengths, c)
 
     def make_cache(batch, max_seq):
         shape = (c.n_layers, batch, max_seq, c.n_kv_heads, c.head_dim)
@@ -232,7 +229,7 @@ def deepseek_engine(params: Any, model_config,
     from dataclasses import replace
     from ..ops.attention import is_tpu
     c = model_config
-    cfg = engine_config or EngineConfig(kv_layout="paged")
+    cfg = engine_config or EngineConfig()
     if cfg.paged_attention == "auto":
         # the engine's own "auto" falls back to the dense view off the
         # TPU, which this family has no step for
@@ -242,10 +239,6 @@ def deepseek_engine(params: Any, model_config,
         (mesh is not None,
          "mesh=: the latent kernel and the grouped expert matmul are "
          "single-device programs; neither is shard_mapped yet"),
-        (cfg.kv_layout != "paged",
-         f"kv_layout={cfg.kv_layout!r}: the family has paged step "
-         f"functions only (chunk prefill and decode read history from the "
-         f"latent page pool); set kv_layout='paged'"),
         (cfg.kv_dtype != "bf16",
          f"kv_dtype={cfg.kv_dtype!r}: a latent row's 512 compressed lanes "
          f"and 64 rope lanes have no int8 scale layout yet"),
@@ -284,10 +277,8 @@ def deepseek_engine(params: Any, model_config,
             params, tokens, pool, v_pool, tables, offsets, chunk_lengths,
             c, implementation=impl)
 
-    # prefill_chunk_fn is what switches chunk walks and the prefix cache
-    # on; the native path never calls it (the view path is refused above)
-    return Engine(params, cfg, prefill_fn=prefill_fn, decode_fn=None,
-                  make_cache=make_cache, prefill_chunk_fn=paged_chunk_fn,
+    return Engine(params, cfg, prefill_fn=prefill_fn,
+                  make_cache=make_cache,
                   paged_decode_fn=paged_decode_fn,
                   paged_chunk_fn=paged_chunk_fn,
                   metrics=metrics, logger=logger, tracer=tracer)

@@ -211,7 +211,7 @@ def act_stall_evict_rejoin() -> None:
 # ------------------------------------- act 3: page exhaustion over HTTP
 def act_page_exhaustion_http() -> None:
     eng = demo_llama_engine(EngineConfig(
-        max_batch=2, max_seq=128, kv_layout="paged", page_size=16,
+        max_batch=2, max_seq=128, page_size=16,
         faults="page_exhaustion:at=1,times=2"))
     app = App(config=DictConfig({
         "HTTP_PORT": "0", "METRICS_PORT": "0",

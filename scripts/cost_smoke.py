@@ -65,7 +65,7 @@ def main() -> int:
     autoprof_dir = f"/tmp/gofr_cost_smoke_{os.getpid()}"
     app = make_app("cost-smoke")
     engine = demo_llama_engine(EngineConfig(
-        max_batch=4, max_seq=256, kv_layout="paged", page_size=8,
+        max_batch=4, max_seq=256, page_size=8,
         prefill_buckets=(8,), seed=5,
         cost_baseline_passes=BASELINE_PASSES,
         cost_drift_ratio=2.0, cost_drift_sigma=6.0,

@@ -82,7 +82,7 @@ def check_kv_capacity() -> None:
 
     def sessions(kv_dtype: str) -> int:
         eng = demo_llama_engine(EngineConfig(
-            max_batch=4, max_seq=128, seed=0, kv_layout="paged",
+            max_batch=4, max_seq=128, seed=0,
             page_size=page, kv_dtype=kv_dtype, kv_pool_bytes=budget))
         return eng._n_pages // pages_per_sess
 
@@ -96,7 +96,7 @@ def check_kv_capacity() -> None:
 def main() -> int:
     check_kv_capacity()
     engine = demo_llama_engine(EngineConfig(
-        max_batch=4, max_seq=128, seed=0, kv_layout="paged",
+        max_batch=4, max_seq=128, seed=0,
         page_size=16, prefix_cache=True, paged_attention="view"))
     # warm + seal: post-warmup novel shapes would now count as
     # recompiles — the smoke's prompts stay inside the warmed bucket.

@@ -66,7 +66,7 @@ def boot_leader(name, rank):
 def boot_worker(name, urls, *, engine_kw=None):
     app = make_app(name)
     engine = demo_llama_engine(EngineConfig(
-        max_batch=4, max_seq=256, kv_layout="paged", page_size=8,
+        max_batch=4, max_seq=256, page_size=8,
         prefill_buckets=(8,), seed=5, **(engine_kw or {})))
     app.serve_model("llm", engine, ByteTokenizer())
     app.join_fleet(urls[0], host_id=name,

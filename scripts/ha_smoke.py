@@ -87,7 +87,7 @@ def main() -> int:
     for host in WORKERS:
         app = make_app(host)
         engine = demo_llama_engine(EngineConfig(
-            max_batch=4, max_seq=256, kv_layout="paged",
+            max_batch=4, max_seq=256,
             page_size=8, prefill_buckets=(8,), seed=5))
         app.serve_model("llm", engine, ByteTokenizer())
         app.join_fleet(urls[0], host_id=host,

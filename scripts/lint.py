@@ -1,7 +1,7 @@
 #!/usr/bin/env python
 """gofrlint CLI — run the repo-native AST invariant analyzer.
 
-    python scripts/lint.py gofr_tpu/ scripts/ bench.py
+    python scripts/lint.py gofr_tpu/ scripts/ chip_smoke.py
     python scripts/lint.py --format=json gofr_tpu/serving/engine.py
     python scripts/lint.py --rule hot-path-purity gofr_tpu/
     python scripts/lint.py --self-test        # seeded violation must fail

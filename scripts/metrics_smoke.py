@@ -70,7 +70,7 @@ def request(port: int, method: str, path: str, body=None, headers=None):
 
 def main() -> int:
     engine = demo_llama_engine(EngineConfig(
-        max_batch=4, max_seq=128, seed=0, kv_layout="paged",
+        max_batch=4, max_seq=128, seed=0,
         page_size=16, prefix_cache=True, paged_attention="view"))
     app = App(config=DictConfig({
         "HTTP_PORT": "0", "METRICS_PORT": "0",

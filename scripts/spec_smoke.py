@@ -83,13 +83,12 @@ def run_engine(cfg: EngineConfig, n_tokens: int = 24):
 
 
 def check_greedy_identity() -> None:
-    """Spec ON == spec OFF, greedy, on both KV layouts (int8 paged
-    pool exercises raw-code KV compaction; slot layout exercises the
-    dense gather/scatter fallback)."""
+    """Spec ON == spec OFF, greedy, on an int8 and a bf16 pool (off
+    the TPU both verify on the gathered dense view; the int8 pool
+    requantizes the accepted rows on the scatter back)."""
     layouts = (
-        ("int8 paged", dict(kv_layout="paged", page_size=16,
-                            kv_dtype="int8")),
-        ("dense slot", {}),
+        ("int8 pool", dict(page_size=16, kv_dtype="int8")),
+        ("bf16 pool", {}),
     )
     for name, extra in layouts:
         base = dict(max_batch=2, max_seq=128, seed=0,
@@ -115,7 +114,7 @@ def main() -> int:
     check_greedy_identity()
 
     engine = demo_llama_engine(EngineConfig(
-        max_batch=2, max_seq=128, seed=0, kv_layout="paged",
+        max_batch=2, max_seq=128, seed=0,
         page_size=16, speculative=True, spec_ngram=2,
         decode_steps_per_pass=1))
     engine.warmup(prompt_lens=(32,), chunked=True)

@@ -539,8 +539,7 @@ class TestCLI:
 
     def test_repo_lints_clean(self):
         # the acceptance gate itself: the live tree must stay clean
-        r = self.run_cli("gofr_tpu/", "scripts/", "bench.py",
-                         "chip_smoke.py")
+        r = self.run_cli("gofr_tpu/", "scripts/", "chip_smoke.py")
         assert r.returncode == 0, r.stdout + r.stderr
 
 

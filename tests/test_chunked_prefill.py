@@ -3,6 +3,7 @@ in bucket-width chunks against the growing cache — no truncation, and
 greedy outputs identical to a single wide prefill."""
 
 import numpy as np
+import pytest
 
 from gofr_tpu.serving.engine import EngineConfig, SamplingParams
 from gofr_tpu.serving.glue import demo_llama_engine
@@ -84,21 +85,20 @@ def test_chunked_interleaves_with_bucketed_admission():
         engine.stop()
 
 
-def test_paged_layout_chunks_and_matches_slot_layout():
-    """The paged pool walks long prompts too (gather view → chunk →
-    scatter back): unclamped, and greedy-identical to the slot
-    layout."""
-    paged = demo_llama_engine(
-        EngineConfig(max_batch=2, max_seq=128, prefill_buckets=(8,),
-                     kv_layout="paged", seed=7))
-    toks_paged, kept = _generate(paged, PROMPT)
+def test_native_chunk_walk_matches_the_view_path():
+    """The native walk (chunk rows written through the tables, history
+    read from the pool) is unclamped and greedy-identical to the view
+    path — the reference: gather view → dense ``llama_prefill_chunk`` →
+    scatter back, no table writes by the model."""
+    base = dict(max_batch=2, max_seq=128, prefill_buckets=(8,),
+                page_size=16, seed=7)
+    native = demo_llama_engine(EngineConfig(paged_attention="xla", **base))
+    toks_native, kept = _generate(native, PROMPT)
     assert kept == len(PROMPT)  # nothing clamped
 
-    slot = demo_llama_engine(
-        EngineConfig(max_batch=2, max_seq=128, prefill_buckets=(8,),
-                     seed=7))
-    toks_slot, _ = _generate(slot, PROMPT)
-    assert toks_paged == toks_slot
+    view = demo_llama_engine(EngineConfig(paged_attention="view", **base))
+    toks_view, _ = _generate(view, PROMPT)
+    assert toks_native == toks_view
 
 
 def test_cancel_mid_chunk_walk_frees_the_slot():
@@ -181,14 +181,14 @@ def test_two_long_prompts_contend_for_the_pool():
         engine.stop()
 
 
-def test_warmup_chunked_compiles_both_layouts():
-    for layout in ("slot", "paged"):
-        engine = demo_llama_engine(
-            EngineConfig(max_batch=2, max_seq=64, prefill_buckets=(8,),
-                         kv_layout=layout, seed=1))
-        engine.warmup(prompt_lens=(8,), chunked=True)  # must not crash
-        toks, _ = _generate(engine, list(range(3, 30)), n=3)
-        assert len(toks) == 3
+@pytest.mark.parametrize("path", ["view", "xla"])
+def test_warmup_chunked_compiles_both_paths(path):
+    engine = demo_llama_engine(
+        EngineConfig(max_batch=2, max_seq=64, prefill_buckets=(8,),
+                     page_size=16, paged_attention=path, seed=1))
+    engine.warmup(prompt_lens=(8,), chunked=True)  # must not crash
+    toks, _ = _generate(engine, list(range(3, 30)), n=3)
+    assert len(toks) == 3
 
 
 def test_walker_does_not_starve_waiting_admission():

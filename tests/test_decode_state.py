@@ -11,7 +11,7 @@ These tests pin the contract:
   * scheduler events trigger exactly one resync;
   * ``decode_passes_per_dispatch`` (M) is a pure throughput knob —
     greedy outputs are bit-identical to the single-pass path on both
-    KV layouts, in fewer dispatches.
+    attention paths, in fewer dispatches.
 """
 
 import time
@@ -124,13 +124,13 @@ def test_dispatch_and_collect_spans_accounted():
 
 
 @pytest.mark.parametrize("layout_kw", [
-    {},
-    {"kv_layout": "paged", "page_size": 16, "paged_attention": "view"},
+    {},      # the default: the pool through the dense view off the TPU
+    {"page_size": 16, "paged_attention": "xla"},    # the native path
 ])
 def test_multi_pass_decode_greedy_identical(layout_kw):
     """decode_passes_per_dispatch is a pure dispatch-overhead knob:
     K x M fused steps must reproduce the single-pass token streams
-    bit for bit (both KV layouts), in fewer dispatches."""
+    bit for bit (both attention paths), in fewer dispatches."""
     prompts = [[5 + i, 2, 9] for i in range(3)]
     n = 32
 

@@ -430,7 +430,7 @@ def test_llama_pass_records_are_as_they_were():
 @pytest.mark.parametrize("kw,names", [
     (dict(kv_dtype="int8"), "kv_dtype"),
     (dict(speculative=True), "speculative"),
-    (dict(kv_layout="slot"), "kv_layout"),
+    (dict(kv_layout="slot"), "removed in PR 30"),
     (dict(paged_attention="view"), "view"),
     (dict(mesh=object()), "mesh")])
 def test_unsupported_combinations_are_refused_at_build(kw, names):

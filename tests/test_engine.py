@@ -271,6 +271,50 @@ def test_moe_engine_generates():
     assert len(req.generated) == 6
 
 
+def test_moe_engine_on_the_pool_matches_the_dense_steps_driven_directly():
+    """``moe_engine`` has no paged steps, so it serves from the page
+    pool through the dense view. Its greedy streams must be those of
+    ``moe_prefill_last`` + ``moe_decode_step`` driven by hand on a
+    dense cache, with no engine and no pool in between."""
+    import jax
+    import jax.numpy as jnp
+    from gofr_tpu.models.moe import (MoEConfig, moe_decode_step, moe_init,
+                                     moe_prefill_last)
+    from gofr_tpu.serving.glue import moe_engine
+    c = MoEConfig.tiny()
+    params = moe_init(jax.random.key(0), c)
+    prompts, n_new, max_seq = [[1, 2, 3], [9, 4, 7, 7, 2]], 20, 64
+
+    def by_hand(prompt):
+        tokens = jnp.zeros((1, 8), jnp.int32).at[0, :len(prompt)].set(
+            jnp.asarray(prompt))
+        n = jnp.asarray([len(prompt)], jnp.int32)
+        logits, (k, v), _ = moe_prefill_last(params, tokens, c,
+                                             kv_lengths=n,
+                                             implementation="xla")
+        shape = (c.n_layers, 1, max_seq, c.n_kv_heads, c.head_dim)
+        kc = jnp.zeros(shape, c.dtype).at[:, :, :8].set(k)
+        vc = jnp.zeros(shape, c.dtype).at[:, :, :8].set(v)
+        out = [int(jnp.argmax(logits[0]))]
+        for _ in range(n_new - 1):
+            logits, kc, vc = moe_decode_step(
+                params, jnp.asarray(out[-1:], jnp.int32), kc, vc, n, c)
+            out.append(int(jnp.argmax(logits[0])))
+            n = n + 1
+        return out
+
+    eng = moe_engine(params, c, EngineConfig(
+        max_batch=2, max_seq=max_seq, page_size=16,
+        prefill_buckets=(8,), seed=3), implementation="xla")
+    assert eng.paged_attention_impl == "view" and eng._n_pages == 8
+    eng.start()
+    reqs = [eng.submit_sync(p, SamplingParams(
+        temperature=0.0, max_new_tokens=n_new)) for p in prompts]
+    eng.stop()
+    assert all(r.error is None for r in reqs)
+    assert [r.generated for r in reqs] == [by_hand(p) for p in prompts]
+
+
 def test_engine_warmup_precompiles_and_serves():
     """warmup() before start() must leave the engine fully functional
     and identical in output to an unwarmed engine."""
@@ -354,20 +398,22 @@ def test_stalled_engine_reports_degraded():
 
 
 def test_decode_windows_match_full_attention():
-    """Windowed decode attention (reads O(window) rows, not O(max_seq))
-    must be greedily identical to the full graph, including prompts
-    whose lengths cross a window boundary mid-generation."""
+    """Windowed view decode (gathers O(window) rows a slot, not
+    O(max_seq)) must be greedily identical to the full graph,
+    including prompts whose lengths cross a window boundary
+    mid-generation."""
     import time as _t
 
     from gofr_tpu.serving.glue import demo_llama_engine
 
     def run(**extra):
         eng = demo_llama_engine(EngineConfig(max_batch=4, max_seq=256,
-                                             seed=13, **extra))
+                                             seed=13, page_size=16,
+                                             **extra))
         eng.start()
         # 10-token prompt + 40 generated: passes need 18, 26, 34, ...
         # rows (len + K, K=8) — the 32-window graph runs the early
-        # passes, then selection hands the SAME donated caches to the
+        # passes, then selection hands the SAME donated pools to the
         # 64 graph and finally the full graph as lengths cross each
         # boundary (the riskiest path: variant switches mid-request)
         reqs = [eng.submit(list(range(2, 12)), SamplingParams(
@@ -388,8 +434,8 @@ def test_decode_windows_match_full_attention():
 
 def test_moe_decode_windows_match_full_attention():
     """MoE windowed decode must match the full graph greedily across a
-    window boundary (same contract as the llama test — the signature
-    probe now enables windows for moe_engine too)."""
+    window boundary (same contract as the llama test: windows are the
+    view path's, whatever family's dense steps run on the view)."""
     import time as _t
 
     import jax
@@ -402,7 +448,7 @@ def test_moe_decode_windows_match_full_attention():
     def run(**extra):
         eng = moe_engine(params, c,
                          EngineConfig(max_batch=2, max_seq=128, seed=7,
-                                      **extra),
+                                      page_size=16, **extra),
                          implementation="xla")
         eng.start()
         reqs = [eng.submit([4 + i, 2, 9], SamplingParams(

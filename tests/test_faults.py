@@ -280,9 +280,9 @@ def test_recover_salvage_rules_whitebox():
 
 # ------------------------------------------------ restartable lifecycle
 @pytest.mark.parametrize("layout", [
-    {"kv_layout": "slot"},
-    {"kv_layout": "paged", "page_size": 16},
-], ids=["slot", "paged"])
+    {"page_size": 16},
+    {"page_size": 16, "paged_attention": "xla"},
+], ids=["view", "native"])
 def test_stop_start_stop_cycle_serves_identically(layout):
     eng = demo_llama_engine(EngineConfig(max_batch=2, max_seq=64,
                                          seed=3, **layout))

@@ -297,15 +297,6 @@ def test_engine_watermarks_monotone_within_run():
         eng.stop()
 
 
-def test_slot_layout_records_kv_rows_watermark():
-    eng = demo_llama_engine(EngineConfig(max_batch=2, max_seq=128,
-                                         seed=0))
-    _run(eng, [[1, 2, 3]], 8)
-    marks = eng.efficiency_state()["watermarks"]
-    assert marks["kv_rows"]["value"] > 0
-    assert "kv_pages" not in marks
-
-
 # ---------------------------------------------------- metrics surface
 def test_waste_counters_and_ratio_published():
     m = MetricsManager()

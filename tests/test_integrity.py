@@ -50,16 +50,18 @@ def test_request_digest_deterministic_and_sensitive():
     assert a == request_digest([1, 2, 3], jittered, [9, 8, 7])
 
 
-def test_digest_identical_across_kv_layouts():
-    """Slot and paged layouts produce bit-identical greedy tokens
-    (test_paged_attention pins that) — the fingerprint must agree
-    too, or a mixed-layout fleet would vote against itself."""
+def test_digest_identical_across_attention_paths():
+    """The view path (dense step functions) and the native path
+    (the ragged kernel, interpreted) produce bit-identical greedy
+    tokens (test_paged_attention pins that) — the fingerprint must
+    agree too, or a fleet of sharded and single-chip hosts would vote
+    against itself."""
     prompts = [[5 + i, 2, 9] for i in range(2)]
     digests = {}
     for name, extra in (
-            ("slot", {}),
-            ("paged", dict(kv_layout="paged", page_size=16,
-                           paged_attention="interpret"))):
+            ("view", dict(page_size=16, paged_attention="view")),
+            ("native", dict(page_size=16,
+                            paged_attention="interpret"))):
         engine = demo_llama_engine(EngineConfig(
             max_batch=2, max_seq=128, seed=23, **extra))
         engine.start()
@@ -69,7 +71,7 @@ def test_digest_identical_across_kv_layouts():
         assert all(r.error is None for r in reqs)
         digests[name] = [r.digest for r in reqs]
         assert all(digests[name])
-    assert digests["slot"] == digests["paged"]
+    assert digests["view"] == digests["native"]
 
 
 def test_digest_deterministic_on_int8_pool():

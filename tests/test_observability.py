@@ -162,12 +162,12 @@ def test_steady_state_zero_h2d_with_observability_enabled():
 
 
 @pytest.mark.parametrize("layout_kw", [
-    {},
-    {"kv_layout": "paged", "page_size": 16, "paged_attention": "view"},
+    {},      # the default: the pool through the dense view off the TPU
+    {"page_size": 16, "paged_attention": "xla"},    # the native path
 ])
 def test_greedy_bit_identical_with_observability_enabled(layout_kw):
     """Greedy token streams with tracer+recorder+metrics enabled are
-    bit-identical to the bare engine (both KV layouts)."""
+    bit-identical to the bare engine (both attention paths)."""
     prompts = [[5 + i, 2, 9] for i in range(3)]
 
     def cfg():

@@ -538,10 +538,12 @@ def test_int8_chunk_within_quant_bound_of_f32():
 
 # ------------------------------------------------- engine-level parity
 
-def test_paged_native_engine_matches_slot_engine():
+def test_paged_native_engine_matches_view_engine():
     """The native paged decode path (row writes through the table +
-    ragged kernel in interpret mode) must reproduce slot-layout greedy
-    outputs exactly — same contract as the view path."""
+    ragged kernel in interpret mode) must reproduce the view engine's
+    greedy outputs exactly. The view engine is the reference: the dense
+    step functions on a gathered per-slot view, no kernel, no table
+    writes by the model."""
     import time
 
     from gofr_tpu.serving.engine import EngineConfig, SamplingParams
@@ -554,17 +556,16 @@ def test_paged_native_engine_matches_slot_engine():
             time.sleep(0.01)
         return reqs
 
-    cfg = dict(max_batch=3, max_seq=128, seed=23)
-    slot = demo_llama_engine(EngineConfig(**cfg))
-    slot.start()
-    want = [slot.submit([5 + i, 2, 9], SamplingParams(
+    cfg = dict(max_batch=3, max_seq=128, seed=23, page_size=16)
+    view = demo_llama_engine(EngineConfig(paged_attention="view", **cfg))
+    view.start()
+    want = [view.submit([5 + i, 2, 9], SamplingParams(
         temperature=0.0, max_new_tokens=9)) for i in range(3)]
     drain(want)
-    slot.stop()
+    view.stop()
 
     native = demo_llama_engine(EngineConfig(
-        kv_layout="paged", page_size=16, paged_attention="interpret",
-        **cfg))
+        paged_attention="interpret", **cfg))
     assert native._decode is not None
     native.start()
     got = [native.submit([5 + i, 2, 9], SamplingParams(

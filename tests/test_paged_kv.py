@@ -345,22 +345,25 @@ def _drain(reqs, timeout=120):
     return reqs
 
 
-def test_paged_engine_matches_slot_engine():
-    cfg = dict(max_batch=4, max_seq=128, seed=17)
-    slot = demo_llama_engine(EngineConfig(**cfg))
-    slot.start()
-    want = [slot.submit([3 + i, 1, 4], SamplingParams(
+def test_native_engine_matches_view_engine():
+    """The reference is the view engine: the dense step functions on a
+    gathered per-slot view, no kernel, no table writes by the model.
+    The native path (rows written through the tables, attention
+    gathering inside the op) must reproduce its greedy streams."""
+    cfg = dict(max_batch=4, max_seq=128, seed=17, page_size=16)
+    view = demo_llama_engine(EngineConfig(paged_attention="view", **cfg))
+    view.start()
+    want = [view.submit([3 + i, 1, 4], SamplingParams(
         temperature=0.0, max_new_tokens=10)) for i in range(4)]
     _drain(want)
-    slot.stop()
+    view.stop()
 
-    paged = demo_llama_engine(EngineConfig(kv_layout="paged", page_size=16,
-                                           **cfg))
-    paged.start()
-    got = [paged.submit([3 + i, 1, 4], SamplingParams(
+    native = demo_llama_engine(EngineConfig(paged_attention="xla", **cfg))
+    native.start()
+    got = [native.submit([3 + i, 1, 4], SamplingParams(
         temperature=0.0, max_new_tokens=10)) for i in range(4)]
     _drain(got)
-    paged.stop()
+    native.stop()
 
     assert [r.generated for r in got] == [r.generated for r in want]
     assert all(r.error is None for r in got)
@@ -461,14 +464,26 @@ def test_single_device_pool_is_not_pinned(kv_dtype):
 
 def test_kv_dtype_validation():
     """Engine construction (where every config knob is validated)
-    rejects unknown kv_dtypes and int8/byte-budgets on the slot
-    layout — both only mean something for paged pools."""
+    rejects unknown kv_dtypes; an int8 pool and a byte budget need no
+    other option since the pool is the one layout."""
     with pytest.raises(ValueError, match="kv_dtype"):
         demo_llama_engine(EngineConfig(kv_dtype="fp8"))
-    with pytest.raises(ValueError, match="kv_layout='paged'"):
-        demo_llama_engine(EngineConfig(kv_dtype="int8"))  # slot layout
-    with pytest.raises(ValueError, match="kv_pool_bytes"):
-        demo_llama_engine(EngineConfig(kv_pool_bytes=1 << 20))
+    eng = demo_llama_engine(EngineConfig(max_batch=2, max_seq=64,
+                                         kv_dtype="int8"))
+    assert is_quantized_pool(eng.k_cache)
+    sized = demo_llama_engine(EngineConfig(max_batch=2, max_seq=64,
+                                           kv_pool_bytes=1 << 20))
+    assert 0 < sized._kv_bytes_total <= 1 << 20
+
+
+def test_slot_layout_is_refused_by_name():
+    """``kv_layout`` is accepted only as "paged": the slot layout's
+    removal is named, with what reserves the same capacity."""
+    with pytest.raises(ValueError, match="removed in PR 30") as err:
+        demo_llama_engine(EngineConfig(kv_layout="slot"))
+    assert "kv_pages=None" in str(err.value)
+    assert demo_llama_engine(EngineConfig(
+        max_batch=2, max_seq=64, kv_layout="paged")) is not None
 
 
 def test_int8_view_and_native_paths_agree_exactly():
@@ -476,7 +491,7 @@ def test_int8_view_and_native_paths_agree_exactly():
     int8 native path (pool_write + ragged XLA fallback) see the SAME
     dequantized rows, so greedy outputs must agree token-for-token —
     this pins the two quantized implementations against each other the
-    way the bf16 paths are pinned against the slot engine."""
+    way the bf16 native paths are pinned against the view engine."""
     def run(**extra):
         eng = demo_llama_engine(EngineConfig(
             max_batch=2, max_seq=128, seed=13, kv_layout="paged",
@@ -574,14 +589,23 @@ def test_recovered_pool_stays_quantized():
     assert all(len(r.generated) == 6 for r in reqs)
 
 
-def test_paged_view_decode_windows_match():
-    """Windowed paged-view decode (gathers only the table columns
-    covering the window) must match the unwindowed paged engine
-    greedily across a window boundary."""
+@pytest.mark.parametrize("path", ["view", "xla"])
+def test_decode_windows_match_and_only_the_view_compiles_them(path):
+    """``decode_windows`` is the width of the VIEW path's gather: the
+    windowed view decode (only the table columns covering the window)
+    must match the unwindowed engine greedily across a window boundary.
+    The native path walks live pages only: windows change neither its
+    streams nor the programs it compiles."""
+    shapes = {}
+
     def run(**extra):
         eng = demo_llama_engine(EngineConfig(
-            max_batch=2, max_seq=128, seed=21, kv_layout="paged",
-            page_size=16, **extra))
+            max_batch=2, max_seq=128, seed=21, page_size=16,
+            paged_attention=path, **extra))
+        eng.warmup(prompt_lens=(10,))
+        shapes[bool(extra)] = eng.sentinel.state()["known_shapes"]
+        assert len(eng._decode_by_window) == \
+            (len(extra.get("decode_windows", ())) if path == "view" else 0)
         eng.start()
         reqs = [eng.submit(list(range(2, 12)), SamplingParams(
             temperature=0.0, max_new_tokens=40)) for _ in range(2)]
@@ -594,3 +618,42 @@ def test_paged_view_decode_windows_match():
     want = run()
     got = run(decode_windows=(32, 64))
     assert got == want
+    assert shapes[True] - shapes[False] == (2 if path == "view" else 0)
+
+
+def test_default_pool_reserves_every_slots_full_length():
+    """``EngineConfig()`` is the page pool, and left alone
+    (``kv_pages=None``) it reserves ``max_batch x ceil(max_seq /
+    page_size)`` pages: what the removed slot layout reserved."""
+    default = EngineConfig()
+    assert default.kv_layout == "paged" and default.kv_pages is None
+    eng = demo_llama_engine()           # max_batch 4, max_seq 128
+    assert eng.paged_attention_impl == "view"     # "auto" off the TPU
+    assert eng._n_pages == 4 * -(-128 // default.page_size) == 8
+    odd = demo_llama_engine(EngineConfig(max_batch=3, max_seq=72,
+                                         page_size=16))
+    assert odd._n_pages == 3 * 5 and odd._pages_per_slot == 5
+
+
+@pytest.mark.parametrize("path", ["view", "xla"])
+def test_default_pool_runs_every_slot_to_max_seq_unpreempted(path):
+    """The slot layout's capacity guarantee, kept: with the default
+    pool every slot can fill its ``max_seq`` rows at once and nothing
+    is preempted, requeued or refused."""
+    eng = demo_llama_engine(EngineConfig(
+        max_batch=3, max_seq=72, page_size=16, seed=2,
+        prefill_buckets=(16,), paged_attention=path))
+    eng.start()
+    reqs = [eng.submit([7 + i, 3, 1, 4, 1, 5], SamplingParams(
+        temperature=0.0, max_new_tokens=500)) for i in range(3)]
+    _drain(reqs)
+    stats = dict(eng.stats)
+    peak = eng.efficiency_state()["watermarks"]["kv_pages"]["value"]
+    eng.stop()
+    assert all(r.error is None for r in reqs), [r.error for r in reqs]
+    # a slot ends when its 72 rows are full (the last token sampled
+    # needs no row of its own)
+    assert [len(r.prompt_tokens) + len(r.generated) for r in reqs] \
+        == [73, 73, 73]
+    assert stats["preemptions"] == 0
+    assert peak == eng._n_pages == 15

@@ -201,10 +201,11 @@ def test_native_paged_hot_paths_never_gather_view(monkeypatch):
     assert got == want
 
 
-def test_native_chunk_walk_matches_slot_layout():
+def test_native_chunk_walk_matches_the_view_engine():
     """Long prompt through the native chunk walk (interpret kernel)
-    reproduces the slot layout's greedy stream — the same contract the
-    view path holds."""
+    reproduces the view engine's greedy stream. The view engine is the
+    reference: dense step functions on a gathered view, no kernel, no
+    table writes by the model."""
     native = demo_llama_engine(EngineConfig(
         max_batch=2, max_seq=128, prefill_buckets=(8,), seed=7,
         kv_layout="paged", page_size=16, paged_attention="interpret"))
@@ -215,12 +216,13 @@ def test_native_chunk_walk_matches_slot_layout():
     native.stop()
     assert got.error is None and len(got.prompt_tokens) == len(PROMPT)
 
-    slot = demo_llama_engine(EngineConfig(
-        max_batch=2, max_seq=128, prefill_buckets=(8,), seed=7))
-    slot.start()
-    want = slot.submit_sync(PROMPT, SamplingParams(
+    view = demo_llama_engine(EngineConfig(
+        max_batch=2, max_seq=128, prefill_buckets=(8,), seed=7,
+        page_size=16, paged_attention="view"))
+    view.start()
+    want = view.submit_sync(PROMPT, SamplingParams(
         temperature=0.0, max_new_tokens=6))
-    slot.stop()
+    view.stop()
     assert got.generated == want.generated
 
 

@@ -115,8 +115,10 @@ def test_engine_quantize_rejects_unknown():
 def test_int8_composes_with_native_paged_kernel():
     """int8 weights + the native paged decode path (row writes through
     the block table, ragged kernel in interpret mode) must match the
-    int8 slot-layout engine greedily — protects the best-known TPU
-    serving composition (paged kernel + int8)."""
+    int8-weight view engine greedily (the reference: dense step
+    functions on a gathered view, no kernel, no table writes by the
+    model) — protects the best-known TPU serving composition (paged
+    kernel + int8)."""
     import time
 
     from gofr_tpu.serving.engine import EngineConfig, SamplingParams
@@ -142,9 +144,8 @@ def test_int8_composes_with_native_paged_kernel():
         assert all(len(r.generated) == 8 for r in reqs)  # really finished
         return [r.generated for r in reqs]
 
-    want = run()
-    got = run(kv_layout="paged", page_size=16,
-              paged_attention="interpret")
+    want = run(page_size=16, paged_attention="view")
+    got = run(page_size=16, paged_attention="interpret")
     assert got == want
 
 

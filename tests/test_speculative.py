@@ -39,11 +39,30 @@ def test_greedy_tokens_identical_to_vanilla():
     assert stats["spec_passes"] > 0
 
 
-def test_paged_layout_matches_too():
-    base = _cfg(kv_layout="paged", page_size=16)
-    vanilla, _ = _run(demo_llama_engine(base), PATTERN)
+@pytest.mark.parametrize("adaptive", [False, True])
+def test_pipelined_speculation_drafts_from_the_settled_stream(adaptive):
+    """With a decode pass left in flight (``pipeline_depth=1``) the
+    drafting loop must settle it before it reads a stream's tail: a
+    tree rooted at the token BEFORE the in-flight pass's verified a
+    stale root and the stream left the greedy one (every engine did,
+    sharded or not, until PR 30)."""
+    cfg = dict(spec_ngram=1, decode_steps_per_pass=2, page_size=16)
+    vanilla, _ = _run(demo_llama_engine(_cfg(**cfg)), PATTERN)
+    spec, stats = _run(demo_llama_engine(_cfg(
+        speculative=True, spec_adaptive=adaptive, pipeline_depth=1,
+        **cfg)), PATTERN)
+    assert spec == vanilla
+    assert stats["spec_passes"] > 0
+
+
+def test_native_path_matches_too():
+    """Native tree verify (fed nodes written through the tables, raw
+    pool rows compacted) against plain decode on the view engine — the
+    reference: dense step functions, no table writes by the model."""
+    vanilla, _ = _run(demo_llama_engine(
+        _cfg(page_size=16, paged_attention="view")), PATTERN)
     spec, stats = _run(
-        demo_llama_engine(_cfg(kv_layout="paged", page_size=16,
+        demo_llama_engine(_cfg(page_size=16, paged_attention="xla",
                                speculative=True)), PATTERN)
     assert spec == vanilla
     assert stats["spec_passes"] > 0
