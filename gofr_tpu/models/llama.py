@@ -282,32 +282,33 @@ def llama_decode_step_paged(params: dict, tokens: jnp.ndarray,
 
     Unlike the engine's generic paged path (gather a dense view, run
     :func:`llama_decode_step`, scatter back — O(full cache) extra HBM
-    traffic per pass), this writes each new K/V row through the block
-    table and attends with the ragged paged kernel
-    (:func:`..ops.paged_attention.paged_decode_attention`), so the pool
-    is only ever touched in place. pools [L, Hkv, Np, pg, hd]
+    traffic per pass), this hands each new K/V row and the block table
+    to the decode walk, which writes the row and attends
+    (:func:`..ops.paged_attention.paged_decode_append_attention`), so
+    the pool is only ever touched in place. pools [L, Hkv, Np, pg, hd]
     (head-major — see ops/paged_kv.py); tables [B, Mp]; lengths [B] =
     rows already cached (the new token lands at that position).
     Returns (logits [B, V], new_k_pool, new_v_pool). Quantized pools
     (the ``{"q", "s"}`` pytree from ops/paged_kv.py) ride the same
-    scan: writes quantize inside :func:`..ops.paged_kv.pool_write` and
-    the ragged kernel dequantizes per page.
+    scan: their rows quantize inside :func:`..ops.paged_kv.pool_write`
+    in front of the walk, which dequantizes per page.
     """
-    from ..ops.paged_attention import paged_decode_attention
-    from ..ops.paged_kv import pool_write
+    from ..ops.paged_attention import paged_decode_append_attention
     c = config
     b = tokens.shape[0]
     hd = c.head_dim
     inv_freq = rope_frequencies(c.head_dim, c.rope_theta, c.rope_scaling)
     positions = lengths[:, None]
-    one = jnp.ones_like(lengths)
     x = qgather(params["embed"], tokens, c.dtype)[:, None, :]  # [B, 1, D]
 
     # pools ride the scan CARRY (see llama_decode_step) and never leave
-    # it: the fresh row lands at position ``lengths`` through the table
-    # (rows at or past the allocation drop), by whole pages, and the
-    # kernel reads the whole pool at ``li`` — a layer's slice taken out
-    # of the carry is a copy per layer-step (ops/paged_kv.pool_write)
+    # it: the kernel takes the whole pool at ``li`` — a layer's slice
+    # taken out of the carry is a copy per layer-step — aliased in and
+    # out, and lays a live slot's fresh row at position ``lengths``
+    # through the table from inside its walk (a tail page the table
+    # does not hold drops). ``pool_write`` in front of the walk moved
+    # four page sets of all the compiled slots a layer-step to store
+    # one row each (ops/paged_kv.py, "Decode's one row")
     def layer_fn(carry, scanned):
         x, kp_all, vp_all = carry     # [L, Hkv, Np, pg, hd]
         lp, li = scanned
@@ -317,11 +318,9 @@ def llama_decode_step_paged(params: dict, tokens: jnp.ndarray,
         v = qmatmul(h, lp["wv"]).reshape(b, 1, c.n_kv_heads, hd)
         q = apply_rope(q, positions, inv_freq)
         k = apply_rope(k, positions, inv_freq)
-        kp_all = pool_write(kp_all, li, tables, lengths, one, k)
-        vp_all = pool_write(vp_all, li, tables, lengths, one, v)
-        out = paged_decode_attention(q[:, 0], kp_all, vp_all, tables,
-                                     lengths + 1, layer=li,
-                                     implementation=implementation)
+        out, kp_all, vp_all = paged_decode_append_attention(
+            q[:, 0], k[:, 0], v[:, 0], kp_all, vp_all, tables, lengths + 1,
+            layer=li, implementation=implementation)
         x = x + qmatmul(out.reshape(b, 1, c.n_heads * hd), lp["wo"])
         x = x + _mlp_block(x, lp, c)
         return (x, kp_all, vp_all), None

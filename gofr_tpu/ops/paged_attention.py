@@ -30,7 +30,11 @@ One algorithm, two ``pallas_call``s that share the fold
 - the *decode* walk (:func:`_decode_kernel`, ``paged_decode_attention``)
   serves the chunk of ONE row — ``history = length - 1`` — where the
   price is the walk itself: a cell a slot, every head group of a page
-  in one fold (see "the decode walk" below).
+  in one fold (see "the decode walk" below). It is also decode's
+  WRITER (``paged_decode_append_attention``, what the dense model step
+  calls): the step's fresh row of a live slot is laid over the last
+  fold's buffer and the tile-aligned block that holds it goes back to
+  the pool, aliased in and out ("the walk writes" below).
 
 What the TPU's compiler takes (established by ahead-of-time compiles
 for v5e — ``tests/test_tpu_compile.py`` keeps them):
@@ -72,6 +76,14 @@ for v5e — ``tests/test_tpu_compile.py`` keeps them):
   (32 rows: a bf16 tile is 16). What it refused: a fold of 8 MiB —
   two of them are 16.19 MiB of scoped VMEM against the 16 MiB a
   kernel gets unasked; folds of 1, 2 and 4 MiB compile.
+- What the walk's write asked, and got at the first compile: the pool
+  as an aliased operand (``input_output_aliases``) inside the model's
+  layer scan with no copy of it; in one cell, a page read from the
+  pool by one DMA and a block of it written back by another
+  (``buf.at[half, :, pl.ds(r, 16), :]`` to ``pool.at[layer, :, pid,
+  pl.ds(r0, 16), :]``, both offsets dynamic multiples of the block). A
+  ONE-row DMA is refused ("Slice shape along dimension 3 must be
+  aligned to tiling (8), but is 1"): the write is a block's.
 - What the chip said (PERF.md section 6, PR 29): a fold of the q-block
   walk costs 0.43 us and a cell 0.62 us WHATEVER they hold, because a
   fold is a chain (matmul -> row max -> exp -> row sum -> matmul ->
@@ -118,7 +130,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from .attention import is_tpu
-from .paged_kv import LANES, gather_view
+from .paged_kv import LANES, gather_view, pool_write
 
 NEG_INF = -1e30
 
@@ -553,6 +565,21 @@ def _ragged_attention(q, k_pool, v_pool, tables, history_lens, chunk_lens,
 # without rows costs a grid step and a zero store, and K and V enter
 # the MXU as stored (:func:`_mxu_dot`).
 
+#
+# The walk writes. The step's fresh K/V row of a slot belongs at row
+# ``length - 1``: the last row of the last page of the slot's last fold,
+# a page the walk has in VMEM anyway. Written in FRONT of the walk by
+# whole pages (ops/paged_kv.pool_write) one row a slot cost four page
+# sets of every compiled slot a layer-step — three times the walk
+# (PERF.md section 6, PR 31). So for a plain pool the pools are aliased
+# in and out of the call, the fresh rows ride in packed as the pool
+# packs them, and a LIVE slot's cell lays its row over the last fold's
+# buffer after that fold's wait, attends as ever, and copies the
+# tile-aligned block that holds the row back to its page, waited on
+# before the cell ends. A slot that is not live writes nothing; a tail
+# page the table does not hold drops the row — what ``pool_write`` did
+# to both.
+
 #: bytes of K and V one fold of the decode walk brings in; two folds
 #: are resident (the double buffer)
 FOLD_BYTES = 2 << 20
@@ -583,16 +610,21 @@ def _has_rows(length, first_page, capacity: int, n_pages: int):
     return (length > 0) & (length <= capacity) & (first_page < n_pages)
 
 
-def _decode_kernel(tables_ref, lengths_ref, layer_ref, q_ref, k_hbm, v_hbm,
-                   *refs, page: int, fold_pages: int, n_pages: int,
-                   scale: float, pack: int, quantized: bool):
-    ks_hbm = vs_hbm = ks_buf = vs_buf = None
-    if quantized:
-        (ks_hbm, vs_hbm, o_ref, k_buf, v_buf, ks_buf, vs_buf, acc_ref,
-         m_ref, l_ref, sems, next_ref, half_ref) = refs
+def _decode_kernel(tables_ref, lengths_ref, layer_ref, q_ref, *refs,
+                   page: int, fold_pages: int, n_pages: int, scale: float,
+                   pack: int, quantized: bool, write_rows: int):
+    ks_hbm = vs_hbm = ks_buf = vs_buf = kn_ref = vn_ref = wsems = None
+    if write_rows:
+        # the pools are aliased in and out: ONE buffer a side, read and
+        # written through the output's ref (the input's is not touched)
+        (kn_ref, vn_ref, _, _, o_ref, k_hbm, v_hbm, k_buf, v_buf, acc_ref,
+         m_ref, l_ref, sems, next_ref, half_ref, wsems) = refs
+    elif quantized:
+        (k_hbm, v_hbm, ks_hbm, vs_hbm, o_ref, k_buf, v_buf, ks_buf, vs_buf,
+         acc_ref, m_ref, l_ref, sems, next_ref, half_ref) = refs
     else:
-        (o_ref, k_buf, v_buf, acc_ref, m_ref, l_ref, sems, next_ref,
-         half_ref) = refs
+        (k_hbm, v_hbm, o_ref, k_buf, v_buf, acc_ref, m_ref, l_ref, sems,
+         next_ref, half_ref) = refs
     li = layer_ref[0]
     b = pl.program_id(0)
     n_slots, max_pages = tables_ref.shape
@@ -672,6 +704,28 @@ def _decode_kernel(tables_ref, lengths_ref, layer_ref, q_ref, k_hbm, v_hbm,
         # kv head, then its (padded) GQA query heads
         row_head = jax.lax.broadcasted_iota(
             jnp.int32, (1, rows, 1), 1) // (rows // pack)
+        if write_rows:
+            # the step's fresh row is the slot's last, in the last page
+            # of its last fold: it is laid over that fold's buffer and
+            # the tile-aligned block that holds it (rows ``at`` of the
+            # buffer, ``at % page`` of the page) goes back to the pool. A
+            # tail page the table does not hold drops the row, as
+            # ``pool_write`` drops it
+            tail = length - 1
+            tail_pid = tables_ref[b, tail // page]
+            writes = tail_pid < n_pages
+            at = pl.multiple_of(
+                tail % chunk // write_rows * write_rows, write_rows)
+
+            def write_back(half):
+                return [pltpu.make_async_copy(
+                    buf.at[half, :, pl.ds(at, write_rows), :],
+                    pool.at[li, :, tail_pid,
+                            pl.ds(pl.multiple_of(at % page, write_rows),
+                                  write_rows), :],
+                    wsems.at[side])
+                    for side, (buf, pool) in enumerate(
+                        ((k_buf, k_hbm), (v_buf, v_hbm)))]
 
         def body(ci, _):
             half = jax.lax.rem(first_half + ci, 2)
@@ -685,6 +739,17 @@ def _decode_kernel(tables_ref, lengths_ref, layer_ref, q_ref, k_hbm, v_hbm,
                 fold_dmas(next_slot, jnp.where(last, 0, ci + 1), 1 - half)
 
             fold_dmas(b, ci, half, wait=True)
+            if write_rows:
+                @pl.when(last & writes)
+                def _():
+                    here = jax.lax.broadcasted_iota(
+                        jnp.int32, (1, write_rows, 1), 1) == tail % chunk - at
+                    for buf, new in ((k_buf, kn_ref), (v_buf, vn_ref)):
+                        block = buf.at[half, :, pl.ds(at, write_rows), :]
+                        block[...] = jnp.where(here, new[0], block[...])
+                    for dma in write_back(half):
+                        dma.start()
+
             s = _mxu_dot(q, k_buf[half], 2) * scale     # [Hg, rows, chunk]
             if quantized:
                 s = s * _row_scales(
@@ -707,13 +772,34 @@ def _decode_kernel(tables_ref, lengths_ref, layer_ref, q_ref, k_hbm, v_hbm,
         jax.lax.fori_loop(0, n_folds, body, 0)
         half_ref[0] = jax.lax.rem(first_half + n_folds, 2)
         o_ref[0] = (acc_ref[...] / l_ref[...]).astype(o_ref.dtype)
+        if write_rows:
+            # the next cell's first fetch goes into the half the block
+            # leaves from: it has left before the cell ends
+            @pl.when(writes)
+            def _():
+                for dma in write_back(1 - half_ref[0]):
+                    dma.wait()
+
+
+def _write_block_rows(page: int, itemsize: int) -> int:
+    """Rows of the block in which the decode walk hands a fresh row
+    back to the pool: one tile of the pool's dtype along the sublanes
+    (8 rows of 32 bits; 16 of bf16, two to a sublane) where tiles
+    divide the page, else the 8 rows every page is a multiple of."""
+    tile = SUBLANE * max(1, 4 // itemsize)
+    return SUBLANE if page % tile else tile
 
 
 def _decode_walk(q, k_pool, v_pool, tables, lengths, *, layer, scale,
-                 interpret):
+                 interpret, new_rows=None):
     """The pallas_call behind decode. q [B, Hq, hd]; pools and ``layer``
-    as :func:`_ragged_attention` takes them."""
-    if layer is None:
+    as :func:`_ragged_attention` takes them. With ``new_rows`` — the
+    step's fresh ``(k, v)``, each [B, Hkv, hd], for a plain pool — the
+    walk also WRITES: the pools are aliased in and out, a live slot's
+    row lands at position ``length - 1``, and the result is ``(out,
+    k_pool, v_pool)``."""
+    one_layer = layer is None
+    if one_layer:
         k_pool, v_pool = jax.tree.map(lambda x: x[None], (k_pool, v_pool))
         layer = 0
     k_codes, k_scales = _split_pool(k_pool)
@@ -731,19 +817,40 @@ def _decode_walk(q, k_pool, v_pool, tables, lengths, *, layer, scale,
     chunk = fold_pages * page
     q4, gp = _group_rows(q[:, None], hg, pack, 1)       # [B, Hg, rows, W]
     rows = pack * gp
+    write_rows = 0 if new_rows is None else \
+        _write_block_rows(page, k_codes.dtype.itemsize)
     kernel = functools.partial(
         _decode_kernel, page=page, fold_pages=fold_pages, n_pages=n_pages,
-        scale=scale, pack=pack, quantized=quantized)
+        scale=scale, pack=pack, quantized=quantized, write_rows=write_rows)
     q_spec = pl.BlockSpec((1, hg, rows, width), lambda i, *_: (i, 0, 0, 0),
                           memory_space=pltpu.VMEM)
     in_hbm = pl.BlockSpec(memory_space=pl.ANY)  # pools stay in HBM
     scale_bufs = [pltpu.VMEM((2, fold_pages, hg, 1, k_scales.shape[-1]),
                              jnp.float32)] * 2 if quantized else []
+    args = [tables.astype(jnp.int32), lengths.astype(jnp.int32),
+            jnp.asarray(layer, jnp.int32).reshape(1), q4]
+    in_specs, out_specs = [q_spec], q_spec
+    out_shape = jax.ShapeDtypeStruct((b, hg, rows, width), q.dtype)
+    aliases = {}
+    if write_rows:
+        # the fresh rows packed as the pool packs them, a slot a block
+        row_spec = pl.BlockSpec((1, hg, 1, width),
+                                lambda i, *_: (i, 0, 0, 0),
+                                memory_space=pltpu.VMEM)
+        args += [x.astype(k_codes.dtype).reshape(b, hg, 1, width)
+                 for x in new_rows]
+        in_specs += [row_spec, row_spec]
+        aliases = {len(args): 1, len(args) + 1: 2}
+        out_specs = [q_spec, in_hbm, in_hbm]
+        out_shape = [out_shape, k_codes, v_codes]
+    args += [k_codes, v_codes]
+    if quantized:
+        args += [k_scales, v_scales]
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,
         grid=(b,),
-        in_specs=[q_spec] + [in_hbm] * (4 if quantized else 2),
-        out_specs=q_spec,
+        in_specs=in_specs + [in_hbm] * (4 if quantized else 2),
+        out_specs=out_specs,
         scratch_shapes=[
             pltpu.VMEM((2, hg, chunk, width), k_codes.dtype),
             pltpu.VMEM((2, hg, chunk, width), v_codes.dtype),
@@ -755,22 +862,26 @@ def _decode_walk(q, k_pool, v_pool, tables, lengths, *, layer, scale,
                                      fold_pages)),
             pltpu.SMEM((b,), jnp.int32),    # each live slot's successor
             pltpu.SMEM((1,), jnp.int32),    # buffer half of the next fold
+            # the block's write-back, K and V
+            *([pltpu.SemaphoreType.DMA((2,))] if write_rows else []),
         ],
     )
-    args = [tables.astype(jnp.int32), lengths.astype(jnp.int32),
-            jnp.asarray(layer, jnp.int32).reshape(1), q4, k_codes, v_codes]
-    if quantized:
-        args += [k_scales, v_scales]
     out = pl.pallas_call(
         kernel,
-        out_shape=jax.ShapeDtypeStruct((b, hg, rows, width), q.dtype),
+        out_shape=out_shape,
         grid_spec=grid_spec,
+        input_output_aliases=aliases,
         # a cell starts the next live cell's first fold: in order
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=interpret,
     )(*args)
-    return _ungroup_rows(out, 1, hq, hd, pack, gp)[:, 0]
+    if not write_rows:
+        return _ungroup_rows(out, 1, hq, hd, pack, gp)[:, 0]
+    out, *pools = out
+    if one_layer:
+        pools = [x[0] for x in pools]
+    return (_ungroup_rows(out, 1, hq, hd, pack, gp)[:, 0], *pools)
 
 
 def paged_chunk_attention_pallas(q: jnp.ndarray, k_pool,
@@ -809,6 +920,48 @@ def paged_decode_attention_pallas(q: jnp.ndarray, k_pool,
     zeros."""
     return _decode_walk(q, k_pool, v_pool, tables, lengths, layer=layer,
                         scale=scale, interpret=interpret)
+
+
+def _append_rows(k_pool, v_pool, k_new, v_new, tables, lengths, layer):
+    """:func:`..paged_kv.pool_write` of a decode step's fresh rows
+    [B, Hkv, hd], at position ``lengths - 1`` of the slots that hold
+    rows (:func:`_has_rows`: the others drop there anyway — an empty
+    slot's table is all unallocated, ``max_seq`` is past the table)."""
+    pools = (k_pool, v_pool)
+    if layer is None:
+        pools = jax.tree.map(lambda x: x[None], pools)
+    n_pages, page = _split_pool(pools[0])[0].shape[2:4]
+    counts = _has_rows(lengths, tables[:, 0], tables.shape[1] * page,
+                       n_pages).astype(jnp.int32)
+    pools = tuple(
+        pool_write(pool, 0 if layer is None else layer, tables, lengths - 1,
+                   counts, rows[:, None])
+        for pool, rows in zip(pools, (k_new, v_new)))
+    return jax.tree.map(lambda x: x[0], pools) if layer is None else pools
+
+
+def paged_decode_append_attention_pallas(q: jnp.ndarray, k_new: jnp.ndarray,
+                                         v_new: jnp.ndarray, k_pool, v_pool,
+                                         tables: jnp.ndarray,
+                                         lengths: jnp.ndarray, *,
+                                         layer=None,
+                                         scale: float | None = None,
+                                         interpret: bool = False):
+    """Decode with the step's write in it: ``k_new`` / ``v_new``
+    [B, Hkv, hd] are the fresh rows of the one new position a slot,
+    ``lengths`` the valid rows AFTER they land (at ``lengths - 1``).
+    Returns ``(out, k_pool, v_pool)``. A plain pool is written inside
+    the walk (:func:`_decode_walk`); a quantized pool keeps
+    write-then-walk — its scale leaf is a lane-major row a page with a
+    layout of its own, and no deployment measured here runs one."""
+    if _split_pool(k_pool)[1] is None:
+        return _decode_walk(q, k_pool, v_pool, tables, lengths, layer=layer,
+                            scale=scale, interpret=interpret,
+                            new_rows=(k_new, v_new))
+    k_pool, v_pool = _append_rows(k_pool, v_pool, k_new, v_new, tables,
+                                  lengths, layer)
+    return _decode_walk(q, k_pool, v_pool, tables, lengths, layer=layer,
+                        scale=scale, interpret=interpret), k_pool, v_pool
 
 
 def paged_tree_attention_pallas(q: jnp.ndarray, k_pool,
@@ -868,6 +1021,20 @@ def paged_decode_attention_xla(q: jnp.ndarray, k_pool,
     n_pages, page = _split_pool(k_pool)[0].shape[-3:-1]
     live = _has_rows(lengths, tables[:, 0], tables.shape[1] * page, n_pages)
     return jnp.where(live[:, None, None], out, jnp.zeros_like(out))
+
+
+def paged_decode_append_attention_xla(q: jnp.ndarray, k_new: jnp.ndarray,
+                                      v_new: jnp.ndarray, k_pool, v_pool,
+                                      tables: jnp.ndarray,
+                                      lengths: jnp.ndarray, *, layer=None,
+                                      scale: float | None = None):
+    """Reference path: the page-granular write, then the gather
+    reference — what the walk's own write is held to, byte for byte."""
+    k_pool, v_pool = _append_rows(k_pool, v_pool, k_new, v_new, tables,
+                                  lengths, layer)
+    return paged_decode_attention_xla(
+        q, k_pool, v_pool, tables, lengths, layer=layer,
+        scale=scale), k_pool, v_pool
 
 
 def paged_chunk_attention_xla(q: jnp.ndarray, k_pool,
@@ -958,3 +1125,15 @@ def paged_decode_attention(q: jnp.ndarray, k_pool,
     return _dispatch(implementation, paged_decode_attention_pallas,
                      paged_decode_attention_xla, q, k_pool, v_pool, tables,
                      lengths, layer=layer, scale=scale)
+
+
+def paged_decode_append_attention(q: jnp.ndarray, k_new: jnp.ndarray,
+                                  v_new: jnp.ndarray, k_pool, v_pool,
+                                  tables: jnp.ndarray,
+                                  lengths: jnp.ndarray, *, layer=None,
+                                  scale: float | None = None,
+                                  implementation: str = "auto"):
+    return _dispatch(implementation, paged_decode_append_attention_pallas,
+                     paged_decode_append_attention_xla, q, k_new, v_new,
+                     k_pool, v_pool, tables, lengths, layer=layer,
+                     scale=scale)

@@ -46,8 +46,12 @@ Everything here is a pure jittable function on static shapes:
   view once per K-step decode pass (NOT per token) — the engine then
   runs the model family's ordinary dense decode step on the view, so
   paged mode needs zero model changes.
-- :func:`pool_write` is the one writer: each slot's contiguous run of
-  new positions goes through its table by WHOLE PAGES (below).
+- :func:`pool_write` is the writer of XLA's side: each slot's
+  contiguous run of new positions goes through its table by WHOLE
+  PAGES (below) — prompt runs, tree-verify nodes, the rows of a
+  one-vector family and of a quantized pool. The ONE row a dense
+  decode step appends to a plain pool is written by the decode walk
+  itself ("Decode's one row", below).
   :func:`scatter_prefill` / :func:`scatter_chunk` /
   :func:`scatter_decode` are its all-layer entries for prompt slabs
   and the view path's freshly decoded rows. A table's unallocated
@@ -80,6 +84,24 @@ pages back — and the kernel takes the whole pool and a layer index
 (``ops/paged_attention.py``). The pool keeps one layout from program
 entry to exit, with no copy of it and no temp of its size
 (``tests/test_tpu_compile.py`` holds the compiled programs to that).
+
+Decode's one row
+----------------
+A page-granular write prices a run by the pages it touches, and a
+decode step's run is one row: ``pool_write`` gathered the 64-row tail
+page of every COMPILED slot on both sides, laid a row over each and
+scattered the pages back — four page sets a layer-step (4 x 8.4 MB at
+SmolLM2-1.7B's widths and 32 slots) to store 32 x 2 x 4 KiB, live slot
+or not: 56-80 us of a 329 us layer-step in the chat cell, three times
+the decode walk it fed (PERF.md section 6, PR 31). A narrower XLA
+write is the token-major relayout again; a Pallas operand's layout is
+fixed. So the dense decode step hands its fresh rows to
+``ops/paged_attention.paged_decode_append_attention`` and the walk —
+which already holds a live slot's tail page in VMEM, in its last fold
+— lays the row over it and copies the tile-aligned block that holds
+it back to the pool, aliased in and out of the kernel. The stored
+bytes are what ``pool_write`` stores. This routine stays that entry's
+reference (its ``xla`` path) and the writer of everything else.
 
 Quantized pools
 ---------------
@@ -235,13 +257,18 @@ def _scale_lanes(offs: jnp.ndarray, pack: int, pg: int) -> jnp.ndarray:
 
 
 def pool_write(pool, layer, tables, starts, counts, rows):
-    """The one pool writer: slot b's ``rows[..., b, :counts[b]]`` land
+    """XLA's pool writer: slot b's ``rows[..., b, :counts[b]]`` land
     at logical positions ``[starts[b], starts[b] + counts[b])`` of its
     table, by WHOLE PAGES (module docstring: written by rows, XLA lays
     the pool out token-major and copies it for the kernel in every
-    program and layer-step). ``rows`` is the model's token-major K or V:
+    program and layer-step). Still the writer of runs (bucket and chunk
+    prefill, tree verify, the view path), of one-vector families and
+    of quantized pools; a dense decode step's single row into a plain
+    pool goes through the decode walk instead — here it cost four page
+    sets of every compiled slot ("Decode's one row"). ``rows`` is the
+    model's token-major K or V:
     ``[B, S, Hkv, hd]`` for one ``layer`` (a traced index — what the
-    model families call inside their layer scan; decode is S = 1) or
+    model families call inside their layer scan; S = 1 is one row) or
     ``[L, B, S, Hkv, hd]`` with ``layer=None`` (all layers: the
     ``scatter_*`` entries below). Packs heads into lanes, quantizes on
     write for quantized pools; plain pools absorb the dtype cast here

@@ -243,7 +243,8 @@ def test_engine_construction_rejects_untakeable_kernel_shape():
 
 from gofr_tpu.ops.paged_attention import (  # noqa: E402
     paged_chunk_attention_pallas, paged_chunk_attention_xla,
-    paged_tree_attention_pallas, paged_tree_attention_xla)
+    paged_decode_append_attention_pallas, paged_tree_attention_pallas,
+    paged_tree_attention_xla)
 from gofr_tpu.ops.paged_kv import pack_pool, quantize_pool  # noqa: E402
 
 
@@ -427,6 +428,138 @@ def test_decode_fold_is_sized_from_what_the_kernel_sees():
     assert _fold_pages(2, 24, 128, 2, 64) == 5        # a page off the lanes
 
 
+# ------------------------------------------- the walk writes the row
+#
+# The model's decode step hands the step's fresh K/V rows to the walk
+# (``paged_decode_append_attention``): a plain pool is written INSIDE
+# the walk — the row laid over the last fold's buffer, the tile-aligned
+# block that holds it copied back — and an int8 pool in front of it, by
+# ``pool_write`` as before; the pool's type selects. Both are held to
+# the page-granular write + the gather reference: pools equal bit for
+# bit, every byte outside the written rows untouched. Slots are
+# ``length after the write`` or ``(length, pages held)``: ``False``
+# none, ``True`` all it needs, a number that many.
+
+APPEND_LENGTHS = {
+    # a page's first and last position, the first page and the second
+    "page-edges": lambda blk, fold, cap: (
+        1, WALK_PAGE, WALK_PAGE + 1, 2 * WALK_PAGE, (3, False), 2),
+    # either side of the write-back block's boundary
+    "block-edges": lambda blk, fold, cap: (
+        blk, blk + 1, WALK_PAGE + blk, WALK_PAGE + blk + 1, 2 * blk,
+        (9, False)),
+    # the row closes a fold, opens the next one
+    "fold-edges": lambda blk, fold, cap: (
+        fold, fold + 1, 2 * fold, 2 * fold + 1, 3 * fold + 1, (1, False)),
+    # a table used to its last page and row; a slot mid-prefill
+    # carries max_seq and lands past the table
+    "last-page": lambda blk, fold, cap: (
+        cap, (cap + 1, True), cap - WALK_PAGE + 1, (2, False), cap - 1,
+        (7, False)),
+    # the tail page is not in the table: the row drops, the walk attends
+    "tail-unallocated": lambda blk, fold, cap: (
+        (WALK_PAGE + 1, 1), 70, (2 * WALK_PAGE + 5, 2), (1, False),
+        (fold + 1, fold // WALK_PAGE), 130),
+    "interleaved": lambda blk, fold, cap: (
+        (5, False), 700, (1, False), 66, (cap + 1, True), 17),
+    "nobody-live": lambda blk, fold, cap: (
+        (1, False), (2, False), (cap + 1, True), (cap + 5, True),
+        (3, False), (8, False)),
+}
+APPEND_LAYER = 1        # of two: the other layer's bytes stay
+
+
+_append_kernel = jax.jit(lambda *a: paged_decode_append_attention_pallas(
+    *a, layer=APPEND_LAYER, interpret=True))
+
+
+@jax.jit
+def _append_reference(q, k_new, v_new, k_pool, v_pool, tables, lens):
+    """``pool_write`` of every slot's row, then the gather reference
+    (a bf16 pool attended as float32: it attends a bf16 view in bf16)."""
+    from gofr_tpu.ops.paged_kv import is_quantized_pool, pool_write
+    one = jnp.ones_like(lens)
+    pools = tuple(pool_write(pool, APPEND_LAYER, tables, lens - 1, one,
+                             rows[:, None])
+                  for pool, rows in ((k_pool, k_new), (v_pool, v_new)))
+    seen = pools if is_quantized_pool(k_pool) else \
+        tuple(x.astype(jnp.float32) for x in pools)
+    return (paged_decode_attention_xla(q, *seen, tables, lens,
+                                       layer=APPEND_LAYER), *pools)
+
+
+@pytest.mark.parametrize("quantized", [False, True], ids=["bf16", "int8"])
+@pytest.mark.parametrize("geometry", sorted(WALK_GEOMETRIES))
+@pytest.mark.parametrize("traffic", sorted(APPEND_LENGTHS))
+def test_decode_walk_writes_the_fresh_row(traffic, geometry, quantized):
+    from gofr_tpu.ops.paged_attention import (_fold_pages,
+                                              _write_block_rows)
+    from gofr_tpu.ops.paged_kv import head_pack
+    g = WALK_GEOMETRIES[geometry]
+    hq, hkv, hd = g["hq"], g["hkv"], g["hd"]
+    pack = head_pack(hkv, hd)
+    itemsize = 1 if quantized else 2
+    fold_pages = _fold_pages(hkv // pack, WALK_PAGE, pack * hd, itemsize,
+                             10 ** 6)
+    max_pages = 3 * fold_pages + 2          # holds three folds + 1
+    fold, cap = fold_pages * WALK_PAGE, max_pages * WALK_PAGE
+    blk = _write_block_rows(WALK_PAGE, itemsize)
+    assert blk in (16, 32) and WALK_PAGE % blk == 0
+    spec = [x if isinstance(x, tuple) else (x, True)
+            for x in APPEND_LENGTHS[traffic](blk, fold, cap)]
+    assert len(spec) == WALK_SLOTS
+    held = [min(-(-n // WALK_PAGE), max_pages) if pages is True
+            else int(pages) for n, pages in spec]
+    n_pages = sum(held) + 3
+    rng = np.random.default_rng(len(traffic) + hd + quantized)
+    # the last page is nobody's: an unallocated entry reads it clamped
+    order = rng.permutation(n_pages - 1)
+    tables = np.full((WALK_SLOTS, max_pages), n_pages, np.int32)
+    at = 0
+    for i, need in enumerate(held):
+        tables[i, :need] = order[at:at + need]
+        at += need
+    ks = jax.random.split(jax.random.key(hd + len(traffic)), 5)
+    kp, vp = (pack_pool(jax.random.normal(
+        k, (2, hkv, n_pages, WALK_PAGE, hd), jnp.float32)
+        .astype(jnp.bfloat16)) for k in ks[:2])
+    if quantized:
+        kp, vp = (quantize_pool(x, head_dim=hd) for x in (kp, vp))
+    k_new, v_new = (jax.random.normal(k, (WALK_SLOTS, hkv, hd), jnp.float32)
+                    .astype(jnp.bfloat16) for k in ks[2:4])
+    lens = jnp.asarray([n for n, _ in spec], jnp.int32)
+    live = np.asarray([bool(h) and 0 < n <= cap
+                       for (n, _), h in zip(spec, held)])
+    # the slots whose row lands: live, and the tail page in the table
+    lands = {i: (int(tables[i, (n - 1) // WALK_PAGE]), (n - 1) % WALK_PAGE)
+             for i, (n, _) in enumerate(spec)
+             if live[i] and (n - 1) // WALK_PAGE < held[i]}
+    tables = jnp.asarray(tables)
+    for dtype, tol in ((jnp.float32, 2e-5), (jnp.bfloat16, 2e-2)):
+        q = jax.random.normal(ks[4], (WALK_SLOTS, hq, hd),
+                              jnp.float32).astype(dtype)
+        got, *got_pools = _append_kernel(q, k_new, v_new, kp, vp, tables,
+                                         lens)
+        want, *want_pools = _append_reference(q, k_new, v_new, kp, vp,
+                                              tables, lens)
+        for a, b in zip(jax.tree.leaves(got_pools),
+                        jax.tree.leaves(want_pools)):
+            assert a.dtype == b.dtype and a.shape == b.shape
+            np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+        got, want = np.asarray(got, np.float32), np.asarray(want, np.float32)
+        assert not np.isnan(got).any()
+        np.testing.assert_allclose(got[live], want[live], rtol=tol, atol=tol)
+        np.testing.assert_array_equal(got[~live], 0.0)
+    # ... and what changed is those rows of that layer, nothing else
+    for old, new in zip((kp, vp), got_pools):
+        old, new = (np.asarray(x["q"] if quantized else x, np.float32)
+                    for x in (old, new))
+        changed = {(int(l), int(pid), int(row)) for l, _, pid, row, _ in
+                   np.argwhere(old != new)}
+        assert changed <= {(APPEND_LAYER, *at) for at in lands.values()}
+        assert len(changed) == len(lands)
+
+
 # ---------------------------------------------------- quantized pools
 #
 # int8 KV pages (ops/paged_kv.py: {"q": int8, "s": f32 per-row}). The
@@ -538,14 +671,21 @@ def test_int8_chunk_within_quant_bound_of_f32():
 
 # ------------------------------------------------- engine-level parity
 
-def test_paged_native_engine_matches_view_engine():
-    """The native paged decode path (row writes through the table +
-    ragged kernel in interpret mode) must reproduce the view engine's
-    greedy outputs exactly. The view engine is the reference: the dense
-    step functions on a gathered per-slot view, no kernel, no table
-    writes by the model."""
+@pytest.mark.parametrize("page_size,prompt_len", [
+    (16, 3),
+    # a 24-row page does not tile the lanes, so a fold is 5 pages = 120
+    # rows: the first 8-step pass appends rows 115..122 — across a page
+    # boundary that is a fold boundary too, and a write-back block's
+    (24, 115)], ids=["short", "across-a-fold"])
+def test_paged_native_engine_matches_view_engine(page_size, prompt_len):
+    """The native paged decode path (the decode walk in interpret mode,
+    writing each fresh row through the table as it attends) must
+    reproduce the view engine's greedy outputs exactly. The view engine
+    is the reference: the dense step functions on a gathered per-slot
+    view, no kernel, no table writes by the model."""
     import time
 
+    from gofr_tpu.ops.paged_attention import _fold_pages
     from gofr_tpu.serving.engine import EngineConfig, SamplingParams
     from gofr_tpu.serving.glue import demo_llama_engine
 
@@ -556,10 +696,14 @@ def test_paged_native_engine_matches_view_engine():
             time.sleep(0.01)
         return reqs
 
-    cfg = dict(max_batch=3, max_seq=128, seed=23, page_size=16)
+    def prompt(i):
+        return [5 + i, 2, 9] if prompt_len == 3 else \
+            [(5 + i + 3 * j) % 200 + 3 for j in range(prompt_len)]
+
+    cfg = dict(max_batch=3, max_seq=128, seed=23, page_size=page_size)
     view = demo_llama_engine(EngineConfig(paged_attention="view", **cfg))
     view.start()
-    want = [view.submit([5 + i, 2, 9], SamplingParams(
+    want = [view.submit(prompt(i), SamplingParams(
         temperature=0.0, max_new_tokens=9)) for i in range(3)]
     drain(want)
     view.stop()
@@ -567,13 +711,19 @@ def test_paged_native_engine_matches_view_engine():
     native = demo_llama_engine(EngineConfig(
         paged_attention="interpret", **cfg))
     assert native._decode is not None
+    if prompt_len > 3:  # the pass does cross a fold of the engine's pool
+        hg, _, pg, w = native.k_cache.shape[1:]
+        fold = pg * _fold_pages(hg, pg, w, native.k_cache.dtype.itemsize,
+                                native._pages_per_slot)
+        assert prompt_len < fold < prompt_len + 8 and fold % pg == 0
     native.start()
-    got = [native.submit([5 + i, 2, 9], SamplingParams(
+    got = [native.submit(prompt(i), SamplingParams(
         temperature=0.0, max_new_tokens=9)) for i in range(3)]
     drain(got)
     native.stop()
 
     assert all(r.error is None for r in got)
+    assert all(len(r.generated) == 9 for r in got)
     assert [r.generated for r in got] == [r.generated for r in want]
 
 

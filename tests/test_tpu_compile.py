@@ -30,7 +30,12 @@ layer slice folds away and proves nothing). Two things are held:
   it, no temp that follows the pool's size. A pool written by ROWS is
   laid out token-major by XLA and copied for the kernel in every
   program and every layer-step — a third of the device's time in
-  PR 25's traces (``ops/paged_kv.pool_write``).
+  PR 25's traces (``ops/paged_kv.pool_write``). The dense DECODE
+  program writes through the kernel instead (the decode walk lays a
+  slot's fresh row into the pool, aliased in and out): it holds no
+  gather or scatter of a page set at all — ``pool_write`` of one row
+  moved four page sets of every compiled slot a layer-step, a quarter
+  of a chat decode pass (PERF.md section 6, PR 31).
 
 The topology is described in a fixture, never at import: only one
 process may load the TPU's library, and every test worker imports
@@ -151,16 +156,23 @@ ENGINE_SLOTS, ENGINE_SLOT_PAGES = 32, 128
 ENGINE_HEADS = {64: (32, 32), 128: (32, 8)}
 
 
+@pytest.mark.parametrize("writes", [False, True], ids=["reads", "writes"])
 @pytest.mark.parametrize("quantized", [False, True],
                          ids=["bf16", "int8"])
 @pytest.mark.parametrize("hd", sorted(ENGINE_HEADS))
-def test_decode_walk_compiles_at_the_engines_geometry(hd, quantized, chip):
+def test_decode_walk_compiles_at_the_engines_geometry(hd, quantized, writes,
+                                                      chip):
     """The decode walk's fold is sized from (head groups, page, row
     width, dtype): at the engine's real geometry its double buffer
     must fit the scoped VMEM a kernel gets without asking (16 MiB on
     v5e — the compile raises past it), with room for the fold's
-    float32 scores beside it."""
-    from gofr_tpu.ops.paged_attention import FOLD_BYTES, _fold_pages
+    float32 scores beside it. ``writes``: the entry the model step
+    calls, the step's fresh rows going into the pool — inside the walk
+    for a plain pool (the aliased pool, a block read by one DMA and
+    written by another in one cell, a dynamic block offset), in front
+    of it for an int8 one."""
+    from gofr_tpu.ops.paged_attention import (
+        FOLD_BYTES, _fold_pages, paged_decode_append_attention_pallas)
     hq, hkv = ENGINE_HEADS[hd]
     pack = head_pack(hkv, hd)
     shape = (4, hkv // pack, N_PAGES, PAGE, pack * hd)
@@ -173,11 +185,15 @@ def test_decode_walk_compiles_at_the_engines_geometry(hd, quantized, chip):
                         ENGINE_SLOT_PAGES)
     fold = 2 * shape[1] * pages * PAGE * shape[-1] * (1 if quantized else 2)
     assert FOLD_BYTES // 2 < fold <= FOLD_BYTES and 2 * fold <= 8 << 20
+    rows = [_shape((ENGINE_SLOTS, hkv, hd), jnp.bfloat16, chip)] * 2
     _compiles_to_kernel(
-        lambda q, k, v, t, n, li: paged_decode_attention_pallas(
-            q, k, v, t, n, layer=li),
-        _shape((ENGINE_SLOTS, hq, hd), jnp.bfloat16, chip), pool, pool,
-        _shape((ENGINE_SLOTS, ENGINE_SLOT_PAGES), jnp.int32, chip),
+        (lambda q, kn, vn, k, v, t, n, li:
+         paged_decode_append_attention_pallas(q, kn, vn, k, v, t, n,
+                                              layer=li)) if writes else
+        (lambda q, kn, vn, k, v, t, n, li: paged_decode_attention_pallas(
+            q, k, v, t, n, layer=li)),
+        _shape((ENGINE_SLOTS, hq, hd), jnp.bfloat16, chip), *rows, pool,
+        pool, _shape((ENGINE_SLOTS, ENGINE_SLOT_PAGES), jnp.int32, chip),
         _shape((ENGINE_SLOTS,), jnp.int32, chip),
         _shape((), jnp.int32, chip))
 
@@ -353,9 +369,10 @@ def test_kanana_programs_keep_one_attention_kernel_class(compiled,
 
 
 # -------------------------------------- the pool's one physical layout
-#: results that hand the pool on or update it in place
+#: results that hand the pool on or update it in place (a custom-call
+#: that returns the pool is held to its aliasing below)
 POOL_CARRIERS = {"parameter", "tuple", "get-tuple-element", "bitcast",
-                 "while", "conditional", "call", "scatter"}
+                 "while", "conditional", "call", "scatter", "custom-call"}
 _RESULT = re.compile(r"^\s*(?:ROOT )?%(\S+) = (.*?) ([\w-]+)\(")
 _ARRAY = re.compile(r"\b[a-z]+\d*\[([\d,]*)\](?:\{([\d,]*))?")
 
@@ -398,8 +415,17 @@ def _pool_shaped_results(text, pool_shape):
 def test_pool_keeps_one_layout_and_is_never_copied(kind, widths, compiled):
     text, temp, pool_shape = compiled(widths, kind)
     results = _pool_shaped_results(text, pool_shape)
-    assert any(op in ("scatter", "fusion:scatter")
-               for _, op, _, _ in results), "the program writes no pool?"
+    # the dense decode program writes through the decode walk, the pool
+    # aliased in and out of the kernel; every other program scatters pages
+    kernels = {name for name, op, _, _ in results if op == "custom-call"}
+    assert bool(kernels) == (kind == "decode" and widths in WIDTHS), kernels
+    for line in text.splitlines():
+        m = _RESULT.match(line)
+        if m and m.group(1) in kernels:
+            assert "output_to_operand_aliasing" in line, line[:200]
+    assert kernels or any(op in ("scatter", "fusion:scatter")
+                          for _, op, _, _ in results), \
+        "the program writes no pool?"
     copies = [r for r in results
               if r[1] not in POOL_CARRIERS | {"fusion:scatter"}]
     assert not copies, f"the pool, or a layer of it, is copied: {copies}"
@@ -410,3 +436,30 @@ def test_pool_keeps_one_layout_and_is_never_copied(kind, widths, compiled):
     # is the relayout
     _, temp_twice, _ = compiled(widths, kind, 2 * POOL_PAGES)
     assert temp_twice <= temp, (temp, temp_twice)
+
+
+@pytest.mark.parametrize("widths", sorted(WIDTHS))
+def test_dense_decode_moves_no_page_set(widths, compiled, trace_reader):
+    """Decode's one fresh row a slot reaches the pool inside the decode
+    walk: the compiled dense decode program holds no ``gather`` or
+    ``scatter`` (nor a fusion of one: fused computations are in the
+    text) of a slot-by-page-set ``[B, Hg, page, W]`` or of the pool —
+    ``pool_write`` in front of the walk was two of each a layer — and
+    its one Pallas kernel still reads as ``attention`` in the trace."""
+    text, _, pool_shape = compiled(widths, "decode")
+    _, hg, _, page, width = pool_shape
+    moved = []
+    for line in text.splitlines():
+        m = _RESULT.match(line)
+        if not m or m.group(3) not in ("gather", "scatter"):
+            continue
+        for dims, _ in _ARRAY.findall(m.group(2)):
+            shape = tuple(int(d) for d in dims.split(",") if d)
+            if shape == tuple(pool_shape) or shape[-3:] == (hg, page, width):
+                moved.append((m.group(1), m.group(3), shape))
+    assert not moved, f"page sets are moved around the kernel: {moved}"
+    kernels = [line.strip() for line in text.splitlines()
+               if 'custom_call_target="tpu_custom_call"' in line]
+    assert len(kernels) == 1, kernels
+    assert trace_reader.classify(
+        kernels[0], trace_reader.load_names()["kernels"]) == "attention"
