@@ -1,11 +1,14 @@
 """Rotary position embeddings (RoPE), Llama-3 style.
 
-Supports plain RoPE and Llama-3's frequency scaling for long context.
+Supports plain RoPE, Llama-3's frequency scaling for long context and
+YaRN's (arXiv:2309.00071, as the ``deepseek_v3`` modelling code has it).
 Computed in float32; applied as interleaved-free "rotate half" over the
 head dimension (the GPT-NeoX convention Llama uses).
 """
 
 from __future__ import annotations
+
+import math
 
 import jax.numpy as jnp
 
@@ -32,6 +35,46 @@ def rope_frequencies(head_dim: int, theta: float = 500000.0,
                         jnp.where(wavelen > orig / low, inv / factor,
                                   (1 - smooth) * inv / factor + smooth * inv))
     return inv
+
+
+def yarn_mscale(factor: float, mscale: float = 1.0) -> float:
+    """YaRN's attention temperature for a context stretched ``factor``
+    times: ``0.1 * mscale * ln(factor) + 1`` (1 at or below 1)."""
+    return 1.0 if factor <= 1 else 0.1 * mscale * math.log(factor) + 1.0
+
+
+def yarn_frequencies(head_dim: int, theta: float, scaling: dict
+                     ) -> jnp.ndarray:
+    """Inverse frequencies [head_dim // 2] under YaRN: each pair's
+    frequency is blended between the plain one (``extrapolation``: pairs
+    that turn more than ``beta_fast`` times in the original context
+    keep it) and the plain one over ``factor`` (``interpolation``:
+    pairs that turn less than ``beta_slow`` times), by a linear ramp
+    between the two pairs' indices (``find_correction_range``, floor and
+    ceiling unless ``truncate`` is false).
+
+    ``scaling``: {"factor", "original_max_position_embeddings",
+    "beta_fast" (32), "beta_slow" (1)}. The temperature is not applied
+    here: cos and sin are scaled by ``mscale / mscale_all_dim`` ratios
+    the caller owns (:func:`yarn_mscale`)."""
+    factor = float(scaling["factor"])
+    orig = float(scaling["original_max_position_embeddings"])
+
+    def correction_dim(rotations: float) -> float:
+        return (head_dim * math.log(orig / (rotations * 2 * math.pi))
+                / (2 * math.log(theta)))
+
+    low = correction_dim(float(scaling.get("beta_fast") or 32))
+    high = correction_dim(float(scaling.get("beta_slow") or 1))
+    if scaling.get("truncate", True):
+        low, high = math.floor(low), math.ceil(high)
+    low, high = max(low, 0), min(high, head_dim - 1)
+    if low == high:
+        high += 0.001
+    half = jnp.arange(head_dim // 2, dtype=jnp.float32)
+    plain = 1.0 / (theta ** (2.0 * half / head_dim))
+    ramp = jnp.clip((half - low) / (high - low), 0.0, 1.0)
+    return plain / factor * ramp + plain * (1.0 - ramp)
 
 
 def apply_rope(x: jnp.ndarray, positions: jnp.ndarray,

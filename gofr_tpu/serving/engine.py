@@ -506,6 +506,11 @@ class Engine:
     path's steps take the pools and the block tables
     (``paged_decode_fn``, ``paged_chunk_fn``, ``paged_verify_fn`` — e.g.
     ``llama_decode_step_paged``). A family passes the steps it has.
+    A ``paged_decode_fn`` may return a fourth value, a small int32
+    vector of counters it took on the device; ``decode_facts`` then
+    names them, in the vector's order: name -> reducer of that
+    counter's column over a pass's steps (numpy int32 [T]) to the
+    number the decode pass record carries under the name.
     """
 
     def __init__(self, params: Any, config: EngineConfig, *,
@@ -515,10 +520,12 @@ class Engine:
                  paged_decode_fn: Callable | None = None,
                  paged_chunk_fn: Callable | None = None,
                  paged_verify_fn: Callable | None = None,
+                 decode_facts: dict[str, Callable] | None = None,
                  metrics: Any = None,
                  logger: Any = None, tracer: Any = None) -> None:
         self.params = params
         self.config = config
+        self._decode_facts = dict(decode_facts or {})
         self.metrics = metrics
         self.logger = logger
         #: tracer for engine.* request spans (assembled at retire from
@@ -3418,16 +3425,17 @@ class Engine:
                 # the pass record: everything here is a host int/float the
                 # collect already computed — no device reads beyond the
                 # token sync that IS the collect. A family whose step
-                # counts its routing on the device sent the counts as
-                # extra columns of the token array (_fused_decode)
+                # counts on the device sent the counters as extra columns
+                # of the token array (_fused_decode) and named them
+                # (``decode_facts``)
                 facts = step_np[:, self.config.max_batch:]
-                routing = {} if not facts.shape[1] else {
-                    "experts_touched": int(facts[:, 0].sum()),
-                    "assignments": int(facts[:, 1].sum()),
-                    "kv_row_bytes": self._kv_row_bytes}
+                counted = {name: read(facts[:, i]) for i, (name, read)
+                           in enumerate(self._decode_facts.items())}
+                if counted:
+                    counted["kv_row_bytes"] = self._kv_row_bytes
                 self.recorder.record_pass(
                     "decode", rec["pass_id"], t0=rec["t0"], t1=end,
-                    **routing,
+                    **counted,
                     rids=rec["rids"], ctx=rec["ctx"],
                     steps=self._tokens_per_pass, win=rec.get("win", 0),
                     dur=round(busy, 6),

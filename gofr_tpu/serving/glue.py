@@ -223,7 +223,7 @@ def deepseek_engine(params: Any, model_config,
     from ..models.deepseek import (deepseek_decode_step_paged,
                                    deepseek_prefill_chunk_paged,
                                    deepseek_prefill_last,
-                                   make_latent_cache)
+                                   make_latent_cache, step_fact_readers)
     from ..ops.latent_attention import check_latent_layout
     from ..ops.paged_kv import pool_from_cache_shape
     from dataclasses import replace
@@ -281,6 +281,7 @@ def deepseek_engine(params: Any, model_config,
                   make_cache=make_cache,
                   paged_decode_fn=paged_decode_fn,
                   paged_chunk_fn=paged_chunk_fn,
+                  decode_facts=step_fact_readers(c),
                   metrics=metrics, logger=logger, tracer=tracer)
 
 

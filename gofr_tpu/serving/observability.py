@@ -183,7 +183,8 @@ class FlightRecorder:
         a sparse-expert family also carries ``experts_touched``,
         ``assignments`` (counted on the device in the model's step,
         read with the sampled tokens) and ``kv_row_bytes`` (the pool's
-        stored bytes a token, from the model's stated row)."""
+        stored bytes a token, from the model's stated row); one with a
+        multi-stream residual also ``streams`` and ``mhc_row_err``."""
         if not self.enabled:
             return
         rec = {"pass_id": self.new_pass() if pass_id is None else pass_id,

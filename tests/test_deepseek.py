@@ -1,16 +1,23 @@
-"""The ``deepseek_v3`` family (Kanana-2): latent (MLA) attention over a
-one-vector page pool and sigmoid-routed sparse experts, against the
-plain reference the benchmark keeps (``benchmarks/references/
-deepseek_v3_mla_moe.py``, which imports nothing of the program).
+"""The ``deepseek_v3`` family (Kanana-2) and its ``xing4_0`` descendant
+(Xing4.0): latent (MLA) attention over a one-vector page pool and
+sigmoid-routed sparse experts — and for ``xing4_0`` a four-stream
+mHC residual, compressed queries and YaRN — each against the plain
+reference the benchmark keeps for it (``benchmarks/references/
+deepseek_v3_mla_moe.py``, ``xing4_mhc_mla_moe.py``, which import
+nothing of the program).
 
 Everything here runs at a small size of the same shape — d 64, 4 heads,
 nope 16 / rope 8 / v 16, latent 32, 8 experts top-2 + 1 shared, 1 dense
-+ 2 expert layers — in float32 on the CPU. Tolerances, and why:
++ 2 expert layers (``xing4_0``: 4 streams mixed by 4 Sinkhorn rounds —
+the published 20 are ``tests/test_hyper_connections.py``'s — queries
+through 24, YaRN x 8 over 32 positions) — in float32 on the CPU.
+Tolerances, and why:
 
 - ``LOGIT_TOL`` 2e-4 on logits of standard deviation ~1: both sides
   compute in float32 and differ by the order of their sums (absorbed
   against materialised attention, a grouped matmul against a masked
-  loop, an online softmax against a dense one): 5e-6 as measured. The
+  loop, an online softmax against a dense one; with streams, the
+  stream mix as adds of slabs against an einsum): 5e-6 as measured. The
   same model in bf16 — the nearest precision below — reads 5e-2 and
   more, and ``test_bf16_where_float32_is_stated_fails`` holds that it
   fails the tolerance.
@@ -53,11 +60,14 @@ PAGE, N_PAGES, MAX_PAGES = 8, 32, 16
 S = 40          # tokens of the test sequence
 
 
-@pytest.fixture(scope="module")
-def reference():
+#: family -> (its reference's file, the small config of its shape)
+FAMILIES = {"deepseek_v3": ("deepseek_v3_mla_moe", DeepseekConfig.tiny),
+            "xing4_0": ("xing4_mhc_mla_moe", DeepseekConfig.tiny_mhc)}
+
+
+def load_reference(name):
     spec = importlib.util.spec_from_file_location(
-        "ref_deepseek_v3", REPO / "benchmarks" / "references"
-        / "deepseek_v3_mla_moe.py")
+        "ref_" + name, REPO / "benchmarks" / "references" / f"{name}.py")
     module = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(module)
     return module
@@ -69,11 +79,12 @@ def published_keys(c: DeepseekConfig) -> dict:
     return {**keys, "attention_bias": False}
 
 
-@pytest.fixture(scope="module")
-def case(reference):
+@pytest.fixture(scope="module", params=list(FAMILIES))
+def case(request):
     """Seeded float32 weights from the reference's own initialiser, a
     token sequence, and the reference's logits at every position."""
-    c = DeepseekConfig.tiny()
+    name, tiny = FAMILIES[request.param]
+    reference, c = load_reference(name), tiny()
     cfg = published_keys(c)
     params = jax.tree.map(lambda x: x.astype(jnp.float32),
                           reference.init_weights(cfg, 7))
@@ -97,15 +108,22 @@ def serve(c, params, tokens, *, path, impl):
     """Logits at every position a serving path produces them for:
     ``bucket`` prefills 24 tokens at once and decodes the rest;
     ``chunk`` walks two 16-token chunks (the second with history) and
-    decodes the rest. Returns {position: logits}."""
+    decodes the rest. Returns {position: logits}. The steps are jitted:
+    called bare, every call would trace and compile its layer scans
+    anew."""
     pool, v_pool = fresh_pools(c)
     got = {}
+    prefill = jax.jit(lambda *a, **kw: deepseek_prefill_last(*a, c, **kw))
+    chunk = jax.jit(lambda *a: deepseek_prefill_chunk_paged(
+        *a, c, implementation=impl))
+    decode = jax.jit(lambda *a: deepseek_decode_step_paged(
+        *a, c, implementation=impl))
     if path == "bucket":
         done = 24
         padded = np.zeros((1, 32), np.int32)
         padded[0, :done] = tokens[:done]
-        logits, (k, v) = deepseek_prefill_last(
-            params, jnp.asarray(padded), c, kv_lengths=jnp.array([done]))
+        logits, (k, v) = prefill(params, jnp.asarray(padded),
+                                 kv_lengths=jnp.array([done]))
         got[done - 1] = logits[0]
         zero, n = jnp.zeros(1, jnp.int32), jnp.array([done])
         pool = scatter_chunk(pool, TABLES, k, zero, n)
@@ -113,15 +131,14 @@ def serve(c, params, tokens, *, path, impl):
     else:
         done = 32
         for off in (0, 16):
-            logits, pool, v_pool = deepseek_prefill_chunk_paged(
+            logits, pool, v_pool = chunk(
                 params, jnp.asarray(tokens[None, off:off + 16]), pool,
-                v_pool, TABLES, jnp.array([off]), jnp.array([16]), c,
-                implementation=impl)
+                v_pool, TABLES, jnp.array([off]), jnp.array([16]))
             got[off + 15] = logits[0]
     for t in range(done, len(tokens)):
-        logits, pool, v_pool, _ = deepseek_decode_step_paged(
+        logits, pool, v_pool, _ = decode(
             params, jnp.asarray(tokens[t:t + 1]), pool, v_pool, TABLES,
-            jnp.array([t]), c, implementation=impl)
+            jnp.array([t]))
         got[t] = logits[0]
     assert v_pool.size == 0
     return got
@@ -167,7 +184,7 @@ def test_absorbed_attention_equals_materialised(case, history, impl):
     n = 24
     x = jax.random.normal(jax.random.key(history), (1, n, c.hidden_size),
                           jnp.float32)
-    inv_freq = rope_frequencies(c.qk_rope_head_dim, c.rope_theta)
+    inv_freq = c.rope_inv_freq
     positions = jnp.arange(n)[None, :]
     want, rows = deepseek._attn_materialised(
         x, lp, c, positions, inv_freq, jnp.array([n]))
@@ -345,12 +362,12 @@ ENGINE = dict(max_batch=2, max_seq=128, prefill_buckets=(8, 16), page_size=8,
               kv_layout="paged", seed=7, kv_pages=24)
 
 
-@pytest.fixture(scope="module")
-def served():
+@pytest.fixture(scope="module", params=list(FAMILIES))
+def served(request):
     """Four prompts (two walk chunks) through the engine on both
     implementations: ids, the decode pass records, the engine's own
     account of its cache."""
-    c = DeepseekConfig.tiny()
+    c = FAMILIES[request.param][1]()
     params = deepseek_init(jax.random.key(3), c)
     rng = np.random.RandomState(5)
     prompts = [list(rng.randint(3, 200, size=n)) for n in (30, 7, 45, 12)]
@@ -408,6 +425,42 @@ def test_decode_pass_record_carries_the_routing_facts(served):
         assert rec["kv_row_bytes"] == latent_row_bytes(c)[1]
 
 
+def test_decode_pass_record_carries_the_stream_facts(served):
+    """With ``hc_mult`` a decode pass names its residual streams and
+    the largest row error any stream mix of the pass left; the plain
+    residual carries neither."""
+    c, out = served
+    for impl in ("xla", "interpret"):
+        assert out[impl]["decode"]
+        for rec in out[impl]["decode"]:
+            if c.hc_mult is None:
+                assert "streams" not in rec and "mhc_row_err" not in rec
+            else:
+                assert rec["streams"] == c.hc_mult
+                # the small config's 4 rounds leave a positive residue,
+                # not nought and not a row's own size
+                assert 0.0 < rec["mhc_row_err"] < 0.5
+
+
+def test_the_builder_names_the_facts_and_the_engine_none():
+    """The step's counters reach the pass record under the names and
+    through the reducers the family hands over, in the vector's order;
+    the engine's own code names no counter."""
+    plain = deepseek.step_fact_readers(DeepseekConfig.tiny())
+    mhc = deepseek.step_fact_readers(DeepseekConfig.tiny_mhc())
+    assert list(plain) == ["experts_touched", "assignments"]
+    assert list(mhc) == [*plain, "streams", "mhc_row_err"]
+    assert plain["experts_touched"](np.array([3, 5, 4], np.int32)) == 12
+    assert mhc["streams"](np.array([4, 4, 4], np.int32)) == 4
+    # a pass's largest row error: float32 bits ride the int32 column
+    bits = np.array([0.25, 0.5, 0.125], np.float32).view(np.int32)
+    assert mhc["mhc_row_err"](bits) == 0.5
+    import inspect
+    from gofr_tpu.serving import engine
+    source = inspect.getsource(engine)
+    assert not any(f'"{name}"' in source for name in mhc)
+
+
 def test_llama_pass_records_are_as_they_were():
     from gofr_tpu.serving.glue import demo_llama_engine
     eng = demo_llama_engine(EngineConfig(
@@ -442,18 +495,81 @@ def test_unsupported_combinations_are_refused_at_build(kw, names):
                         mesh=mesh)
 
 
+YARN = {"type": "yarn", "factor": 64, "beta_fast": 32, "beta_slow": 1,
+        "mscale": 1, "mscale_all_dim": 1,
+        "original_max_position_embeddings": 4096}
+
+
 @pytest.mark.parametrize("key,value", [
-    ("q_lora_rank", 1536), ("scoring_func", "softmax"), ("n_group", 8),
-    ("rope_interleave", False), ("rope_scaling", {"type": "yarn"})])
+    ("scoring_func", "softmax"), ("n_group", 8), ("rope_interleave", False),
+    ("rope_scaling", {"type": "linear", "factor": 4}),
+    ("rope_scaling", {"type": "yarn"}),              # lacks its sizes
+    ("rope_scaling", {**YARN, "mscale": 0.5}),       # cos/sin scaled
+    ("hc_mult", 1), ("hc_mult", 2.0), ("q_lora_rank", 0)])
 def test_config_refuses_what_is_not_implemented(key, value):
     with pytest.raises(ValueError, match=key):
         DeepseekConfig(**{key: value})
 
 
+@pytest.mark.parametrize("key,value", [
+    ("q_lora_rank", 1536), ("rope_scaling", YARN), ("hc_mult", 4),
+    ("hc_mult", None)])
+def test_config_accepts_what_is_implemented(key, value):
+    assert getattr(DeepseekConfig(**{key: value}), key) == value
+
+
+def test_yarn_frequencies_and_scale_are_the_published_formulas():
+    """Xing4.0's rope: 64 lanes, theta 1e4, factor 64 over 4,096. The
+    correction dims are 64 ln(4096 / (32 x 2 pi)) / (2 ln 1e4) = 10.47
+    -> 10 and 64 ln(4096 / (2 pi)) / (2 ln 1e4) = 22.51 -> 23: pairs 0
+    to 10 keep 1e4^(-i/32), pairs 23 to 31 have it over 64, and pair i
+    between them blends by (i - 10) / 13. The softmax scale is
+    192^-1/2 x (0.1 ln 64 + 1)^2."""
+    c = DeepseekConfig(rope_theta=10000.0, rope_scaling=YARN)
+    got = np.asarray(c.rope_inv_freq, np.float64)
+    plain = 10000.0 ** (-np.arange(32) / 32.0)
+    ramp = np.clip((np.arange(32) - 10) / 13.0, 0.0, 1.0)
+    np.testing.assert_allclose(got, plain / 64 * ramp + plain * (1 - ramp),
+                               rtol=1e-6)
+    np.testing.assert_allclose(got[:11], plain[:11], rtol=1e-6)
+    np.testing.assert_allclose(got[23:], plain[23:] / 64, rtol=1e-6)
+    np.testing.assert_allclose(got[16], plain[16] * (7 / 13 + 6 / 13 / 64),
+                               rtol=1e-6)
+    assert c.softmax_scale == pytest.approx(0.07216878 * 1.4158883 ** 2,
+                                            rel=1e-6)
+    assert c.softmax_scale == pytest.approx(0.1446788, rel=1e-5)
+    # no scaling: the plain frequencies and the plain scale, as before
+    plain_c = DeepseekConfig(rope_theta=10000.0)
+    np.testing.assert_array_equal(
+        np.asarray(plain_c.rope_inv_freq),
+        np.asarray(rope_frequencies(64, 10000.0)))
+    assert plain_c.softmax_scale == 192 ** -0.5
+
+
+def test_reference_head_in_blocks_equals_the_whole_head():
+    """The xing4_0 reference multiplies by the head V_BLOCK columns at
+    a time (a float32 copy of 131,072 columns would be 1.9 GB); the
+    tiny vocabulary does not divide, so the blocks are tested here."""
+    ref = load_reference("xing4_mhc_mla_moe")
+    ks = jax.random.split(jax.random.key(0), 2)
+    x = jax.random.normal(ks[0], (5, 32), jnp.float32)
+    head = jax.random.normal(ks[1], (32, 256), jnp.float32)
+    for low in (None, "int8"):
+        whole = np.asarray(ref._head(x, head, low))     # 256 % 16384
+        ref.V_BLOCK = 64
+        try:
+            blocks = np.asarray(ref._head(x, head, low))
+        finally:
+            ref.V_BLOCK = 16384
+        # blocks of other widths sum in another order
+        np.testing.assert_allclose(blocks, whole, rtol=1e-5, atol=1e-5)
+
+
 # ----------------------------------------------------------- capacity maths
 
 @pytest.mark.parametrize("config,needed,stored", [
-    ("kanana-2-30b-a3b-6l", 6912, 7680), ("smollm2-1.7b", 196608, 196608),
+    ("kanana-2-30b-a3b-6l", 6912, 7680), ("xing4-29b-a4b-8l", 9216, 10240),
+    ("smollm2-1.7b", 196608, 196608),
     ("mistral-7b-16l", 65536, 65536)])
 def test_capacity_tool_reads_the_row_from_the_model(config, needed, stored):
     proc = subprocess.run(

@@ -231,8 +231,8 @@ def _engine(widths):
     """The benchmark's builder on a two-layer model, kernels by name:
     no chip is attached, so nothing may be left to ``auto``. Weights
     are zeros of the right shapes — only shapes are compiled."""
-    if widths == "kanana":
-        return _kanana_engine()
+    if widths in LATENT_WIDTHS:
+        return _latent_engine(widths)
     from gofr_tpu.models.llama import LlamaConfig, llama_init
     from gofr_tpu.serving.engine import EngineConfig
     from gofr_tpu.serving.glue import llama_engine
@@ -250,15 +250,32 @@ def _engine(widths):
         implementation="pallas")
 
 
-def _kanana_engine():
-    """``deepseek_engine`` at Kanana-2-30B-A3B's published widths
-    (benchmarks/configs/kanana-2-30b-a3b-6l.json), one dense and two
-    expert layers, every expert of each. The weights are SHAPES: three
-    such layers are 3.7 GB, and only shapes are compiled."""
+#: the latent, sparse family's two configurations
+#: (benchmarks/configs/kanana-2-30b-a3b-6l.json: the class's defaults;
+#: xing4-29b-a4b-8l.json: four mHC residual streams, q-LoRA, YaRN)
+LATENT_WIDTHS = {
+    "kanana": dict(num_hidden_layers=3),
+    "xing4": dict(
+        vocab_size=131072, hidden_size=3584, num_hidden_layers=3,
+        first_k_dense_replace=2, q_lora_rank=768, intermediate_size=9216,
+        moe_intermediate_size=1024, n_routed_experts=64, n_shared_experts=1,
+        num_experts_per_tok=4, routed_scaling_factor=2.0, rope_theta=10000.0,
+        max_position_embeddings=262144, hc_mult=4,
+        rope_scaling={"type": "yarn", "factor": 64, "beta_fast": 32,
+                      "beta_slow": 1, "mscale": 1, "mscale_all_dim": 1,
+                      "original_max_position_embeddings": 4096}),
+}
+
+
+def _latent_engine(widths):
+    """``deepseek_engine`` at a configuration's published widths, three
+    layers (Kanana-2: one dense and two expert layers; Xing4.0: two
+    dense and one), every expert of each. The weights are SHAPES: three
+    such layers are gigabytes, and only shapes are compiled."""
     from gofr_tpu.models.deepseek import DeepseekConfig, deepseek_init
     from gofr_tpu.serving.engine import EngineConfig
     from gofr_tpu.serving.glue import deepseek_engine
-    c = DeepseekConfig(num_hidden_layers=3)
+    c = DeepseekConfig(**LATENT_WIDTHS[widths])
     params = jax.eval_shape(lambda: deepseek_init(jax.random.key(0), c))
     return deepseek_engine(
         params, c,
@@ -344,16 +361,19 @@ def test_trace_names_find_the_engines_programs_and_kernels(
             == "attention", line[:160]
 
 
-def test_kanana_programs_keep_one_attention_kernel_class(compiled,
-                                                          trace_reader):
+@pytest.mark.parametrize("widths", sorted(LATENT_WIDTHS))
+def test_latent_programs_keep_one_attention_kernel_class(widths, compiled,
+                                                         trace_reader):
     """The latent kernel is the one Pallas call of the decode and chunk
     programs that the trace names ``%closed_call``: XLA's grouped
     matmul is a Mosaic kernel too, under its own name
-    (``%ragged-dot-...``), and must not read as attention."""
+    (``%ragged-dot-...``), and must not read as attention. The stream
+    mixes of a multi-stream residual are plain XLA: a Pallas call there
+    would read as attention and spoil the cell's attention roofline."""
     names = trace_reader.load_names()
     for kind, program in (("decode", "decode"), ("chunk", "prefill"),
                           ("bucket", "prefill")):
-        text, _, _ = compiled("kanana", kind)
+        text, _, _ = compiled(widths, kind)
         module = re.match(r"HloModule (\S+?),", text).group(1)
         assert trace_reader.classify(module, names["programs"]) == program
         kernels = [line.strip() for line in text.splitlines()
@@ -366,6 +386,10 @@ def test_kanana_programs_keep_one_attention_kernel_class(compiled,
         # the bucket program attends on XLA (PERF.md section 7)
         assert ("attention" in classes.values()) == (kind != "bucket"), \
             classes
+        # one latent kernel a layer scan (dense layers, expert layers)
+        assert sum(cls == "attention" for cls in (
+            trace_reader.classify(line, names["kernels"])
+            for line in kernels)) == (0 if kind == "bucket" else 2)
 
 
 # -------------------------------------- the pool's one physical layout
@@ -410,7 +434,8 @@ def _pool_shaped_results(text, pool_shape):
     return found
 
 
-@pytest.mark.parametrize("widths", [*sorted(WIDTHS), "kanana"])
+@pytest.mark.parametrize("widths", [*sorted(WIDTHS),
+                                    *sorted(LATENT_WIDTHS)])
 @pytest.mark.parametrize("kind", ["decode", "bucket", "chunk"])
 def test_pool_keeps_one_layout_and_is_never_copied(kind, widths, compiled):
     text, temp, pool_shape = compiled(widths, kind)
